@@ -74,8 +74,13 @@ def timeit(fn, reps: int):
     return {"p50": float(med), "iqr": float(q3 - q1), "n": reps}
 
 
-def gen_data(tmp: str, n_items: int, n_orders: int, n_files: int = 8):
-    rng = np.random.default_rng(7)
+def gen_data(
+    tmp: str, n_items: int, n_orders: int, n_files: int = 8, seed: int = 7
+):
+    """Write the bench's ``lineitem``/``orders`` tables under ``tmp`` as
+    ``n_files`` parquet files each, every value drawn from ``seed``
+    (``chip_smoke.py`` runs the same shape on the chip)."""
+    rng = np.random.default_rng(seed)
     items_dir = os.path.join(tmp, "lineitem")
     orders_dir = os.path.join(tmp, "orders")
     os.makedirs(items_dir)
@@ -866,7 +871,8 @@ def main() -> None:
             fast_avg = row["fast_wait_ms_total"] / max(1, row["fast_waits"])
             poll_avg = row["poll_wait_ms_total"] / max(1, row["poll_waits"])
             log(
-                f"fleet {np_} procs: {row['qps']} qps aggregate, p50 "
+                f"fleet {np_} procs (workers on {row['worker_platform']}): "
+                f"{row['qps']} qps aggregate, p50 "
                 f"{row['p50_ms']}ms p99 {row['p99_ms']}ms, dedup "
                 f"{row['cross_process_dedup']}+{row['fast_handoffs']}fast"
                 f"/{row['queries']}, push recv {row['fast_push_received']}, "
@@ -896,6 +902,7 @@ def main() -> None:
             fleet_vs_single = {
                 "single_process_64c_qps": serve64["qps"],
                 "fleet_2proc_qps": fleet2["qps"],
+                "fleet_worker_platform": fleet2["worker_platform"],
                 "beats_single": bool(fleet2["qps"] > serve64["qps"]),
             }
             log(

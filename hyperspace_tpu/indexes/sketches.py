@@ -13,14 +13,12 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, List, Optional, Tuple, Type
 
-import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
 
 from hyperspace_tpu.exceptions import HyperspaceException
 from hyperspace_tpu.io.columnar import Column, ColumnarBatch, column_value_range
-from hyperspace_tpu.ops.bloom import _bit_indices
-from hyperspace_tpu.ops.hash import split_words_np
+from hyperspace_tpu.ops.bloom import bit_indices_np
 from hyperspace_tpu.plan import expressions as E
 from hyperspace_tpu.utils.hashing import murmur3_64_bytes
 
@@ -256,12 +254,8 @@ class BloomFilterSketch(Sketch):
                 for b in blobs
             ]
         )
-        idx = np.asarray(
-            _bit_indices(
-                jnp.asarray(split_words_np(np.array(reps, dtype=np.int64)[None, :])),
-                self.m,
-                self.k,
-            )
+        idx = bit_indices_np(
+            np.array(reps, dtype=np.int64), self.m, self.k
         )  # [k, n_values]
         widx, bit = idx >> 6, (idx & 63).astype(np.uint64)
         # hits[f, j] = all k bits of value j set in bloom f

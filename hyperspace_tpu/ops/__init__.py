@@ -34,57 +34,30 @@ import jax
 
 jax.config.update("jax_enable_x64", True)
 
-def _machine_cache_tag() -> str:
-    """Short fingerprint of THIS machine's CPU feature set (plus arch).
-
-    The persistent cache stores XLA:CPU AOT results compiled against the
-    build machine's exact feature flags; loading an entry on a host with
-    a different feature set makes ``cpu_aot_loader`` emit a wall of
-    machine-feature-mismatch warnings per entry (and risks SIGILL).
-    Shared cache dirs (home on NFS, baked images, heterogeneous fleets)
-    hit this constantly — scoping the cache per machine fingerprint
-    makes every entry loadable by construction. Same-hardware hosts
-    still share (same flags -> same tag)."""
-    import hashlib
-    import platform
-
-    feats = ""
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                if line.startswith(("flags", "Features")):
-                    feats = " ".join(sorted(line.split(":", 1)[1].split()))
-                    break
-    except OSError:
-        feats = platform.processor() or platform.machine()
-
-    return hashlib.sha256(
-        (platform.machine() + "|" + feats).encode()
-    ).hexdigest()[:12]
-
-
 # Persistent XLA compilation cache. TPU sort kernels take 40-80s to
 # compile while executing in milliseconds; caching them on disk makes every
-# process after the first pay only dispatch cost. Opt out (or relocate)
-# via HYPERSPACE_JAX_CACHE_DIR; the exact value "off" disables (a
-# directory literally named off/OFF still works as a path). The cache is
-# scoped per machine fingerprint (see _machine_cache_tag) so entries are
-# always feature-compatible with the loading host.
-_cache_dir = os.environ.get(
-    "HYPERSPACE_JAX_CACHE_DIR",
-    os.path.join(os.path.expanduser("~"), ".cache", "hyperspace_tpu", "jax"),
+# process after the first pay only dispatch cost. The cache lives where
+# JAX_COMPILATION_CACHE_DIR says (jax reads that variable into
+# ``jax_compilation_cache_dir`` itself — nothing is set here, so the
+# directory is exactly the one given). Unset, it is a fixed directory in
+# the checkout: the path is part of what makes a cache reusable, so it is
+# never derived from a temp name, a pid or the home directory. No
+# per-machine subdirectory: jax's own cache key hashes the backend
+# topology, which for XLA:CPU carries the host's CPU feature list, so an
+# entry compiled for other features is never looked up.
+JAX_CACHE_DIR_DEFAULT = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
 )
-if _cache_dir != "off":
-    try:
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.path.join(_cache_dir, "m-" + _machine_cache_tag()),
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
-    # older jax without the knobs (exception type varies by version):
-    # in-memory cache only
-    except Exception:  # hslint: disable=HS402
-        pass
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update("jax_compilation_cache_dir", JAX_CACHE_DIR_DEFAULT)
+if jax.config.jax_platforms != "cpu":
+    # Toward an accelerator every compile is kept, however short: a second
+    # process over the same directory then compiles nothing. A process
+    # held to the CPU (tests, fleet workers) keeps jax's own threshold —
+    # XLA:CPU compiles of these programs are sub-second, and jax 0.9.0
+    # logs two multi-KB "machine feature" lines per CPU entry it loads.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
 
 def pad_len(n: int, minimum: int = 8) -> int:

@@ -7,6 +7,12 @@ engine is the serve path, so grouped reductions run as XLA segment ops
 runs compiled on device. Null semantics match SQL/Spark: sum/min/max/avg
 ignore nulls (an all-null group yields null), count(col) counts non-null
 rows, count(*) counts rows.
+
+Integers only on the device. The TPU has no IEEE double: a float64 it
+holds keeps float32's exponent range and fewer mantissa bits (chip run,
+PR 21: 1e300 arrives as inf, segment_min returns values that are no
+input, sums are off by 2e-13), so float reductions always run the numpy
+twins, whose answers are exact.
 """
 
 from __future__ import annotations
@@ -34,37 +40,12 @@ def _seg_sum_count(gid, vals, valid, num_segments):
 
 @functools.partial(jax.jit, static_argnames=("num_segments",))
 def _seg_min(gid, vals, valid, num_segments):
-    if jnp.issubdtype(vals.dtype, jnp.floating):
-        # Spark float ordering: NaN > +inf, so min is NaN only when the
-        # group has no non-NaN valid values (matches ops/sort.order_rep).
-        isn = jnp.isnan(vals)
-        clean = jnp.where(valid & ~isn, vals, jnp.inf)
-        m = jax.ops.segment_min(clean, gid, num_segments=num_segments)
-        has_clean = (
-            jax.ops.segment_sum(
-                (valid & ~isn).astype(jnp.int32), gid, num_segments=num_segments
-            )
-            > 0
-        )
-        return jnp.where(has_clean, m, jnp.asarray(jnp.nan, vals.dtype))
     v = jnp.where(valid, vals, jnp.iinfo(vals.dtype).max)
     return jax.ops.segment_min(v, gid, num_segments=num_segments)
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments",))
 def _seg_max(gid, vals, valid, num_segments):
-    if jnp.issubdtype(vals.dtype, jnp.floating):
-        # Spark float ordering: any valid NaN wins the max.
-        isn = jnp.isnan(vals)
-        clean = jnp.where(valid & ~isn, vals, -jnp.inf)
-        m = jax.ops.segment_max(clean, gid, num_segments=num_segments)
-        has_nan = (
-            jax.ops.segment_sum(
-                (valid & isn).astype(jnp.int32), gid, num_segments=num_segments
-            )
-            > 0
-        )
-        return jnp.where(has_nan, jnp.asarray(jnp.nan, vals.dtype), m)
     v = jnp.where(valid, vals, jnp.iinfo(vals.dtype).min)
     return jax.ops.segment_max(v, gid, num_segments=num_segments)
 
@@ -85,6 +66,12 @@ def _as_device(vals: np.ndarray) -> jnp.ndarray:
 # semantics, exact int64 sums via ufunc.at) finish in milliseconds on
 # host-resident serve batches.
 _HOST_AGG_MAX_ROWS = 1 << 20
+
+
+def _on_host(vals: np.ndarray) -> bool:
+    """Floats of any length (see module docstring), everything else up
+    to ``_HOST_AGG_MAX_ROWS``."""
+    return vals.dtype.kind == "f" or len(vals) <= _HOST_AGG_MAX_ROWS
 
 
 def _host_sum_count(gid, vals, valid, num_segments):
@@ -138,7 +125,7 @@ def segment_sum_count(
     valid = (
         np.ones(len(vals), dtype=bool) if valid is None else valid
     )
-    if len(vals) <= _HOST_AGG_MAX_ROWS:
+    if _on_host(vals):
         return _host_sum_count(gid, vals, valid, num_segments)
     s, c = _seg_sum_count(
         jnp.asarray(gid), _as_device(vals), jnp.asarray(valid), num_segments
@@ -154,7 +141,7 @@ def segment_minmax(
     mode: str,
 ) -> np.ndarray:
     valid = np.ones(len(vals), dtype=bool) if valid is None else valid
-    if len(vals) <= _HOST_AGG_MAX_ROWS:
+    if _on_host(vals):
         return _host_minmax(gid, vals, valid, num_segments, mode)
     fn = _seg_min if mode == "min" else _seg_max
     out = fn(jnp.asarray(gid), _as_device(vals), jnp.asarray(valid), num_segments)

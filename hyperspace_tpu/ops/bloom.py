@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import hyperspace_tpu.ops  # noqa: F401  (enables x64)
+from hyperspace_tpu.ops import pad_len
 from hyperspace_tpu.ops.hash import hash_words, split_words_np
 
 
@@ -41,12 +42,27 @@ def _bit_indices(words, m: int, k: int):
     return jnp.stack(idx)
 
 
+def bit_indices_np(key_reps: np.ndarray, m: int, k: int) -> np.ndarray:
+    """Host entry of :func:`_bit_indices`: int64 key reps [n] -> [k, n]
+    int32 bit indices. The row dimension is padded to ``pad_len``
+    (ops/__init__ shape policy) — a data-skipping build hashes one batch
+    per source file, and an unpadded ``n`` would compile once per
+    distinct file row count."""
+    n = len(key_reps)
+    words = split_words_np(np.asarray(key_reps, dtype=np.int64)[None, :])
+    n_pad = pad_len(n)
+    if n_pad != n:
+        words = np.concatenate(
+            [words, np.zeros((2, n_pad - n), dtype=np.uint32)], axis=1
+        )
+    return np.asarray(_bit_indices(jnp.asarray(words), m, k))[:, :n]
+
+
 def build_bloom(key_reps: np.ndarray, m: int, k: int) -> np.ndarray:
     """int64 key reps [n] -> packed bit array as uint64 words [m/64]."""
     if len(key_reps) == 0:
         return np.zeros(m // 64, dtype=np.uint64)
-    words = split_words_np(key_reps[None, :])
-    idx = np.asarray(_bit_indices(jnp.asarray(words), m, k)).ravel()
+    idx = bit_indices_np(key_reps, m, k).ravel()
     bits = np.zeros(m, dtype=bool)
     bits[idx] = True
     return np.packbits(bits, bitorder="little").view(np.uint64)
@@ -59,6 +75,4 @@ def might_contain(bloom_words: np.ndarray, key_reps: np.ndarray, m: int, k: int)
     bits = np.unpackbits(
         bloom_words.view(np.uint8), bitorder="little", count=m
     ).astype(bool)
-    words = split_words_np(key_reps[None, :])
-    idx = np.asarray(_bit_indices(jnp.asarray(words), m, k))  # [k, n]
-    return bits[idx].all(axis=0)
+    return bits[bit_indices_np(key_reps, m, k)].all(axis=0)

@@ -64,6 +64,13 @@ class _Prep:
             spec = ("col", vals, valid, "string", name)
             self._col_slots[name] = (spec, ref)
             return self._col_slots[name]
+        if col.values.dtype == np.float64:
+            # The TPU has no IEEE double: a float64 it holds keeps
+            # float32's exponent range and fewer mantissa bits (1e300
+            # arrives as inf, 3.0000000000000004 as 3.0 — chip run, PR
+            # 21), so a compare against such a column cannot give the
+            # host's mask. The host evaluates it.
+            raise Unsupported(f"float64 column on device: {name!r}")
         vals = self._arg(col.values, per_row=True)
         valid = (
             -1 if col.validity is None else self._arg(col.validity, per_row=True)

@@ -28,14 +28,16 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 import hyperspace_tpu.ops  # noqa: F401  (enables x64)
-from hyperspace_tpu.parallel.mesh import SHARD_AXIS
+from hyperspace_tpu.parallel.mesh import (
+    SHARD_AXIS,
+    mesh_dispatch_lock,
+    put_sharded,
+)
 
-try:  # jax >= 0.6
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+# numpy, not jnp: a module-level jnp scalar initializes the JAX backend —
+# and so takes the chip — when this module is merely imported
+_PAD = np.int64(0x7FFFFFFFFFFFFFFF)
 
-_PAD = jnp.int64(0x7FFFFFFFFFFFFFFF)
 
 def combine_reps_np(reps: np.ndarray) -> np.ndarray:
     """[k, n] int64 -> [n] int64: splitmix64 mix of the composite key
@@ -172,7 +174,7 @@ _vmapped = jax.vmap(_bucket_join, in_axes=(0, 0, 0, 0))
 
 @functools.partial(jax.jit, static_argnames=("mesh",))
 def _sharded_join(mesh, l_rep, l_len, r_rep, r_len):
-    return shard_map(
+    return jax.shard_map(
         _vmapped,
         mesh=mesh,
         in_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS)),
@@ -189,11 +191,10 @@ def _match_ranges_host(l_rep, l_len, r_rep, r_len):
     and transfer latency."""
     B, n = l_rep.shape
     m = r_rep.shape[1]
-    pad = np.int64(0x7FFFFFFFFFFFFFFF)
     col_l = np.arange(n)[None, :]
     col_r = np.arange(m)[None, :]
-    l_key = np.where(col_l < l_len[:, None], l_rep, pad)
-    r_key = np.where(col_r < r_len[:, None], r_rep, pad)
+    l_key = np.where(col_l < l_len[:, None], l_rep, _PAD)
+    r_key = np.where(col_r < r_len[:, None], r_rep, _PAD)
     perm_l = np.argsort(l_key, axis=1, kind="stable")
     perm_r = np.argsort(r_key, axis=1, kind="stable")
     ls = np.take_along_axis(l_key, perm_l, axis=1)
@@ -231,14 +232,14 @@ def bucketed_match_ranges(
     )
     if not use_mesh and total < device_min_rows:
         return _match_ranges_host(l_rep, l_len, r_rep, r_len)
-    args = (
-        jnp.asarray(l_rep),
-        jnp.asarray(l_len),
-        jnp.asarray(r_rep),
-        jnp.asarray(r_len),
-    )
+    host_args = (l_rep, l_len, r_rep, r_len)
     if use_mesh:
-        out = _sharded_join(mesh, *args)
+        # each device receives only its own bucket rows, straight from
+        # the host — jnp.asarray would land every operand whole on
+        # device 0 before the program reshards it
+        args = tuple(put_sharded(mesh, a) for a in host_args)
+        with mesh_dispatch_lock:
+            out = _sharded_join(mesh, *args)
     else:
-        out = _jit_vmapped(*args)
+        out = _jit_vmapped(*(jnp.asarray(a) for a in host_args))
     return tuple(np.asarray(o) for o in out)

@@ -15,11 +15,12 @@ processes x 4 CPU devices.
 
 from __future__ import annotations
 
-import os
+import threading
 from typing import Optional, Sequence
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 SHARD_AXIS = "shard"
 # hierarchical mesh axes: DCN (cross-host) outer, ICI (intra-host) inner
@@ -27,6 +28,26 @@ DCN_AXIS = "dcn"
 ICI_AXIS = "ici"
 
 _DISTRIBUTED_INITIALIZED = False
+
+# Held while a multi-device program is ENQUEUED (not while it runs). A
+# program spanning several devices is launched device by device; two host
+# threads launching two such programs at once (serve workers running the
+# sharded join, a build's exchange, the calibration probe's exchanges) can
+# enqueue them in different orders on different devices, and collectives
+# that wait for each other in crossed order never finish. One lock makes
+# every device see one launch order. Single-device programs need none.
+mesh_dispatch_lock = threading.Lock()
+
+
+def put_sharded(mesh: jax.sharding.Mesh, array, spec=None):
+    """Host array -> device array split over the leading axis of ``mesh``
+    (or by ``spec``), each shard transferred from the host to its own
+    device. ``jnp.asarray`` would instead put the whole array on device 0
+    and leave the program to reshard it — a first-chip pile-up of every
+    operand at build sizes."""
+    if spec is None:
+        spec = PartitionSpec(mesh.axis_names[0])
+    return jax.device_put(array, NamedSharding(mesh, spec))
 
 
 def initialize_distributed(
@@ -62,20 +83,7 @@ def initialize_distributed(
             f"none of them (auto-detected TPU pod); got {explicit}"
         )
     if cpu_local_devices is not None:
-        try:
-            jax.config.update("jax_num_cpu_devices", int(cpu_local_devices))
-        except AttributeError:
-            # older jax: the option predates jax_num_cpu_devices — fall
-            # back to the XLA flag, honored as long as no backend has
-            # been initialized yet (this function's contract: call
-            # before any session / device use)
-            flags = os.environ.get("XLA_FLAGS", "")
-            if "xla_force_host_platform_device_count" not in flags:
-                os.environ["XLA_FLAGS"] = (
-                    flags
-                    + " --xla_force_host_platform_device_count="
-                    + str(int(cpu_local_devices))
-                ).strip()
+        jax.config.update("jax_num_cpu_devices", int(cpu_local_devices))
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
     kwargs = {}
     if coordinator_address is not None:

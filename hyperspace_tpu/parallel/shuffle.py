@@ -97,12 +97,13 @@ _skew_warned: bool = False
 
 from hyperspace_tpu.ops.hash import bucket_ids_host
 from hyperspace_tpu.ops.sort import partition_by_bucket
-from hyperspace_tpu.parallel.mesh import DCN_AXIS, ICI_AXIS, SHARD_AXIS
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from hyperspace_tpu.parallel.mesh import (
+    DCN_AXIS,
+    ICI_AXIS,
+    SHARD_AXIS,
+    mesh_dispatch_lock,
+    put_sharded,
+)
 
 STRATEGY_AUTO = "auto"
 STRATEGY_FLAT = "flat"
@@ -357,7 +358,7 @@ def _flat_program(mesh, bucket_host, valid, payloads, num_buckets, num_payload, 
             tuple(c[perm] for c in flat_cols),
         )
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS)),
@@ -402,15 +403,17 @@ def _flat_exchange(mesh, key_reps, payloads, num_buckets, seed):
     cap, counts = _flat_cap(bucket_host, valid, D)
     pack_s = _time.perf_counter() - t0
     t0 = _time.perf_counter()
-    bucket, vmask, cols = _flat_program(
-        mesh,
-        jnp.asarray(bucket_host),
-        jnp.asarray(valid),
-        tuple(jnp.asarray(p) for p in payloads),
-        num_buckets,
-        len(payloads),
-        cap,
+    # operands go host -> owning device shard by shard (put_sharded);
+    # the program's in_specs then find them already in place
+    operands = (
+        put_sharded(mesh, bucket_host),
+        put_sharded(mesh, valid),
+        tuple(put_sharded(mesh, p) for p in payloads),
     )
+    with mesh_dispatch_lock:
+        bucket, vmask, cols = _flat_program(
+            mesh, *operands, num_buckets, len(payloads), cap
+        )
     bucket = np.asarray(bucket)
     vmask = np.asarray(vmask)
     exchange_s = _time.perf_counter() - t0
@@ -531,7 +534,7 @@ def _compact_program(mesh, payloads):
             lax.all_to_all(c, SHARD_AXIS, 0, 0, tiled=True) for c in cols
         )
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(P(SHARD_AXIS),), out_specs=P(SHARD_AXIS)
     )(payloads)
 
@@ -568,10 +571,9 @@ def _compact_exchange(mesh, key_reps, payloads, num_buckets, seed):
         sends.append(buf.reshape(D * D, cap))
     pack_s = _time.perf_counter() - t0
     t0 = _time.perf_counter()
-    out = _compact_program(
-        mesh,
-        tuple(jnp.asarray(s) for s in sends),
-    )
+    operands = tuple(put_sharded(mesh, s) for s in sends)
+    with mesh_dispatch_lock:
+        out = _compact_program(mesh, operands)
     flats = [np.asarray(o).reshape(-1) for o in out]
     exchange_s = _time.perf_counter() - t0
     t0 = _time.perf_counter()
@@ -625,7 +627,7 @@ def _twostage_program(hmesh, payloads, caps):
 
         return tuple(route(c) for c in cols)
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=hmesh,
         in_specs=(P(DCN_AXIS, ICI_AXIS),),
@@ -698,9 +700,9 @@ def _twostage_exchange_mp(mesh, key_reps, payloads, num_buckets, seed):
     pack_s = _time.perf_counter() - t0
     t0 = _time.perf_counter()
     hmesh = hierarchical_view(mesh, H)
-    out = _twostage_program(
-        hmesh, tuple(_process_local_operand(hmesh, s) for s in sends), caps
-    )
+    operands = tuple(_process_local_operand(hmesh, s) for s in sends)
+    with mesh_dispatch_lock:
+        out = _twostage_program(hmesh, operands, caps)
     local = []
     for arr in out:
         shards = sorted(arr.addressable_shards, key=lambda s: s.index)
@@ -834,11 +836,11 @@ def _twostage_exchange(mesh, key_reps, payloads, num_buckets, seed, hosts):
     pack_s = _time.perf_counter() - t0
     t0 = _time.perf_counter()
     hmesh = hierarchical_view(mesh, H)
-    out = _twostage_program(
-        hmesh,
-        tuple(jnp.asarray(s) for s in sends),
-        caps,
+    operands = tuple(
+        put_sharded(hmesh, s, P(DCN_AXIS, ICI_AXIS)) for s in sends
     )
+    with mesh_dispatch_lock:
+        out = _twostage_program(hmesh, operands, caps)
     flats = [np.asarray(o).reshape(-1) for o in out]
     exchange_s = _time.perf_counter() - t0
     t0 = _time.perf_counter()
@@ -935,7 +937,16 @@ def bucket_shuffle(
     device-local past the exchange. A peer that owns no rows gets an
     empty extent.
     """
-    payloads = list(payloads)
+    # Floats cross the exchange as integers of their own width. The
+    # exchange moves bytes and must never interpret them: the TPU has no
+    # IEEE double (a float64 it holds keeps float32's exponent range and
+    # fewer mantissa bits — chip run, PR 21), while 64-bit integers are
+    # carried exactly.
+    dtypes = [p.dtype for p in payloads]
+    payloads = [
+        p.view(f"i{p.dtype.itemsize}") if p.dtype.kind == "f" else p
+        for p in payloads
+    ]
     name = resolve_strategy(strategy, mesh, key_reps.shape[1])
     if name == STRATEGY_FLAT:
         bucket, cols, offsets = _flat_exchange(
@@ -953,6 +964,7 @@ def bucket_shuffle(
         bucket, cols, offsets = _twostage_exchange(
             mesh, key_reps, payloads, num_buckets, seed, twostage_hosts
         )
+    cols = [c.view(dt) for c, dt in zip(cols, dtypes)]
     if with_shard_offsets:
         return bucket, cols, offsets
     return bucket, cols

@@ -140,19 +140,17 @@ class HyperspaceSession:
         # compile (~2s, cached per machine) then lands during session
         # setup instead of inside the first large sort or join; hot paths
         # use load(wait=False) and fall back to numpy until it finishes.
-        # The same thread then warms the dispatch-calibration probe
-        # (native/calibrate.py) — a once-per-machine microbenchmark whose
-        # JSON cache lives next to the .so, so later sessions only read
-        # a file. Until it lands, dispatch uses the fallback constants.
+        # Host work only: this thread never touches JAX. The dispatch-
+        # calibration probe (native/calibrate.py) runs device programs —
+        # collectives, on a mesh — so it runs on the thread of the first
+        # dispatch that asks for thresholds(), in sequence with that
+        # thread's own device work, not here beside it (and no daemon
+        # thread can be inside an XLA compile when the interpreter exits).
         from hyperspace_tpu import native
 
-        def _warm():
-            native.load()
-            from hyperspace_tpu.native import calibrate
-
-            calibrate.thresholds()
-
-        threading.Thread(target=_warm, daemon=True).start()
+        threading.Thread(
+            target=native.load, name="hs-native-warm", daemon=True
+        ).start()
 
     # -- context (HyperspaceContext, Hyperspace.scala:195-223) --------------
     @property

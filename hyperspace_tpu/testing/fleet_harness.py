@@ -334,11 +334,19 @@ def _run_probes(session, fe, src: str) -> Tuple[int, int]:
 # ---------------------------------------------------------------------------
 
 
+# Fleet workers serve from index files on the host and never need a
+# chip; the parent (bench.py, a test) may hold one, and a chip belongs to
+# one process. So the platform is SET for every worker — not defaulted —
+# and recorded in the result row so the ladder's QPS is never read as a
+# chip number.
+WORKER_PLATFORM = "cpu"
+
+
 def _spawn_worker(spec: dict, spec_path: str) -> subprocess.Popen:
     with open(spec_path, "w", encoding="utf-8") as fh:
         json.dump(spec, fh)
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = WORKER_PLATFORM
     pkg_root = os.path.dirname(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     )
@@ -518,6 +526,7 @@ def run_fleet(
     leaked_fast = _converge_fast_members(index_root)
     return {
         "processes": n_procs,
+        "worker_platform": WORKER_PLATFORM,
         "workers_reporting": len(results),
         "killed": bool(kill_one),
         "queries": total_served,

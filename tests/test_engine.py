@@ -98,9 +98,15 @@ class TestDeviceFilter:
 
     @pytest.mark.parametrize("mk", EXPRS)
     def test_device_matches_host(self, batch, mk):
-        from hyperspace_tpu.ops.filter import device_filter_mask
+        from hyperspace_tpu.ops.filter import Unsupported, device_filter_mask
 
         e = mk()
+        if "v" in E.references(e):
+            # float64 columns are the host's: the TPU holds no IEEE
+            # double, so its compare is not the host's compare
+            with pytest.raises(Unsupported, match="float64"):
+                device_filter_mask(e, batch)
+            return
         np.testing.assert_array_equal(
             device_filter_mask(e, batch), E.filter_mask(e, batch)
         )

@@ -132,6 +132,48 @@ class TestStrategyDifferential:
             np.testing.assert_array_equal(a, b)
         assert sh.last_shuffle_stats["hosts"] == float(hosts)
 
+    @pytest.mark.parametrize(
+        "strategy", [sh.STRATEGY_FLAT, sh.STRATEGY_COMPACT, sh.STRATEGY_TWOSTAGE]
+    )
+    def test_floats_cross_the_device_as_integers(self, strategy, monkeypatch):
+        """No float dtype reaches a device exchange program, and float
+        payloads come back bit for bit — including the values a TPU
+        mangles when it is handed them as float64 (it holds no IEEE
+        double: 1e300 -> inf, -0.0 -> 0.0, a NaN's payload bits lost)."""
+        seen = []
+        for prog in ("_flat_program", "_compact_program", "_twostage_program"):
+            real = getattr(sh, prog)
+
+            def spy(*args, _real=real, **kw):
+                seen.extend(
+                    np.dtype(leaf.dtype)
+                    for leaf in jax.tree_util.tree_leaves(args)
+                    if hasattr(leaf, "dtype")
+                )
+                return _real(*args, **kw)
+
+            monkeypatch.setattr(sh, prog, spy)
+        rng = np.random.default_rng(5)
+        n = 4096
+        f64 = rng.normal(size=n)
+        f64[:6] = [1e300, -1e300, 5e-324, -0.0, 3.0000000000000004, np.inf]
+        f64[6:8] = np.array(
+            [0x7FF8000000000123, 0xFFF0000000000001], dtype=np.uint64
+        ).view(np.float64)  # NaNs with payload bits
+        f32 = rng.normal(size=n).astype(np.float32)
+        keys = rng.integers(0, 97, (1, n)).astype(np.int64)
+        ids, (got64, got32) = sh.bucket_shuffle(
+            _mesh(8), keys, [f64, f32], 16, strategy=strategy, twostage_hosts=2
+        )
+        assert seen and not [d for d in seen if d.kind == "f"], seen
+        assert got64.dtype == np.float64 and got32.dtype == np.float32
+        _ids, (ref64, ref32) = sh.bucket_shuffle(
+            _mesh(8), keys, [f64, f32], 16, strategy=sh.STRATEGY_HOST
+        )
+        np.testing.assert_array_equal(got64.view(np.uint64), ref64.view(np.uint64))
+        np.testing.assert_array_equal(got32.view(np.uint32), ref32.view(np.uint32))
+        assert sorted(got64.view(np.uint64)) == sorted(f64.view(np.uint64))
+
     def test_canonical_order_is_flat_order(self):
         """The host-side permutation equals the naive (owner, bucket,
         row) lexsort — the invariant every non-flat strategy rides."""

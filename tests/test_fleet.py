@@ -834,6 +834,9 @@ class TestFleetProcesses:
             fastpath_phase=True,
         )
         assert rep["wrong_answers"] == 0
+        # workers never open a chip the parent may hold, and the row says
+        # where its QPS came from
+        assert rep["worker_platform"] == "cpu"
         # cross-process dedup now lands on the fast plane first (owner
         # handoffs / result-cache hits); the spool remains the fallback
         dedup = (

@@ -258,24 +258,3 @@ def test_shuffle_cap_bounds_memory_and_preserves_rows():
     reps_pad[:, n // 2 :] = 0  # pads all hash to one dest — must not matter
     cap_pad = _exchange_cap(reps_pad, valid_half, D * 4, D, 42)
     assert cap_pad < n_local // 2, cap_pad
-
-
-class TestPallasHashKernel:
-    def test_pallas_matches_host_twin(self):
-        """The Pallas murmur3 kernel (interpret mode on CPU) is bit-exact
-        against the numpy twin — same contract as the XLA kernel."""
-        import numpy as np
-        import jax.numpy as jnp
-
-        from hyperspace_tpu.ops.hash import (
-            _PALLAS_BLOCK_N,
-            bucket_ids_host,
-            bucket_ids_pallas,
-            split_words_np,
-        )
-
-        rng = np.random.default_rng(3)
-        n = _PALLAS_BLOCK_N
-        reps = rng.integers(-(2**62), 2**62, (2, n)).astype(np.int64)
-        out = np.asarray(bucket_ids_pallas(jnp.asarray(split_words_np(reps)), 8))
-        assert np.array_equal(out, bucket_ids_host(reps, 8))
