@@ -1,0 +1,24 @@
+"""Device time of one XLA module, from the profiler's trace.
+
+arg: {"module": <name up to the "(">, "kind": <operation kind>,
+"stat": "seconds_per_op"} -> device seconds of the module per operation
+of the window; {"stat": "roofline", "bytes_fn": <function of roofline.py>}
+-> the least time the chip could take for the rows of those operations,
+over the module's device time, in %. Nothing where the module never ran.
+"""
+
+import roofline
+import trace_reduce
+
+
+def read(record: dict, arg: dict):
+    if record["trace"] is None:
+        return None
+    seconds, runs = trace_reduce.module_seconds(record["trace"], arg["module"])
+    ops = [o for o in record["ops"] if o["kind"] == arg["kind"]]
+    if not seconds or not ops:
+        return None
+    if arg["stat"] == "seconds_per_op":
+        return seconds / len(ops)
+    n_bytes = getattr(roofline, arg["bytes_fn"])(record["rows"]) * len(ops)
+    return 100.0 * roofline.least_seconds(n_bytes, record["device"]["kind"]) / seconds

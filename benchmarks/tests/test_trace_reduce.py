@@ -1,0 +1,48 @@
+"""The reduction from a trace to numbers, on a small recorded trace."""
+
+import json
+import os
+
+import pytest
+
+import trace_reduce as tr
+from conftest import HERE
+
+
+@pytest.fixture(scope="module")
+def trace():
+    with open(os.path.join(HERE, "data", "small_trace.json")) as f:
+        return json.load(f)
+
+
+def test_interval_arithmetic():
+    u = tr.union([[5, 7], [0, 2], [1, 3], [7, 8], [9, 9]])
+    assert u == [[0, 3], [5, 8]] and tr.total(u) == 6
+    assert tr.complement(u, 0, 10) == [[3, 5], [8, 10]]
+    assert tr.overlap(u, [[2, 6], [7, 20]]) == 3
+
+
+def test_busy_is_a_union_not_a_sum(trace):
+    # device 0: [1000,1600) + [5000,5400) + [8000,8100); ops overlap 1200-1300
+    assert tr.busy_seconds(trace) == pytest.approx([1100e-9, 100e-9])
+
+
+def test_module_time_by_name_up_to_the_run_id(trace):
+    seconds, runs = tr.module_seconds(trace, "jit__bucket_ids_words")
+    assert (seconds, runs) == (pytest.approx(1000e-9), 2)
+    assert tr.module_seconds(trace, "jit_never_ran") == (None, 0)
+
+
+def test_top_ops_and_gap_attribution(trace):
+    ops = dict(tr.top_device_ops(trace))
+    assert ops["add_xor_fusion"] == pytest.approx(700e-9)
+    assert ops["all-to-all"] == pytest.approx(100e-9)
+    assert ops["custom-call:X64SplitHigh"] == pytest.approx(100e-9)
+    gaps = dict(tr.idle_gaps(trace, 10000))
+    # busiest device idle: 10000 - 1100 = 8900 ns; create_index covers
+    # [0,6000)+[7000,9000) of which busy 1000+100
+    assert gaps["bench.create_index"] == pytest.approx((8000 - 1100) * 1e-9)
+    assert gaps["bench.delete_vacuum"] == pytest.approx(1000e-9)
+    assert gaps["bench.serve"] == pytest.approx((1000 - 500) * 1e-9)
+    assert gaps[tr.NO_SPAN] == pytest.approx(1000e-9)
+    assert len(tr.describe(trace)) == 5
