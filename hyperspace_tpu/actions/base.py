@@ -156,11 +156,19 @@ class Action(abc.ABC):
 
     # -- driver (Action.run:84-105 + recovery/retry) ------------------------
     def run(self) -> None:
-        """Obs wrapper around the protocol: one ROOT span per lifecycle
-        action (child stage spans — scan/shuffle/sort/write/
-        sidecar_capture/log_commit — attach via the build breakdown
-        hooks), finished whatever the outcome, so every action is
-        explainable after the fact (docs/observability.md)."""
+        """The protocol under one ROOT span, ``action.<Class>``: the
+        action's account, recorded whatever ``hyperspace.obs.enabled``
+        says (that switch gates the serve plane) and finished whatever
+        the outcome, so every action is explainable after the fact
+        (docs/observability.md). Its DIRECT children are the protocol
+        steps — ``validate`` (recovery repair + re-snapshot +
+        ``validate()``), ``begin_log``, ``log_entry``, ``log_commit``,
+        ``publish_event`` — and whatever stages ``op()`` records through
+        ``covering_build.stage`` (a covering create: ``resolve``,
+        ``scan``, ``hash_shuffle``, ``dict_probe``, ``sort``, ``write``,
+        ``sidecar_capture``). ``op()`` itself has no span: what the
+        root's children leave uncovered IS the unnamed time
+        (``root.duration_s - root.children_union_s()``)."""
         # configure, not just set_enabled: action-only processes (build
         # workers with no frontend) must still honor the trace bounds
         obs_trace.configure(self.session.conf)
@@ -168,7 +176,7 @@ class Action(abc.ABC):
             getattr(self, "index_config", None), "index_name", ""
         )
         root = obs_trace.root(
-            f"action.{type(self).__name__}", index=str(index_name)
+            f"action.{type(self).__name__}", always=True, index=str(index_name)
         )
         with obs_trace.activate(root):
             try:
@@ -203,19 +211,24 @@ class Action(abc.ABC):
             # stranded transient tip rolls back (appending an entry), a
             # stale latestStable pointer heals — then the snapshot below
             # sees the repaired log
-            if recovery_on:
-                recovery.ensure_recovered(self.log_manager, lease_ms)
-            self._resnapshot()
             try:
-                self.validate()
+                with obs_trace.span("validate"):
+                    if recovery_on:
+                        recovery.ensure_recovered(self.log_manager, lease_ms)
+                    self._resnapshot()
+                    self.validate()
             except NoChangesException:
                 self._log_event(True, "No-op action")
                 return
-            begin = self.begin_log_entry().with_state(self.transient_state)
-            if recovery_on:
-                recovery.stamp_lease(begin, owner, lease_ms)
-            begin.id = self.base_id + 1
-            if _publish_log(self.log_manager, self.base_id + 1, begin):
+            with obs_trace.span("begin_log"):
+                begin = self.begin_log_entry().with_state(self.transient_state)
+                if recovery_on:
+                    recovery.stamp_lease(begin, owner, lease_ms)
+                begin.id = self.base_id + 1
+                published = _publish_log(
+                    self.log_manager, self.base_id + 1, begin
+                )
+            if published:
                 break
             if attempt >= attempts:
                 raise ConcurrentWriteException(
@@ -232,9 +245,10 @@ class Action(abc.ABC):
         try:
             self.op()
             faults.crash("after_data_write", type(self).__name__)
-            with obs_trace.span("log_commit"):
+            with obs_trace.span("log_entry"):
                 final = self.log_entry().with_state(self.final_state)
                 final.id = self.base_id + 2
+            with obs_trace.span("log_commit"):
                 if not _publish_log(self.log_manager, self.base_id + 2, final):
                     # the end id exists already: a cancel()/recovery
                     # rolled our transient entry back under us — the
@@ -253,8 +267,9 @@ class Action(abc.ABC):
             # thread dies with it, and the lease starts aging
             if heartbeat is not None:
                 heartbeat.stop()
-        self._publish_fleet_event(final)
-        self._log_event(True)
+        with obs_trace.span("publish_event"):
+            self._publish_fleet_event(final)
+            self._log_event(True)
 
     def _rendezvous_step(self, step: str, fn) -> int:
         """Run one protocol step locally, then rendezvous on its
@@ -302,8 +317,9 @@ class Action(abc.ABC):
         self._rendezvous_step("recovered", repair)
 
         def snapshot_validate():
-            self._resnapshot()
-            self.validate()
+            with obs_trace.span("validate"):
+                self._resnapshot()
+                self.validate()
 
         if self._rendezvous_step("validate", snapshot_validate) == _STEP_NOOP:
             self._log_event(True, "No-op action")
@@ -315,15 +331,16 @@ class Action(abc.ABC):
             # only now may the transient entry appear — every worker
             # has finished validating (the rendezvous above), so none
             # can mistake our own begin entry for a concurrent writer
-            begin = self.begin_log_entry().with_state(self.transient_state)
-            if recovery_on:
-                recovery.stamp_lease(begin, owner, lease_ms)
-            begin.id = self.base_id + 1
-            if not _publish_log(self.log_manager, self.base_id + 1, begin):
-                raise ConcurrentWriteException(
-                    f"Another operation is in progress (log id "
-                    f"{self.base_id + 1} already exists)"
-                )
+            with obs_trace.span("begin_log"):
+                begin = self.begin_log_entry().with_state(self.transient_state)
+                if recovery_on:
+                    recovery.stamp_lease(begin, owner, lease_ms)
+                begin.id = self.base_id + 1
+                if not _publish_log(self.log_manager, self.base_id + 1, begin):
+                    raise ConcurrentWriteException(
+                        f"Another operation is in progress (log id "
+                        f"{self.base_id + 1} already exists)"
+                    )
             begin_box.append(begin)
 
         self._rendezvous_step("begin", begin_write)
@@ -335,9 +352,10 @@ class Action(abc.ABC):
             ).start()
         try:
             self.op()
-            with obs_trace.span("log_commit"):
+            with obs_trace.span("log_entry"):
                 final = self.log_entry().with_state(self.final_state)
                 final.id = self.base_id + 2
+            with obs_trace.span("log_commit"):
                 if not _publish_log(self.log_manager, self.base_id + 2, final):
                     raise ConcurrentWriteException(
                         f"Concurrent write at log id {self.base_id + 2}"
@@ -351,8 +369,9 @@ class Action(abc.ABC):
                 heartbeat.stop()
         # coordinator-only, like every other metadata-plane write: the
         # fanout is plain file I/O, one publisher per action
-        self._publish_fleet_event(final)
-        self._log_event(True)
+        with obs_trace.span("publish_event"):
+            self._publish_fleet_event(final)
+            self._log_event(True)
 
     def _run_data_plane(self) -> None:
         """The non-coordinator replica of :meth:`_run_coordinated`: the
@@ -366,8 +385,9 @@ class Action(abc.ABC):
         def snapshot_validate():
             # ordered AFTER the coordinator's recovery repair by the
             # rendezvous above: both sides validate the repaired log
-            self._resnapshot()
-            self.validate()
+            with obs_trace.span("validate"):
+                self._resnapshot()
+                self.validate()
 
         if self._rendezvous_step("validate", snapshot_validate) == _STEP_NOOP:
             self._log_event(True, "No-op action")

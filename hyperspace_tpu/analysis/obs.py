@@ -12,7 +12,9 @@ style (KERNEL_TWINS / SHARED_STATE / COLLECTIVE_SITES): every site is
 in ``OBS_SITES`` (``obs/sites.py``) with a one-line justification.
 
 * HS901 — a call that creates spans (``trace.root`` / ``trace.span`` /
-  ``trace.stage``) or registers metrics (``registry.counter`` /
+  ``trace.stage``, or the build plane's one stage hook
+  ``covering_build.stage`` — ``stage(...)`` inside that module) or
+  registers metrics (``registry.counter`` /
   ``gauge`` / ``labeled_counter`` / ``stage_timer`` /
   ``register_view`` / ``register_weak_view``) whose outermost
   enclosing function (or module, for import-time registration) has no
@@ -21,7 +23,8 @@ in ``OBS_SITES`` (``obs/sites.py``) with a one-line justification.
   ``activate``) and point events (``trace.event``) are exempt — they
   create no spans.
 * HS902 — a CONSTANT span/stage name passed to ``trace.span`` /
-  ``trace.stage`` that is not in the declared stage vocabulary
+  ``trace.stage`` / ``covering_build.stage`` that is not in the
+  declared stage vocabulary
   (the ``*_STAGES`` tuples in ``obs/sites.py``), or a constant
   ``trace.root`` name not in ``ROOT_NAMES``: stage spans exist to
   mirror the breakdown keys — a drifted name forks the taxonomy.
@@ -73,6 +76,10 @@ METRIC_PRIMS = frozenset(
     }
 )
 _TRACE_BASES = frozenset({"trace", "obs_trace", "_obs_trace"})
+#: the build plane's stage hook: ``covering_build.stage(...)`` from
+#: outside, a bare ``stage(...)`` inside the module that defines it
+_BUILD_HOOK_BASES = frozenset({"covering_build"})
+_BUILD_HOOK_FILE = "indexes/covering_build.py"
 _METRIC_BASES = frozenset(
     {"registry", "metrics", "obs_metrics", "_obs_metrics"}
 )
@@ -185,10 +192,14 @@ class _Call:
     const_name: Optional[str]  # constant first arg, when present
 
 
-def _is_obs_call(node: ast.Call) -> Optional[str]:
+def _is_obs_call(node: ast.Call, rel: str = "") -> Optional[str]:
     """The primitive name when this call is an obs span/metric
     primitive, else None."""
     f = node.func
+    if isinstance(f, ast.Name):
+        if f.id == "stage" and rel.endswith(_BUILD_HOOK_FILE):
+            return "stage"
+        return None
     if not isinstance(f, ast.Attribute):
         return None
     base = dotted_name(f.value)
@@ -196,6 +207,8 @@ def _is_obs_call(node: ast.Call) -> Optional[str]:
         return None
     last = base.rsplit(".", 1)[-1]
     if f.attr in TRACE_PRIMS and last in _TRACE_BASES:
+        return f.attr
+    if f.attr == "stage" and last in _BUILD_HOOK_BASES:
         return f.attr
     if f.attr in METRIC_PRIMS and last in _METRIC_BASES:
         return f.attr
@@ -227,7 +240,7 @@ def _scan_calls(project: Project) -> List[_Call]:
                 elif isinstance(child, ast.ClassDef) and depth == 0:
                     child_cls = child.name
                 elif isinstance(child, ast.Call):
-                    prim = _is_obs_call(child)
+                    prim = _is_obs_call(child, rel)
                     if prim is not None:
                         cname = (
                             const_str(child.args[0]) if child.args else None

@@ -244,8 +244,9 @@ SHARED_STATE: Dict[str, Tuple[str, str, str]] = {
     "hyperspace_tpu.obs.trace._enabled": (
         "",
         "rebind-only",
-        "the process-global tracing switch: plain bool rebinds; a racy "
-        "read costs one span (recorded or skipped), never a torn value",
+        "the process-global serve-plane tracing switch: plain bool "
+        "rebinds; a racy read costs one serve trace (recorded or "
+        "skipped), never a torn value",
     ),
     "hyperspace_tpu.obs.trace._max_spans": (
         "",

@@ -471,13 +471,14 @@ RECOVERY_RETRY_BACKOFF_MS_DEFAULT = 10
 HYPERSPACE_QUARANTINE_DIR = "_hyperspace_quarantine"
 
 # -- observability plane (hyperspace_tpu/obs/, docs/observability.md) --------
-# Master switch for structured tracing + the durable query log: every
-# query through the serve frontend and every lifecycle action gets ONE
-# root span with child stage spans mirroring the legacy breakdown keys,
-# and each served query appends one JSONL record to the _hyperspace_obs/
-# sidecar next to the lake. Off (the default) = the zero-cost path:
-# every obs call site degrades to a single module-bool check and the
-# serve/build behavior is bit-identical to the pre-obs tree.
+# The SERVE-plane switch for structured tracing + the durable query
+# log: every query through the serve frontend gets ONE root span with
+# child stage spans mirroring the breakdown keys, and each served query
+# appends one JSONL record to the _hyperspace_obs/ sidecar next to the
+# lake. Off (the default) = the zero-cost serve path: no span is live
+# there, every obs call site costs one ContextVar.get, and serve
+# behavior is bit-identical to the pre-obs tree. Lifecycle-action traces
+# (action.<Class> roots) are recorded whatever this says.
 OBS_ENABLED = "hyperspace.obs.enabled"
 OBS_ENABLED_DEFAULT = False
 

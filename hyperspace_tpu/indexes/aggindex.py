@@ -62,7 +62,6 @@ import pyarrow.compute as pa_compute
 import pyarrow.parquet as pq
 
 from hyperspace_tpu import constants as C
-from hyperspace_tpu.obs import trace as _obs_trace
 from hyperspace_tpu.testing import faults
 
 _log = logging.getLogger("hyperspace_tpu.aggindex")
@@ -226,6 +225,7 @@ def file_agg_doc(
     max_groups: int = C.INDEX_AGG_MAX_GROUPS_DEFAULT,
     sample_rows: int = C.INDEX_AGG_SAMPLE_ROWS_DEFAULT,
     group_keys: Optional[Tuple[str, ...]] = None,
+    read_s_out: Optional[List[float]] = None,
 ) -> Tuple[dict, Optional[pa.Table]]:
     """(sidecar entry, stratified sample table) for ONE index data file,
     computed from the file itself — the single definition shared by
@@ -238,7 +238,9 @@ def file_agg_doc(
     (lowercase match): the serve-path backfill passes the ONE key the
     query groups by, so a first serve over an unsidecar'd index pays one
     grouped sweep instead of one per numeric column; build-time capture
-    leaves it None (every fusable candidate)."""
+    leaves it None (every fusable candidate). ``read_s_out`` receives
+    the seconds spent reading and decoding each row group, so capture
+    can tell its read from its partials."""
     from hyperspace_tpu.execution import pipeline_compiler as PC
     from hyperspace_tpu.io.columnar import ColumnarBatch
 
@@ -269,8 +271,11 @@ def file_agg_doc(
         entry["groups"][c] = []
     samples: List[pa.Table] = []
     for gi in range(pf.metadata.num_row_groups):
+        t_read = _time.perf_counter()
         table = pf.read_row_group(gi)
         batch = ColumnarBatch.from_arrow(table)
+        if read_s_out is not None:
+            read_s_out.append(_time.perf_counter() - t_read)
         n = batch.num_rows
         entry["rg_rows"].append(n)
         pt = PC.partials_from_batch(_CaptureSpec((), ops), batch)
@@ -364,9 +369,22 @@ def capture_index_dir(dir_path: str, index, conf=None) -> bool:
         if conf is not None
         else C.INDEX_AGG_SAMPLE_ROWS_DEFAULT
     )
+    from hyperspace_tpu.indexes import covering_build
+
+    # build-tail I/O: one stage of the build's account. Its parts — the
+    # re-read of the files just written, their partials, the publish —
+    # are attrs of the one span, never a span per file; the work is a
+    # call of its own so that freeing the document is inside the stage
+    with covering_build.stage("sidecar_capture", sidecar="aggstate") as sp:
+        return _capture_files(dir_path, max_groups, sample_rows, sp)
+
+
+def _capture_files(
+    dir_path: str, max_groups: int, sample_rows: int, sp
+) -> bool:
+    from hyperspace_tpu.indexes import covering_build
     from hyperspace_tpu.io import parquet as pio
 
-    _t0 = _time.perf_counter()
     try:
         files = pio.list_format_files(dir_path, "parquet")
     except (OSError, KeyError):
@@ -375,14 +393,22 @@ def capture_index_dir(dir_path: str, index, conf=None) -> bool:
         return False
     doc: dict = {"version": _SIDECAR_VERSION, "files": {}}
     sample_tables: List[pa.Table] = []
+    read_s: List[float] = []
+    t_files = _time.perf_counter()
     for f in files:
-        entry, sample = file_agg_doc(f, max_groups, sample_rows)
+        entry, sample = file_agg_doc(
+            f, max_groups, sample_rows, read_s_out=read_s
+        )
         st = os.stat(f)
         entry["size"] = st.st_size
         entry["mtime_ns"] = st.st_mtime_ns
         doc["files"][os.path.basename(f)] = entry
         if sample is not None:
             sample_tables.append(sample)
+    t_publish = _time.perf_counter()
+    sp.set("files", len(files))
+    sp.set("read_s", round(sum(read_s), 6))
+    sp.set("partials_s", round(t_publish - t_files - sum(read_s), 6))
     side_path = os.path.join(dir_path, SIDECAR_NAME)
     tmp = os.path.join(dir_path, f".{SIDECAR_NAME}.tmp.{os.getpid()}")
     try:
@@ -398,6 +424,7 @@ def capture_index_dir(dir_path: str, index, conf=None) -> bool:
         except OSError:
             pass
         return False
+    written = [side_path]
     if sample_tables:
         sample_path = os.path.join(dir_path, SAMPLE_NAME)
         stmp = os.path.join(dir_path, f".{SAMPLE_NAME}.tmp.{os.getpid()}")
@@ -408,6 +435,7 @@ def capture_index_dir(dir_path: str, index, conf=None) -> bool:
             )
             faults.crash("mid_sidecar_publish", sample_path)
             os.replace(stmp, sample_path)
+            written.append(sample_path)
         except OSError:
             try:
                 os.unlink(stmp)
@@ -416,9 +444,9 @@ def capture_index_dir(dir_path: str, index, conf=None) -> bool:
     from hyperspace_tpu.utils.files import fsync_dir
 
     fsync_dir(dir_path)
-    # build-tail I/O outside every breakdown stage — span it so action
-    # traces have no unexplained tail (OBS_SITES-registered)
-    _obs_trace.stage("sidecar_capture", _t0)
+    covering_build.sidecar_published(
+        sp, written, _time.perf_counter() - t_publish
+    )
     return True
 
 

@@ -32,7 +32,9 @@ the previous index data minus deleted-lineage rows.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import os
 import threading as _threading
 import time as _time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -270,8 +272,6 @@ class CompositeScan:
 def per_file_materialized_bytes(files: Sequence[str], fmt: str) -> List[int]:
     """Per-file rough in-memory size: parquet uncompressed data size from
     footers; other formats via on-disk size with an expansion factor."""
-    import os
-
     if fmt in ("parquet", "delta", "iceberg"):
         import pyarrow.parquet as pq
 
@@ -374,53 +374,62 @@ def prepare_covering_index(ctx, source_df, config, properties: Dict[str, str]):
     from hyperspace_tpu.indexes.covering import CoveringIndex
 
     reset_build_breakdown()
-    rel = _single_relation(source_df)
-    indexed, included, lineage, schema_json = resolve_index_schema(
-        rel, config, properties
-    )
-    file_ids = None
-    if lineage:
-        # Key file ids by the PROVIDER's (path,size,mtime) view — the same
-        # keys create_metadata_relation records — or lineage ids and the
-        # log entry's ids diverge for lake sources (Delta mtimes come from
-        # the log, Iceberg pins mtime=0).
-        file_ids = {}
-        for path, size, mtime in source_file_infos(ctx.session, rel):
-            file_ids[path] = ctx.file_id_tracker.add_file(path, size, mtime)
-    index = CoveringIndex(
-        indexed_columns=indexed,
-        included_columns=included,
-        schema_json=schema_json,
-        num_buckets=ctx.session.conf.num_buckets,
-        properties=dict(properties),
-    )
-    budget = ctx.session.conf.build_memory_budget
-    sizes = per_file_materialized_bytes(rel.files, rel.fmt) if budget else None
-    scan = SourceScan(
-        files=tuple(rel.files),
-        fmt=rel.fmt,
-        columns=tuple(indexed + included),
-        file_ids=file_ids,
-        file_sizes=tuple(sizes) if sizes is not None else None,
-    )
+    with stage("resolve"):
+        rel = _single_relation(source_df)
+        indexed, included, lineage, schema_json = resolve_index_schema(
+            rel, config, properties
+        )
+        file_ids = None
+        if lineage:
+            # Key file ids by the PROVIDER's (path,size,mtime) view — the
+            # same keys create_metadata_relation records — or lineage ids
+            # and the log entry's ids diverge for lake sources (Delta
+            # mtimes come from the log, Iceberg pins mtime=0).
+            file_ids = {}
+            for path, size, mtime in source_file_infos(ctx.session, rel):
+                file_ids[path] = ctx.file_id_tracker.add_file(
+                    path, size, mtime
+                )
+        index = CoveringIndex(
+            indexed_columns=indexed,
+            included_columns=included,
+            schema_json=schema_json,
+            num_buckets=ctx.session.conf.num_buckets,
+            properties=dict(properties),
+        )
+        budget = ctx.session.conf.build_memory_budget
+        sizes = (
+            per_file_materialized_bytes(rel.files, rel.fmt) if budget else None
+        )
+        scan = SourceScan(
+            files=tuple(rel.files),
+            fmt=rel.fmt,
+            columns=tuple(indexed + included),
+            file_ids=file_ids,
+            file_sizes=tuple(sizes) if sizes is not None else None,
+        )
     return index, scan
 
 
-# Per-stage wall times of the most recent build (scan/hash/sort/write),
-# reset at each create/refresh data op — the bench publishes these so the
-# throughput story names its bottleneck (SURVEY §7 hard part #4: measure
-# before moving parquet decode on-device). Under the sharded tail the
-# sort/write stages run per shard concurrently, so those values are BUSY
-# time summed across shards (may exceed wall time — the excess over
-# ``tail_wall`` is the sharding win); ``tail_shards`` records how many
-# shard tails ran.
+# Per-stage wall times of the most recent build, reset at each
+# create/refresh data op — the bench publishes these so the throughput
+# story names its bottleneck (SURVEY §7 hard part #4: measure before
+# moving parquet decode on-device). Keys are the names ``stage`` below
+# was entered with: the four long-standing ones (scan / hash_shuffle /
+# sort / write) and whatever else the op ran (resolve, dict_probe,
+# sidecar_capture, the exchange's pack / exchange / unpack). Under the
+# sharded tail the sort/write stages run per shard concurrently, so
+# those values are BUSY time summed across shards (may exceed wall time
+# — the excess over ``tail_wall`` is the sharding win); ``tail_shards``
+# records how many shard tails ran.
 #
 # Obs plane (docs/observability.md): this dict is the backing storage
 # of a REGISTERED instrument — ``registry.stage_timer`` below adopts
 # the exact dict + lock, so the registry's Prometheus snapshot and
-# every legacy reader share one storage (SHARED_STATE unchanged) — and
-# ``_stage_add`` also records a stage span on the current
-# lifecycle-action trace.
+# every legacy reader share one storage (SHARED_STATE unchanged). It is
+# process-global and last-writer-wins; the account that is safe under
+# two concurrent actions is the action's trace, which ``stage`` feeds
+# with the same measurement.
 last_build_breakdown: Dict[str, float] = {}
 _build_bd_lock = _threading.Lock()
 _obs_metrics.registry.stage_timer(
@@ -439,11 +448,62 @@ _obs_metrics.registry.stage_timer(
 last_build_telemetry: Dict[str, object] = {}
 
 
-def _stage_add(name: str, t0: float) -> None:
-    dt = _time.perf_counter() - t0
+def _breakdown_add(name: str, seconds: float) -> None:
     with _build_bd_lock:
-        last_build_breakdown[name] = last_build_breakdown.get(name, 0.0) + dt
-    _obs_trace.stage(name, t0)
+        last_build_breakdown[name] = (
+            last_build_breakdown.get(name, 0.0) + seconds
+        )
+
+
+@contextlib.contextmanager
+def stage(name: str, **attrs):
+    """THE build stage hook — the only way a build-plane stage is
+    recorded: ``with stage("sort") as sp:`` opens a child span of the
+    running action's trace (``obs/trace.span``, which also enters the
+    ``hs.<name>`` profiler annotation, so a profiled run holds the stage
+    on the device trace's clock) and on exit adds the SAME seconds to
+    ``last_build_breakdown[name]`` — one measurement, two views. Yields
+    the span, for attrs that summarize repeated work (``buckets``,
+    ``sum_s``, ``max_s``: never a span per bucket or per file). Outside
+    an action the span is the no-op singleton and only the breakdown is
+    fed."""
+    t0 = _time.perf_counter_ns()
+    sp = _obs_trace.NOOP
+    try:
+        with _obs_trace.span(name, **attrs) as sp:
+            yield sp
+    finally:
+        seconds = sp.duration_s
+        if seconds is None:  # no live trace: the stage's own clock
+            seconds = (_time.perf_counter_ns() - t0) / 1e9
+        _breakdown_add(name, seconds)
+
+
+def _stage_summed(name: str, seconds: float) -> None:
+    """A stage whose busy seconds the pass summed itself (the exchange's
+    pack/exchange/unpack over its waves): no interval, so its span is
+    marked ``summed`` and stays out of every union and self time."""
+    _breakdown_add(name, seconds)
+    _obs_trace.stage(name, seconds=seconds)
+
+
+def sidecar_published(sp, paths: Sequence[str], publish_s: float) -> None:
+    """What a ``sidecar_capture`` stage published — its seconds and
+    bytes on the stage's span, the bytes also summed onto the action's
+    root (``sidecar_bytes``)."""
+    n_bytes = _files_bytes(paths)
+    sp.set("publish_s", round(publish_s, 6))
+    sp.set("bytes", n_bytes)
+    _obs_trace.accumulate("sidecar_bytes", n_bytes)
+
+
+def _repeat_attrs(sp, seconds: Sequence[float], count_key: str) -> None:
+    """Summarize repeated work on its enclosing span — count, sum and
+    the slowest one — so a single stalled bucket shows as ``max_s`` far
+    above ``sum_s / count`` without a span apiece."""
+    sp.set(count_key, len(seconds))
+    sp.set("sum_s", round(float(sum(seconds)), 6))
+    sp.set("max_s", round(float(max(seconds, default=0.0)), 6))
 
 
 def reset_build_breakdown() -> None:
@@ -451,7 +511,7 @@ def reset_build_breakdown() -> None:
     prepare_covering_index; refresh/optimize call it directly) so the
     breakdown never mixes two ops' stage times. Takes the breakdown
     lock: a reset must never interleave with a sharded-tail worker's
-    ``_stage_add`` read-modify-write (HS602, SHARED_STATE). Also rearms
+    ``stage`` read-modify-write (HS602, SHARED_STATE). Also rearms
     the shuffle's once-per-build skew warning (the streaming build runs
     one exchange per wave; the warning fires at most once per op while
     telemetry records every wave)."""
@@ -473,17 +533,32 @@ def lazy_or_materialized(ctx, scan):
     budget = ctx.session.conf.build_memory_budget
     if budget and scan.estimated_bytes() > budget:
         return scan
-    t0 = _time.perf_counter()
-    local = scan.process_local()
-    if local.files:
-        out = local.materialize()
-    else:
-        # more hosts than files: this process contributes zero rows but
-        # must still know the schema (and later join every exchange
-        # collective) — a zero-row batch from the footer schema
-        out = scan.empty_batch()
-    _stage_add("scan", t0)
+    with stage("scan") as sp:
+        local = scan.process_local()
+        if local.files:
+            out = local.materialize()
+        else:
+            # more hosts than files: this process contributes zero rows
+            # but must still know the schema (and later join every
+            # exchange collective) — a zero-row batch from the footer
+            # schema
+            out = scan.empty_batch()
+        sp.set("files", len(local.files))
+        _obs_trace.accumulate("rows", int(out.num_rows))
+        _obs_trace.accumulate("source_bytes", _files_bytes(local.files))
     return out
+
+
+def _files_bytes(paths) -> int:
+    """On-disk bytes of ``paths`` (0 for one that cannot be stat'ed — a
+    counter never fails a build)."""
+    total = 0
+    for p in paths:
+        try:
+            total += os.path.getsize(p)
+        except OSError:
+            pass
+    return total
 
 
 def previous_index_scan(
@@ -579,34 +654,37 @@ def _hash_shuffle(
     owns), or None when no exchange ran (single device / tiny batch)."""
     import jax
 
-    t0 = _time.perf_counter()
-    reps = batch.key_reps(indexed_cols)
-    mesh = ctx.mesh
-    shard_offs = None
-    # multi-process: ALWAYS exchange, even a zero/tiny local batch — the
-    # exchange is a collective and every process must take the same
-    # number of steps (a peer may be feeding this wave real rows)
-    if mesh.devices.size > 1 and (
-        batch.num_rows >= mesh.devices.size or jax.process_count() > 1
-    ):
-        from hyperspace_tpu.parallel import shuffle as _shuffle
+    with stage("hash_shuffle"):
+        with _obs_trace.span("key_reps"):
+            reps = batch.key_reps(indexed_cols)
+        mesh = ctx.mesh
+        shard_offs = None
+        # multi-process: ALWAYS exchange, even a zero/tiny local batch —
+        # the exchange is a collective and every process must take the
+        # same number of steps (a peer may be feeding this wave real rows)
+        if mesh.devices.size > 1 and (
+            batch.num_rows >= mesh.devices.size or jax.process_count() > 1
+        ):
+            from hyperspace_tpu.parallel import shuffle as _shuffle
 
-        arrays, spec = _decompose(batch)
-        k = reps.shape[0]
-        conf = ctx.session.conf
-        buckets, moved, shard_offs = _shuffle.bucket_shuffle(
-            mesh, reps, list(reps) + arrays, num_buckets,
-            with_shard_offsets=True,
-            strategy=conf.build_exchange_strategy,
-            twostage_hosts=conf.build_exchange_twostage_hosts,
-        )
-        reps = np.stack(moved[:k]) if k else np.zeros((0, len(buckets)))
-        batch = _reassemble(spec, moved[k:])
-        _record_shuffle_telemetry(_shuffle.last_shuffle_stats)
-    else:
-        buckets = bucket_ids_np(reps, num_buckets)
-    _stage_add("hash_shuffle", t0)
+            arrays, spec = _decompose(batch)
+            k = reps.shape[0]
+            conf = ctx.session.conf
+            buckets, moved, shard_offs = _shuffle.bucket_shuffle(
+                mesh, reps, list(reps) + arrays, num_buckets,
+                with_shard_offsets=True,
+                strategy=conf.build_exchange_strategy,
+                twostage_hosts=conf.build_exchange_twostage_hosts,
+            )
+            reps = np.stack(moved[:k]) if k else np.zeros((0, len(buckets)))
+            batch = _reassemble(spec, moved[k:])
+            _record_shuffle_telemetry(_shuffle.last_shuffle_stats)
+        else:
+            buckets = bucket_ids_np(reps, num_buckets)
     return buckets, reps, batch, shard_offs
+
+
+_EXCHANGE_STAGE_KEYS = ("pack_s", "exchange_s", "unpack_s")
 
 
 def _record_shuffle_telemetry(stats: Dict) -> None:
@@ -621,7 +699,7 @@ def _record_shuffle_telemetry(stats: Dict) -> None:
         waves = t.get("shuffle_waves", 0.0) + 1.0
         for k, v in stats.items():
             key = "shuffle_" + k
-            if k in ("pack_s", "exchange_s", "unpack_s"):
+            if k in _EXCHANGE_STAGE_KEYS:
                 t[key] = round(t.get(key, 0.0) + float(v), 4)
             else:
                 t[key] = v
@@ -634,6 +712,11 @@ def _record_shuffle_telemetry(stats: Dict) -> None:
         t["shuffle_skew_ratio_mean"] = round(
             prev_mean + (skew - prev_mean) / waves, 3
         )
+    # the exchange is one fused pass, opaque to any outer timer: its own
+    # measured seconds are the only account of it
+    for k in _EXCHANGE_STAGE_KEYS:
+        if stats.get(k):
+            _stage_summed(k.removesuffix("_s"), float(stats[k]))
 
 
 def _partition_first(ctx) -> bool:
@@ -674,19 +757,20 @@ def bucketize(ctx, batch: ColumnarBatch, indexed_cols: List[str], num_buckets: i
     buckets, reps, batch, shard_offs = _hash_shuffle(
         ctx, batch, indexed_cols, num_buckets
     )
-    t0 = _time.perf_counter()
-    if _partition_first(ctx):
-        shard_offs = _sharded_tail_offsets(ctx, shard_offs)
-        if shard_offs is not None:
-            perm = sharded_sort_permutation(
-                reps, buckets, num_buckets, shard_offs
-            )
+    with stage("sort"):
+        if _partition_first(ctx):
+            shard_offs = _sharded_tail_offsets(ctx, shard_offs)
+            if shard_offs is not None:
+                perm = sharded_sort_permutation(
+                    reps, buckets, num_buckets, shard_offs
+                )
+            else:
+                perm = partitioned_sort_permutation(
+                    reps, buckets, num_buckets
+                )
         else:
-            perm = partitioned_sort_permutation(reps, buckets, num_buckets)
-    else:
-        perm = sort_permutation(reps, buckets)
-    out = buckets[perm], batch.take(perm)
-    _stage_add("sort", t0)
+            perm = sort_permutation(reps, buckets)
+        out = buckets[perm], batch.take(perm)
     return out
 
 
@@ -709,8 +793,6 @@ def write_bucketed(
     and partition-first layouts must stay byte-identical, so they cannot
     each sample a differently-ordered table.
     """
-    import os
-
     sources = data if isinstance(data, list) else [data]
     if any(isinstance(s, SourceScan) for s in sources):
         return _global_written(
@@ -726,7 +808,8 @@ def write_bucketed(
         # _global_written barrier (its devices may RECEIVE rows)
         os.makedirs(ctx.index_data_path, exist_ok=True)
         return []
-    use_dict = pio.dictionary_columns_for_batch(batch)
+    with stage("dict_probe"):
+        use_dict = pio.dictionary_columns_for_batch(batch)
     if _partition_first(ctx):
         return _global_written(
             ctx,
@@ -736,17 +819,36 @@ def write_bucketed(
             ),
         )
     buckets, batch = bucketize(ctx, batch, indexed_cols, num_buckets)
-    t0 = _time.perf_counter()
-    out = pio.write_bucket_files(
-        ctx.index_data_path,
-        buckets,
-        batch,
-        num_buckets,
-        file_idx_offset,
-        use_dictionary=use_dict,
-    )
-    _stage_add("write", t0)
+    with stage("write") as sp:
+        out = pio.write_bucket_files(
+            ctx.index_data_path,
+            buckets,
+            batch,
+            num_buckets,
+            file_idx_offset,
+            use_dictionary=use_dict,
+        )
+        _count_written(sp, out)
     return _global_written(ctx, out)
+
+
+def _count_written(sp, paths: List[str]) -> None:
+    """Files and bytes a write stage produced: on its span, and summed
+    onto the action's root."""
+    n_bytes = _files_bytes(paths)
+    sp.set("files", len(paths))
+    sp.set("bytes", n_bytes)
+    _obs_trace.accumulate("index_files", len(paths))
+    _obs_trace.accumulate("index_bytes", n_bytes)
+
+
+def _timed_write_bucket_file(*args) -> Tuple[str, float]:
+    """``pio.write_bucket_file`` -> (path, its seconds on the writer
+    thread): the per-file unit behind a write stage's ``sum_s`` /
+    ``max_s``."""
+    t0 = _time.perf_counter()
+    path = pio.write_bucket_file(*args)
+    return path, _time.perf_counter() - t0
 
 
 def _single_process() -> bool:
@@ -771,7 +873,6 @@ def _global_written(ctx, written: List[str]) -> List[str]:
 
     if jax.process_count() <= 1:
         return written
-    import os
 
     from jax.experimental import multihost_utils as mhu
 
@@ -809,16 +910,18 @@ def _write_bucketed_pipelined(
     and each file is written from the same rows in the same order with
     the same encoding decision.
 
-    Stage accounting: "sort" spans partition + all per-bucket sorts;
-    "write" records only the drain after the last sort — the overlapped
-    portion of the writes hides inside the sort stage, which is the
-    point of the pipeline.
+    Stage accounting: "sort" spans ``partition`` (counting scatter +
+    order words), ``to_arrow`` and ``bucket_sorts`` (all per-bucket
+    sorts, summarized as ``buckets``/``sum_s``/``max_s``); "write"
+    records only the drain after the last sort — the overlapped portion
+    of the writes hides inside the sort stage, which is the point of
+    the pipeline — while its ``sum_s``/``max_s`` count every file's
+    seconds on the writer thread, overlapped or not.
 
     Datasets beyond the memory budget never reach here; they stream
     through ``_write_bucketed_streaming``'s wave/spill loop, whose
     per-wave ``bucketize`` uses the same partition-first sort.
     """
-    import os
     from concurrent.futures import ThreadPoolExecutor
 
     from hyperspace_tpu.ops.sort import (
@@ -837,30 +940,38 @@ def _write_bucketed_pipelined(
             ctx, buckets, reps, batch, file_idx_offset, use_dict,
             num_buckets, shard_offs,
         )
-    t0 = _time.perf_counter()
-    order, offsets = partition_by_bucket(buckets, num_buckets)
-    planes = _order_words_np(reps.astype(np.int64, copy=False))
-    table = batch.to_arrow()
-    written: List[str] = []
+    sort_s: List[float] = []
     with ThreadPoolExecutor(max_workers=1) as writer:
         futures = []
-        for b, final_idx in bucket_key_sort_runs(planes, order, offsets):
-            futures.append(
-                writer.submit(
-                    pio.write_bucket_file,
-                    ctx.index_data_path,
-                    b,
-                    file_idx_offset,
-                    table,
-                    final_idx,
-                    use_dict,
-                )
-            )
-        _stage_add("sort", t0)
-        t0 = _time.perf_counter()
-        for f in futures:
-            written.append(f.result())
-    _stage_add("write", t0)
+        with stage("sort"):
+            with _obs_trace.span("partition"):
+                order, offsets = partition_by_bucket(buckets, num_buckets)
+                planes = _order_words_np(reps.astype(np.int64, copy=False))
+            with _obs_trace.span("to_arrow"):
+                table = batch.to_arrow()
+            with _obs_trace.span("bucket_sorts") as sorts_sp:
+                for b, final_idx in bucket_key_sort_runs(
+                    planes, order, offsets, seconds_out=sort_s
+                ):
+                    futures.append(
+                        writer.submit(
+                            _timed_write_bucket_file,
+                            ctx.index_data_path,
+                            b,
+                            file_idx_offset,
+                            table,
+                            final_idx,
+                            use_dict,
+                        )
+                    )
+                _repeat_attrs(sorts_sp, sort_s, "buckets")
+        with stage("write") as write_sp:
+            done = [f.result() for f in futures]
+            written = [path for path, _s in done]
+            # every file's seconds on the writer thread, those that ran
+            # under the sort stage included
+            _repeat_attrs(write_sp, [sec for _p, sec in done], "buckets")
+            _count_written(write_sp, written)
     return written
 
 
@@ -910,37 +1021,46 @@ def _write_bucketed_sharded(
 
     def run_shard(s: int) -> List[Tuple[int, str]]:
         lo, hi = int(shard_offs[s]), int(shard_offs[s + 1])
-        t0 = _time.perf_counter()
-        order, offsets = partition_by_bucket(buckets[lo:hi], num_buckets)
-        order += lo  # global row coordinates into planes/table
-        out: List[Tuple[int, str]] = []
+        sort_s: List[float] = []
         # one writer thread per shard: bucket i+1 sorts while bucket i
         # writes, exactly the single-tail pipeline, D of them in flight
         with ThreadPoolExecutor(max_workers=1) as writer:
             futures = []
-            for b, final_idx in bucket_key_sort_runs(
-                planes, order, offsets, workers=1, n_threads=threads
-            ):
-                futures.append(
-                    (
-                        b,
-                        writer.submit(
-                            pio.write_bucket_file,
-                            ctx.index_data_path,
-                            b,
-                            file_idx_offset,
-                            table,
-                            final_idx,
-                            use_dict,
-                        ),
-                    )
+            with stage("sort", shard=s) as sort_sp:
+                order, offsets = partition_by_bucket(
+                    buckets[lo:hi], num_buckets
                 )
-            _stage_add("sort", t0)
-            t0 = _time.perf_counter()
-            out = [(b, f.result()) for b, f in futures]
-        _stage_add("write", t0)
+                order += lo  # global row coordinates into planes/table
+                for b, final_idx in bucket_key_sort_runs(
+                    planes, order, offsets, workers=1, n_threads=threads,
+                    seconds_out=sort_s,
+                ):
+                    futures.append(
+                        (
+                            b,
+                            writer.submit(
+                                _timed_write_bucket_file,
+                                ctx.index_data_path,
+                                b,
+                                file_idx_offset,
+                                table,
+                                final_idx,
+                                use_dict,
+                            ),
+                        )
+                    )
+                _repeat_attrs(sort_sp, sort_s, "buckets")
+            with stage("write", shard=s) as write_sp:
+                done = [(b, f.result()) for b, f in futures]
+                out = [(b, path) for b, (path, _sec) in done]
+                _repeat_attrs(
+                    write_sp, [sec for _b, (_p, sec) in done], "buckets"
+                )
+                _count_written(write_sp, [path for _b, path in out])
         return out
 
+    # the shard tails run on pool threads: hand them the action's span
+    run_shard = _obs_trace.carry(run_shard)
     if len(shards) == 1:
         results = [run_shard(shards[0])]
     else:
@@ -984,7 +1104,6 @@ def _write_bucketed_streaming(
     The reference leans on Spark's disk-backed ``repartition`` shuffle for
     exactly this (covering/CoveringIndex.scala:58-61).
     """
-    import os
     import shutil
 
     budget = ctx.session.conf.build_memory_budget or (1 << 62)

@@ -45,6 +45,7 @@ import logging
 import math
 import os
 import threading
+import time
 from bisect import bisect_left
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Tuple
@@ -426,6 +427,15 @@ def capture_index_dir(dir_path: str, index) -> bool:
     kind = getattr(index, "kind", "")
     if kind not in ("CoveringIndex", "ZOrderCoveringIndex"):
         return False
+    from hyperspace_tpu.indexes import covering_build
+
+    # the same build stage as the aggregate sidecar's, told apart by attr
+    with covering_build.stage("sidecar_capture", sidecar="zonemap") as sp:
+        return _capture_files(dir_path, index, kind, sp)
+
+
+def _capture_files(dir_path: str, index, kind: str, sp) -> bool:
+    from hyperspace_tpu.indexes import covering_build
     from hyperspace_tpu.io import parquet as pio
 
     try:
@@ -434,6 +444,7 @@ def capture_index_dir(dir_path: str, index) -> bool:
         return False
     if not files:
         return False
+    t_files = time.perf_counter()
     footers = {}
     for f in files:
         fz = footer_zones(f)
@@ -463,17 +474,24 @@ def capture_index_dir(dir_path: str, index) -> bool:
         # dtype, memory pressure) must leave the min/max sidecar usable
         except Exception as exc:  # hslint: disable=HS402
             _log.warning("z-span capture failed for %s: %s", dir_path, exc)
+    t_publish = time.perf_counter()
+    sp.set("files", len(files))
+    sp.set("read_s", round(t_publish - t_files, 6))
+    side_path = os.path.join(dir_path, SIDECAR_NAME)
     tmp = os.path.join(dir_path, f".{SIDECAR_NAME}.tmp.{os.getpid()}")
     try:
         with open(tmp, "w", encoding="utf-8") as f:
             json.dump(doc, f)
-        os.replace(tmp, os.path.join(dir_path, SIDECAR_NAME))
+        os.replace(tmp, side_path)
     except OSError:
         try:
             os.unlink(tmp)
         except OSError:
             pass
         return False
+    covering_build.sidecar_published(
+        sp, [side_path], time.perf_counter() - t_publish
+    )
     return True
 
 

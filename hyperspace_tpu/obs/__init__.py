@@ -2,12 +2,14 @@
 
 Three legs (docs/observability.md):
 
-* :mod:`obs.trace` — structured tracing: one root span per frontend
-  query and per lifecycle action, child stage spans mirroring the
-  legacy breakdown keys, context propagated across every serve-path
-  thread pool and (via the fleet claim/spool plane and bus events)
-  across processes. Zero-cost no-op path when ``hyperspace.obs.enabled``
-  is off.
+* :mod:`obs.trace` — structured tracing: one root span per lifecycle
+  action (always recorded — the build's account) and per frontend
+  query (when ``hyperspace.obs.enabled`` turns the serve plane on),
+  child spans with real intervals on one clock, context propagated
+  across every serve-path thread pool and (via the fleet claim/spool
+  plane and bus events) across processes. With the switch off no span
+  is live on the serve path and every site there costs one
+  ``ContextVar.get``.
 * :mod:`obs.metrics` — the typed counter/gauge/stage-timer registry
   that absorbed the scattered telemetry snapshots
   (``last_serve_breakdown`` / ``last_build_breakdown`` are views over

@@ -2,7 +2,8 @@
 
 The SHARED_STATE / KERNEL_TWINS / COLLECTIVE_SITES doctrine applied to
 the observability plane: every call site that CREATES spans
-(``trace.root`` / ``trace.span`` / ``trace.stage``) or REGISTERS
+(``trace.root`` / ``trace.span`` / ``trace.stage``, and the build
+plane's one stage hook ``covering_build.stage``) or REGISTERS
 metrics (``registry.counter`` / ``gauge`` / ``labeled_counter`` /
 ``stage_timer`` / ``register_view`` / ``register_weak_view``) declares
 itself HERE with a
@@ -27,9 +28,11 @@ instrument registration). Calls in nested defs/lambdas attribute to
 their outermost enclosing def, like the collective registry.
 
 Stage-span VOCABULARY: HS902 rejects any constant stage/span name that
-is not listed below — stage spans exist to mirror the legacy breakdown
-keys, and a misspelled span name would silently fork the taxonomy the
-querylog, the bench gates and docs/observability.md all key on.
+is not listed below — a misspelled span name would silently fork the
+taxonomy the querylog, the benchmark's ``action_trace`` reader and
+docs/observability.md all key on. A span is declared where the time
+is, at a layer boundary — never per row, per bucket or per file:
+repeated work is summarized as attrs on its enclosing span.
 
 Keep this module stdlib-only and import-cheap: the analyzer only ever
 parses it, and the obs plane imports it for the vocabulary.
@@ -66,18 +69,42 @@ SERVE_STAGES = (
     "spill_restore",
 )
 
-#: build/lifecycle stage spans — the last_build_breakdown keys plus the
-#: shuffle stage seconds and the metadata-plane seams
+#: build/lifecycle spans (docs/observability.md "Span taxonomy"). Direct
+#: children of an ``action.<Class>`` root: the protocol steps and the
+#: stages ``covering_build.stage`` records (which are also the
+#: ``last_build_breakdown`` keys); one level down, the parts of
+#: ``hash_shuffle`` and ``sort``
 BUILD_STAGES = (
+    # the action protocol (actions/base.py)
+    "validate",
+    "begin_log",
+    "log_entry",
+    "log_commit",
+    "publish_event",
+    # build stages (covering_build.stage)
+    "resolve",
     "scan",
     "hash_shuffle",
-    "pack",
-    "exchange",
-    "unpack",
+    "dict_probe",
     "sort",
     "write",
     "sidecar_capture",
-    "log_commit",
+    # under hash_shuffle: key reps, then the device round trip of
+    # ops/hash.bucket_ids_np — or its host twin
+    "key_reps",
+    "split_words",
+    "h2d",
+    "kernel",
+    "d2h",
+    "host_hash",
+    # under sort (the pipelined partition-first tail)
+    "partition",
+    "to_arrow",
+    "bucket_sorts",
+    # the exchange's own busy seconds, summed over waves (no interval)
+    "pack",
+    "exchange",
+    "unpack",
 )
 
 #: advisor-side stage spans (advisor/: query-log mining and what-if
@@ -166,35 +193,93 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
         "last_build_breakdown IS this stage_timer's backing dict — the "
         "build snapshot absorbed as a registered instrument",
     ),
-    "hyperspace_tpu.indexes.covering_build._stage_add": (
+    "hyperspace_tpu.indexes.covering_build.stage": (
         "span",
-        "the ONE build stage hook, mirroring the serve-side discipline",
+        "the ONE build stage hook: the stage span (and its hs.<name> "
+        "profiler annotation) and the breakdown increment are the same "
+        "measurement, so they cannot disagree",
     ),
-    "hyperspace_tpu.parallel.shuffle._publish_stats": (
+    "hyperspace_tpu.indexes.covering_build._stage_summed": (
         "span",
-        "pack/exchange/unpack stage spans from the exchange's own "
-        "measured seconds — the fused-native-pass visibility Flare "
-        "argues for, applied to the shuffle",
+        "pack/exchange/unpack from the exchange's own measured seconds: "
+        "the fused shuffle pass is opaque to any outer timer, so only "
+        "its built-in measurement can explain it (a summed span)",
+    ),
+    "hyperspace_tpu.indexes.covering_build.prepare_covering_index": (
+        "span",
+        "resolve stage: schema resolution, lineage ids and per-file "
+        "footer sizes are metadata reads that scale with the source's "
+        "file count, not its rows",
+    ),
+    "hyperspace_tpu.indexes.covering_build.lazy_or_materialized": (
+        "span",
+        "scan stage: the projected source read; counts rows and source "
+        "bytes at the boundary where they enter the build",
+    ),
+    "hyperspace_tpu.indexes.covering_build._hash_shuffle": (
+        "span",
+        "hash_shuffle stage, with key_reps split off so the hash's "
+        "device round trip (ops/hash) is not confused with the host's "
+        "key encoding",
+    ),
+    "hyperspace_tpu.indexes.covering_build.bucketize": (
+        "span",
+        "sort stage of the wave/legacy path (permutation + take)",
+    ),
+    "hyperspace_tpu.indexes.covering_build.write_bucketed": (
+        "span",
+        "dict_probe (the one encoding decision over the pre-sort input) "
+        "and the legacy write stage",
+    ),
+    "hyperspace_tpu.indexes.covering_build._write_bucketed_pipelined": (
+        "span",
+        "sort (partition / to_arrow / bucket_sorts) and write of the "
+        "pipelined tail: per-bucket sorts and per-file writes are "
+        "summarized as buckets/sum_s/max_s attrs, so a stalled thread "
+        "shows without a span per bucket",
+    ),
+    "hyperspace_tpu.indexes.covering_build._write_bucketed_sharded": (
+        "span",
+        "one sort and one write span per SHARD tail (attr shard), "
+        "carried onto the shard pool's threads",
+    ),
+    "hyperspace_tpu.ops.hash.bucket_ids_np": (
+        "span",
+        "split_words / h2d / kernel / d2h (or host_hash): a 0.7 ms "
+        "kernel inside a 1.2 s stage is explained only by separating "
+        "the host's word split and each transfer from the kernel wait",
     ),
     "hyperspace_tpu.indexes.aggindex.capture_index_dir": (
         "span",
-        "sidecar capture is build-tail I/O outside every breakdown "
-        "stage; unexplained build tail time lands here",
+        "sidecar_capture (aggstate): build-tail I/O that re-reads every "
+        "file just written; files/read_s/partials_s/publish_s/bytes as "
+        "attrs, never a span per file",
+    ),
+    "hyperspace_tpu.indexes.zonemaps.capture_index_dir": (
+        "span",
+        "sidecar_capture (zonemap): the footer pass and its publish, "
+        "the same stage name with its own sidecar attr",
     ),
     "hyperspace_tpu.actions.base.Action.run": (
         "span",
-        "the lifecycle-action ROOT span — every action is explainable "
-        "after the fact, whatever the outcome",
+        "the lifecycle-action ROOT span, always recorded — every action "
+        "is explainable after the fact, whatever the outcome and "
+        "whatever the serve-plane switch says",
     ),
     "hyperspace_tpu.actions.base.Action._run_protocol": (
         "span",
-        "log_commit stage: metadata-plane publish time must be "
-        "separable from data-plane op() time",
+        "validate / begin_log / log_entry / log_commit / publish_event: "
+        "metadata-plane time must be separable from data-plane op() "
+        "time, and op() has no span so uncovered time stays visible",
     ),
     "hyperspace_tpu.actions.base.Action._run_coordinated": (
         "span",
-        "the coordinator-side log_commit stage on multi-process jobs "
-        "(the same seam, behind the rendezvous protocol)",
+        "the coordinator-side protocol spans on multi-process jobs (the "
+        "same seams, behind the rendezvous protocol)",
+    ),
+    "hyperspace_tpu.actions.base.Action._run_data_plane": (
+        "span",
+        "the workers' validate span (they write no log entries)",
     ),
     # -- workload advisor (advisor/, docs/advisor.md) ------------------------
     "hyperspace_tpu.advisor.recommend.advise": (

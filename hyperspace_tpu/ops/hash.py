@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import hyperspace_tpu.ops  # noqa: F401  (enables x64)
+from hyperspace_tpu.obs import trace as _obs_trace
 from hyperspace_tpu.ops import pad_len
 
 _C1 = np.uint32(0xCC9E2D51)
@@ -179,18 +180,38 @@ def bucket_ids_numpy(
 def bucket_ids_np(key_reps: np.ndarray, num_buckets: int, seed: int = 42) -> np.ndarray:
     """Host entry: [k, n] int64 key reps -> int32 bucket ids. Large inputs
     hash on device (padded to a power of two, ops/__init__ shape policy);
-    small ones use the same arithmetic directly in numpy."""
+    small ones use the same arithmetic directly in numpy.
+
+    Under a live trace the device path is four spans — ``split_words``
+    (the host's int64 -> uint32 word split and padding), ``h2d`` (to
+    ``block_until_ready`` of the words on the device), ``kernel``
+    (dispatch to ``block_until_ready``), ``d2h`` — with the bytes each
+    way counted on the root; the host path is one ``host_hash``."""
     n = key_reps.shape[1]
     if n == 0:
         return np.zeros(0, dtype=np.int32)
     if n <= _host_hash_max_rows():
-        return bucket_ids_host(key_reps, num_buckets, seed)
-    words = split_words_np(key_reps)
-    n_pad = pad_len(n)
-    if n_pad != n:
-        words = np.concatenate(
-            [words, np.zeros((words.shape[0], n_pad - n), dtype=np.uint32)],
-            axis=1,
+        with _obs_trace.span("host_hash"):
+            return bucket_ids_host(key_reps, num_buckets, seed)
+    with _obs_trace.span("split_words"):
+        words = split_words_np(key_reps)
+        n_pad = pad_len(n)
+        if n_pad != n:
+            words = np.concatenate(
+                [
+                    words,
+                    np.zeros((words.shape[0], n_pad - n), dtype=np.uint32),
+                ],
+                axis=1,
+            )
+    with _obs_trace.span("h2d", bytes=int(words.nbytes)):
+        on_device = jax.block_until_ready(jnp.asarray(words))
+    with _obs_trace.span("kernel"):
+        ids = jax.block_until_ready(
+            _bucket_ids_words(on_device, num_buckets, seed)
         )
-    out = np.asarray(_bucket_ids_words(jnp.asarray(words), num_buckets, seed))
+    with _obs_trace.span("d2h", bytes=int(ids.nbytes)):
+        out = np.asarray(ids)
+    _obs_trace.accumulate("h2d_bytes", int(words.nbytes))
+    _obs_trace.accumulate("d2h_bytes", int(out.nbytes))
     return out[:n]

@@ -220,6 +220,7 @@ def bucket_key_sort_runs(
     offsets: np.ndarray,
     workers: int | None = None,
     n_threads: int | None = None,
+    seconds_out: list | None = None,
 ):
     """Per-bucket stable key sorts over a partitioned order — yields
     ``(bucket, final_indices)`` in ascending bucket id as each bucket's
@@ -236,7 +237,12 @@ def bucket_key_sort_runs(
     sharded tail runs one of these loops PER SHARD concurrently
     (``workers=1``, the shard thread is the concurrency unit) and hands
     each shard a slice of the native-sort thread budget.
+
+    ``seconds_out``, when given, receives each bucket's sort seconds
+    (appended from the sorting threads) — the build's trace keeps their
+    count, sum and max on ONE span instead of a span per bucket.
     """
+    import time
     from concurrent.futures import ThreadPoolExecutor
 
     nonempty = [
@@ -250,11 +256,15 @@ def bucket_key_sort_runs(
         threads = max(1, n_threads or 1)
 
     def sort_one(b: int) -> np.ndarray:
+        t0 = time.perf_counter()
         idx = order[offsets[b] : offsets[b + 1]]
         perm = lexsort_perm(
             np.ascontiguousarray(planes[:, idx]), n_threads=threads
         )
-        return idx[perm]
+        out = idx[perm]
+        if seconds_out is not None:
+            seconds_out.append(time.perf_counter() - t0)
+        return out
 
     if workers == 1:
         for b in nonempty:

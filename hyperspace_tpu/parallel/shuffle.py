@@ -188,15 +188,6 @@ def _publish_stats(
     stats.update(extra)
     global last_shuffle_stats, _skew_warned
     last_shuffle_stats = stats
-    # stage spans from the exchange's own measured seconds (obs plane,
-    # OBS_SITES-registered): the fused shuffle pass is opaque to any
-    # outer timer, so only this built-in measurement can explain it
-    from hyperspace_tpu.obs import trace as _obs_trace
-
-    for _stage_name in ("pack", "exchange", "unpack"):
-        _sec = extra.get(f"{_stage_name}_s")
-        if _sec:
-            _obs_trace.stage(_stage_name, seconds=float(_sec))
     if (
         skew > BUILD_SHUFFLE_SKEW_WARN_RATIO
         and max_count >= BUILD_SHUFFLE_SKEW_WARN_MIN_ROWS

@@ -432,7 +432,7 @@ class ServeFrontend:
         with obs_trace.activate(root):
             if root.span_id is not None:
                 # admission -> worker pickup, on the root's own clock
-                obs_trace.stage("queue_wait", root._t0)
+                obs_trace.stage("queue_wait", start_ns=root.start_ns)
             try:
                 out = self._run_attempts(plan, pin, pin_token, cls, root)
                 if root.span_id is not None:
@@ -526,7 +526,7 @@ class ServeFrontend:
             "slo_class": root.attrs.get("slo_class"),
             "indexes": root.attrs.get("indexes", []),
             "rule": root.attrs.get("rule"),
-            "duration_s": time.perf_counter() - root._t0,
+            "duration_s": (time.perf_counter_ns() - root.start_ns) / 1e9,
             "stages": {
                 k: round(v, 6) for k, v in root.stage_seconds().items()
             },

@@ -1858,6 +1858,55 @@ class TestObsSites:
         assert len(findings) == 1
         assert "'mystery'" in findings[0].message
 
+    def test_build_stage_hook_is_an_obs_primitive(self, tmp_path):
+        """``covering_build.stage(...)`` from outside, and a bare
+        ``stage(...)`` inside the module that defines the hook, are span
+        sites: declared, and named from the vocabulary."""
+        hook = """
+            import contextlib
+            from pkg.obs import trace
+
+            @contextlib.contextmanager
+            def stage(name):
+                with trace.span(name) as sp:
+                    yield sp
+
+            def build():
+                with stage("write"):
+                    pass
+                with stage("wrte"):
+                    pass
+        """
+        user = """
+            from pkg.indexes import covering_build
+
+            def capture():
+                with covering_build.stage("sidecar"):
+                    pass
+        """
+        registry = OBS_REGISTRY.replace(
+            '"pkg.app.serve": ("span", "roots the query at admission"),',
+            '"pkg.app.serve": ("span", "roots the query at admission"),\n'
+            '        "pkg.indexes.covering_build.stage": ("span", "the hook"),'
+            '\n        "pkg.indexes.covering_build.build": ("span", "stages"),',
+        )
+        files = {
+            "sites.py": registry,
+            "app.py": OBS_APP,
+            "indexes/__init__.py": "",
+            "indexes/covering_build.py": hook,
+            "indexes/sidecar.py": user,
+        }
+        findings = _lint(tmp_path, files)
+        undeclared = [f for f in findings if f.rule == "HS901"]
+        assert len(undeclared) == 1
+        assert "pkg.indexes.sidecar.capture" in undeclared[0].message
+        drifted = sorted(
+            f.message for f in findings if f.rule == "HS902"
+        )
+        assert len(drifted) == 2
+        assert "'sidecar'" in drifted[0] and "'wrte'" in drifted[1]
+
     def test_stale_entries_flagged(self, tmp_path):
         stale_registry = """
             KINDS = ("span", "metric", "view")

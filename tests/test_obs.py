@@ -480,14 +480,18 @@ class TestActionSpans:
         assert root.attrs["status"] == "ok"
         assert root.attrs["index"] == "ai1"
         stages = root.stage_seconds()
-        for want in ("scan", "sort", "write", "log_commit"):
+        for want in (
+            "validate", "begin_log", "resolve", "scan", "hash_shuffle",
+            "sort", "write", "sidecar_capture", "log_entry", "log_commit",
+            "publish_event",
+        ):
             assert want in stages, (want, sorted(stages))
         # the build breakdown and the spans are one measurement
         from hyperspace_tpu.indexes import covering_build
 
         for name, sec in covering_build.last_build_breakdown.items():
             if name in ("tail_wall", "tail_shards"):
-                continue  # derived values, not _stage_add increments
+                continue  # derived values, not stage increments
             assert name in stages, name
 
     def test_failed_action_still_finishes_root(
@@ -507,6 +511,216 @@ class TestActionSpans:
         roots = trace.finished("action.CreateAction")
         assert len(roots) == 1
         assert roots[0].attrs["status"] == "failed"
+
+
+# ---------------------------------------------------------------------------
+# The action trace is the build's account: always recorded, one clock
+# ---------------------------------------------------------------------------
+
+
+def _build(session_factory, tmp_path, name="acc1", warm=True, buckets=None):
+    """One covering build under the DEFAULT configuration (obs off);
+    a warm-up build first pays the one-time imports."""
+    s = session_factory(1)
+    assert s.conf.obs_enabled is False
+    if buckets is not None:
+        s.conf.set(C.INDEX_NUM_BUCKETS, buckets)
+    idir, _odir = _lake(tmp_path)
+    hs = Hyperspace(s)
+    items = s.read.parquet(idir)
+    if warm:
+        hs.create_index(items, CoveringIndexConfig("warm0", ["k"], ["q"]))
+        trace.reset()
+    hs.create_index(items, CoveringIndexConfig(name, ["k"], ["q"]))
+    return s, hs, items
+
+
+class TestActionAccount:
+    def test_recorded_with_obs_off_on_one_clock(
+        self, session_factory, tmp_path
+    ):
+        _build(session_factory, tmp_path)
+        assert trace.enabled() is False
+        roots = trace.finished("action.CreateAction")
+        assert len(roots) == 1
+        root = roots[0]
+        _assert_trace_integrity(root)
+        assert root.start_ms is not None and root.attrs["status"] == "ok"
+        for sp in root.spans:
+            assert sp.start_ns <= sp.end_ns, sp.name
+            if sp is not root:
+                assert sp.start_ms is None  # roots alone keep wall time
+                assert root.start_ns <= sp.start_ns, sp.name
+                assert sp.end_ns <= root.end_ns, sp.name
+        # op() has no span of its own: what the root's direct children
+        # leave uncovered IS the unnamed time, and it is small
+        assert root.children_union_s() >= 0.95 * root.duration_s
+        selfs = root.self_seconds()
+        assert selfs["action.CreateAction"] == pytest.approx(
+            root.duration_s - root.children_union_s(), abs=1e-9
+        )
+        # counts at the same boundaries
+        assert root.attrs["rows"] == 20_000
+        assert root.attrs["source_bytes"] > 0
+        assert root.attrs["index_files"] > 0 and root.attrs["index_bytes"] > 0
+        assert root.attrs["sidecar_bytes"] > 0
+        sidecars = {
+            sp.attrs["sidecar"]: sp.attrs
+            for sp in root.spans
+            if sp.name == "sidecar_capture"
+        }
+        assert set(sidecars) == {"aggstate", "zonemap"}
+        for key in ("files", "read_s", "partials_s", "publish_s", "bytes"):
+            assert key in sidecars["aggstate"], key
+
+    def test_breakdown_is_the_same_measurement(
+        self, session_factory, tmp_path
+    ):
+        from hyperspace_tpu.indexes import covering_build
+
+        _build(session_factory, tmp_path, warm=False)
+        root = trace.finished("action.CreateAction")[-1]
+        stages = root.stage_seconds()
+        bd = dict(covering_build.last_build_breakdown)
+        assert {"scan", "hash_shuffle", "sort", "write",
+                "sidecar_capture"} <= set(bd)
+        for name, sec in bd.items():
+            assert stages[name] == pytest.approx(sec, abs=1e-9), name
+
+    def test_serve_with_obs_off_still_leaves_nothing(
+        self, session_factory, tmp_path
+    ):
+        s, _hs, items = _build(session_factory, tmp_path, warm=False)
+        assert [r.name for r in trace.finished()] == ["action.CreateAction"]
+        s.enable_hyperspace()
+        trace.reset()
+        fe = ServeFrontend(s)
+        try:
+            out = fe.serve(items.filter(items["k"] == 9).select("k", "q"))
+        finally:
+            fe.close()
+        assert out.num_rows > 0
+        assert trace.finished() == []
+        assert trace.current() is None
+
+    def test_no_span_per_bucket(self, session_factory, tmp_path):
+        _build(session_factory, tmp_path, warm=False, buckets=200)
+        root = trace.finished("action.CreateAction")[-1]
+        assert len(root.spans) <= 64 and root.spans_dropped == 0
+        by_name = {sp.name: sp for sp in root.spans}
+        # repeated work is attrs on the enclosing span
+        assert by_name["bucket_sorts"].attrs["buckets"] > 1
+        for sp in (by_name["bucket_sorts"], by_name["write"]):
+            assert sp.attrs["max_s"] <= sp.attrs["sum_s"] + 1e-9
+        assert by_name["write"].attrs["files"] == root.attrs["index_files"]
+
+    def test_self_seconds_on_a_hand_built_tree(self):
+        """Overlapping children count once; a summed span has no
+        interval and is left out of every union and self time."""
+        S = 1_000_000_000
+        root = trace.Span("action.T", start_ns=0)
+
+        def mk(name, parent, lo, hi):
+            sp = trace.Span(name, parent=parent, start_ns=int(lo * S))
+            sp.end_ns = int(hi * S)
+            root.spans.append(sp)
+            return sp
+
+        a = mk("a", root, 1, 5)       # one thread
+        mk("b", root, 3, 8)           # another, overlapping a
+        mk("a1", a, 1, 2)
+        mk("a2", a, 1.5, 3)           # overlaps a1
+        mk("a2", a, 4.5, 6)           # sticks out of its parent: clipped
+        with trace.activate(root):
+            trace.stage("pack", seconds=2.5)
+        root.end_ns = 10 * S
+        root.spans.append(root)
+        assert root.children_union_s() == pytest.approx(7.0)
+        selfs = root.self_seconds()
+        assert selfs["action.T"] == pytest.approx(3.0)
+        assert selfs["a"] == pytest.approx(4.0 - 2.0 - 0.5)
+        assert selfs["b"] == pytest.approx(5.0)
+        assert selfs["a1"] == pytest.approx(1.0)
+        assert selfs["a2"] == pytest.approx(1.5 + 1.5)
+        assert "pack" not in selfs
+        assert root.stage_seconds()["pack"] == pytest.approx(2.5)
+        packed = [sp for sp in root.spans if sp.name == "pack"]
+        assert packed[0].summed and packed[0].to_dict()["summed"] is True
+
+    def test_children_on_two_threads_count_once(self):
+        import time
+        from concurrent.futures import ThreadPoolExecutor
+
+        root = trace.root("action.T", always=True)
+
+        def work(name):
+            with trace.span(name):
+                time.sleep(0.08)
+
+        with trace.activate(root):
+            with ThreadPoolExecutor(max_workers=2) as pool:
+                list(pool.map(trace.carry(work), ["a", "b"]))
+        root.finish()
+        _assert_trace_integrity(root)
+        stages = root.stage_seconds()
+        assert stages["a"] + stages["b"] >= 0.16
+        # side by side, not end to end
+        assert root.children_union_s() < stages["a"] + stages["b"] - 0.02
+        assert root.children_union_s() <= root.duration_s
+
+    def test_carry_and_span_follow_the_parent_not_the_switch(self):
+        trace.set_enabled(False)
+        fn = lambda: trace.current()  # noqa: E731
+        assert trace.carry(fn) is fn           # no parent: identity
+        root = trace.root("action.T", always=True)
+        with trace.activate(root):
+            assert trace.carry(fn) is not fn   # a live parent: wrapped
+            with trace.span("scan") as sp:
+                assert sp is not trace.NOOP
+            trace.accumulate("rows", 3)
+            trace.accumulate("rows", 4)
+        root.finish()
+        assert root.attrs["rows"] == 7
+        assert [s.name for s in root.spans] == ["scan", "action.T"]
+
+    def test_profiler_trace_holds_hs_annotations(
+        self, session_factory, tmp_path
+    ):
+        """Every ``with trace.span`` also enters an ``hs.<name>``
+        TraceAnnotation: on a profiled run the program's spans lie in
+        the profiler's own trace, on the device trace's clock."""
+        import glob
+
+        import jax
+
+        s = session_factory(1)
+        idir, _odir = _lake(tmp_path)
+        hs = Hyperspace(s)
+        items = s.read.parquet(idir)
+        out_dir = str(tmp_path / "prof")
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        jax.profiler.start_trace(out_dir, profiler_options=opts)
+        try:
+            hs.create_index(items, CoveringIndexConfig("pf1", ["k"], ["q"]))
+        finally:
+            jax.profiler.stop_trace()
+        files = glob.glob(
+            os.path.join(out_dir, "plugins", "profile", "*", "*.xplane.pb")
+        )
+        assert files
+        data = jax.profiler.ProfileData.from_file(sorted(files)[-1])
+        names = {
+            e.name
+            for plane in data.planes
+            for line in plane.lines
+            for e in line.events
+            if e.name.startswith("hs.")
+        }
+        for want in ("hs.scan", "hs.sort", "hs.write", "hs.sidecar_capture",
+                     "hs.log_commit"):
+            assert want in names, (want, sorted(names))
 
 
 # ---------------------------------------------------------------------------
