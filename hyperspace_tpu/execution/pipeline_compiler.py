@@ -552,12 +552,13 @@ def partials_from_batch(
     already-filtered batch -> :class:`AggPartials`, bit-identical to
     ``AggState.accumulate(...).partials()`` over the same rows (wrapped
     int sums, +0.0-for-null float sums, replace-on-equal min/max, clean/
-    NaN aux counts, first-occurrence key values). Shared by the sidecar
-    capture (``indexes/aggindex.py`` runs it per row group at build
-    time) and the metadata plane's kernel-less boundary chunks. ``plan``
-    only needs ``group_by`` + ``agg_ops`` (a full FusedAggPlan or the
-    capture's lightweight spec). None when a column falls outside the
-    fused 8-byte type set."""
+    NaN aux counts, first-occurrence key values; the one thing the two
+    may differ in is the sign and payload of a float sum that is NaN).
+    Shared by the sidecar capture (``indexes/aggindex.py``: the pass of
+    a row group the kernel cannot run, or whose float sum came out NaN)
+    and the metadata plane's kernel-less boundary chunks. ``plan`` only
+    needs ``group_by`` + ``agg_ops``. None when a column falls outside
+    the fused 8-byte type set."""
     from hyperspace_tpu.execution.aggregate_exec import _factorize
 
     n = batch.num_rows

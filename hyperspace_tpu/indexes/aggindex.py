@@ -16,11 +16,13 @@ reads* (docs/agg-serve.md):
   whose per-row-group distinct count stays under
   ``hyperspace.index.agg.maxGroupsPerRowGroup``. A stratified per-row-
   group row sample lands next to it in ``_aggsample.parquet`` for the
-  approximate plane (``execution/approx_exec.py``). Partials are
-  computed through the SAME public hook the serve sweep snapshots
-  (``pipeline_compiler.partials_from_batch`` / ``AggPartials``), so the
-  build-time capture and the serve-time pass share one state layout by
-  construction.
+  approximate plane (``execution/approx_exec.py``). Partials are swept
+  by the SAME kernel the serve sweep runs and snapshot through the same
+  public hook (``pipeline_compiler.AggState.partials`` → ``AggPartials``;
+  the numpy twin ``partials_from_batch`` where the kernel cannot run),
+  so the build-time capture and the serve-time pass share one state
+  layout by construction. The files of a version directory are swept
+  concurrently; the sidecars keep file order.
 * **lazy backfill** — pre-existing indexes (and files whose sidecar
   entry is stale by (size, mtime_ns)) compute the same per-file doc by
   reading the file once, memoized per file identity; a rewritten file
@@ -46,6 +48,7 @@ else is PARTIAL and gets scanned.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import json
@@ -121,13 +124,138 @@ def _capture_spec(schema: pa.Schema):
     return count_only, numeric
 
 
-class _CaptureSpec:
-    """A minimal plan-shaped object for ``partials_from_batch``: just
-    ``group_by`` + ``agg_ops`` (the capture has no AggSpecs)."""
+class _OverCap(Exception):
+    """A grouped sweep met a group past ``max_groups``: the row group's
+    key is over the cardinality cap, whatever the rest of it holds."""
 
-    def __init__(self, group_by, agg_ops):
-        self.group_by = tuple(group_by)
-        self.agg_ops = tuple(agg_ops)
+
+@contextlib.contextmanager
+def _outside(turn: Optional[threading.Lock]):
+    """Put ``turn`` down for a call that runs outside the interpreter
+    lock (a parquet read, a kernel sweep), and take it up again after.
+
+    ``capture_index_dir``'s tasks hold one lock between them while they
+    run Python: with 13 of them taking the interpreter lock from one
+    another at every small call, the Python part of a file cost 3.5
+    times the CPU it costs alone (the chip's 13-core host, PERF.md §6
+    PR 26) and the pool lost to a pool of two. Taking turns, only the
+    calls in here overlap — which is all that can."""
+    if turn is None:
+        yield
+        return
+    turn.release()
+    try:
+        yield
+    finally:
+        turn.acquire()
+
+
+@functools.lru_cache(maxsize=None)
+def _capped_state_cls():
+    """``pipeline_compiler.AggState`` with a ceiling on its group table
+    (built on first use: importing this module must not import the
+    execution stack)."""
+    from hyperspace_tpu.execution.pipeline_compiler import AggState
+
+    class CappedState(AggState):
+        """The serve sweep's chunk state, for ONE row group of a capture:
+        the table starts at ``max_groups`` slots (or ``_INIT_CAP``, if
+        that is smaller), and a kernel stop at a full table that may not
+        grow abandons the pass at that row — the kernel stops before the
+        overflowing row, so what it met is a group past the cap."""
+
+        def __init__(self, plan, max_groups: int):
+            self.max_groups = max_groups
+            self._INIT_CAP = min(AggState._INIT_CAP, max(1, max_groups))
+            super().__init__(plan)
+
+        def _alloc(self, cap: int) -> None:
+            super()._alloc(cap)
+            # the kernel wants a power-of-two table strictly larger than
+            # cap; cap itself (a conf value) need not be a power of two
+            self.ht = np.full(1 << (4 * cap - 1).bit_length(), -1, np.int64)
+
+        def _grow(self) -> None:
+            if self.cap >= self.max_groups:
+                raise _OverCap
+            super()._grow()
+
+    return CappedState
+
+
+def _sweep_plan(PC, schema: pa.Schema, key: Optional[str], ops):
+    """The fused kernel's plan for one capture sweep: no filter terms
+    (every row passes), ``key`` as the single group key or none, and the
+    capture's agg ops. ``partials_from_batch`` reads only ``group_by``
+    and ``agg_ops`` of it."""
+    group_by = () if key is None else (key,)
+    key_types = tuple(schema.field(k).type for k in group_by)
+    return PC.FusedAggPlan(
+        read_cols=(),
+        terms=(),
+        term_f64=(),
+        bounds=((), (), (), (), ()),
+        group_by=group_by,
+        key_f64=tuple(bool(PC._fusable_f64(t)) for t in key_types),
+        key_types=key_types,
+        agg_ops=tuple(ops),
+        aggs=(),
+        out_types=(),
+    )
+
+
+def _in_factorize_order(pt):
+    """Grouped kernel partials (groups in first-occurrence order) put in
+    the twin's group order — ``aggregate_exec._factorize``: ascending
+    signed key rep, then the null plane — which is the order the sidecar
+    stores. The accumulators are per group, so this moves columns and
+    re-sums nothing."""
+    perm = np.lexsort((pt.g_nulls[0], pt.g_reps[0]))
+    return dataclasses.replace(
+        pt,
+        **{
+            f.name: getattr(pt, f.name)[:, perm]
+            for f in dataclasses.fields(pt)
+            if isinstance(getattr(pt, f.name), np.ndarray)
+        },
+    )
+
+
+def _sweep(PC, plan, batch, max_groups: int, stats, turn=None):
+    """Partials of one row group under ``plan``, or None when a grouped
+    pass is over ``max_groups``: the native one-pass kernel serve runs
+    (``hs_fused_filter_agg`` through ``AggState``), else — native not
+    loaded, a column outside the fused 8-byte set — its numpy twin
+    ``partials_from_batch``. The two are bit-identical per group, and
+    float sums keep the kernel's row order either way, with one
+    exception that the twin therefore decides: a float sum that came out
+    NaN. Which NaN — the sign and payload of a data NaN, or of the one
+    ``inf - inf`` makes — is the first operand's on x86, and the two
+    compilers do not order the operands alike."""
+    grouped = bool(plan.group_by)
+    state = _capped_state_cls()(plan, max_groups if grouped else 1)
+    try:
+        with _outside(turn):
+            swept = state.accumulate(batch)
+    except _OverCap:
+        stats["sweeps_native"] += 1
+        stats["early_rejects"] += 1
+        return None
+    if swept and not np.isnan(state.acc_f[:, : state.n_groups]).any():
+        stats["sweeps_native"] += 1  # (min/max slots hold clean values)
+        pt, reorder = state.partials(copy=False), grouped
+    else:
+        stats["sweeps_twin"] += 1
+        pt, reorder = PC.partials_from_batch(plan, batch), False
+        if pt is None:  # a column decoded outside the expected set
+            raise ValueError("uncapturable column set")
+    if grouped and pt.n_groups > max_groups:
+        return None
+    return _in_factorize_order(pt) if reorder else pt
+
+
+#: what one ``file_agg_doc`` call adds up in its ``stats`` dict
+_FILE_STATS = ("read_s", "sweeps_native", "sweeps_twin", "early_rejects")
 
 
 def _capture_ops(count_only, numeric):
@@ -166,47 +294,41 @@ def _capture_ops(count_only, numeric):
 
 def _partials_to_cols(pt, slots) -> Dict[str, Dict[str, list]]:
     """Per-column stored arrays (one cell per group) from one partials
-    snapshot — the inverse of :func:`rg_partials`' accumulator mapping."""
-    G = pt.n_groups
+    snapshot — the inverse of :func:`rg_partials`' accumulator mapping.
+    Floats are stored as their int64 bit views (the scalar codec)."""
+    acc_cnt = pt.acc_cnt.tolist()
+    acc_aux = pt.acc_aux.tolist()
+    acc_i = pt.acc_i.tolist()
+    acc_f = pt.acc_f.view(np.int64).tolist()
+
+    def where(vals, present):
+        return [v if p else None for v, p in zip(vals, present)]
+
     cols: Dict[str, Dict[str, list]] = {}
     for name, sl in slots.items():
         if "sum" not in sl:  # count-only column
-            a = sl["cnt"]
-            cols[name] = {"cnt": [int(pt.acc_cnt[a, g]) for g in range(G)]}
+            cols[name] = {"cnt": acc_cnt[sl["cnt"]]}
             continue
         a_sum, a_min, a_max = sl["sum"], sl["min"], sl["max"]
-        cnt = [int(pt.acc_cnt[a_sum, g]) for g in range(G)]
+        cnt = acc_cnt[a_sum]
         if sl["f64"]:
-            clean = [int(pt.acc_aux[a_min, g]) for g in range(G)]
-            nan = [int(pt.acc_aux[a_max, g]) for g in range(G)]
+            clean = acc_aux[a_min]
             cols[name] = {
                 "cnt": cnt,
                 "f64": 1,
-                "sum": [_enc_f64(pt.acc_f[a_sum, g]) for g in range(G)],
-                "min": [
-                    _enc_f64(pt.acc_f[a_min, g]) if clean[g] else None
-                    for g in range(G)
-                ],
-                "max": [
-                    _enc_f64(pt.acc_f[a_max, g]) if clean[g] else None
-                    for g in range(G)
-                ],
+                "sum": acc_f[a_sum],
+                "min": where(acc_f[a_min], clean),
+                "max": where(acc_f[a_max], clean),
                 "clean": clean,
-                "nan": nan,
+                "nan": acc_aux[a_max],
             }
         else:
             cols[name] = {
                 "cnt": cnt,
                 "f64": 0,
-                "sum": [int(pt.acc_i[a_sum, g]) for g in range(G)],
-                "min": [
-                    int(pt.acc_i[a_min, g]) if cnt[g] else None
-                    for g in range(G)
-                ],
-                "max": [
-                    int(pt.acc_i[a_max, g]) if cnt[g] else None
-                    for g in range(G)
-                ],
+                "sum": acc_i[a_sum],
+                "min": where(acc_i[a_min], cnt),
+                "max": where(acc_i[a_max], cnt),
             }
     return cols
 
@@ -225,26 +347,33 @@ def file_agg_doc(
     max_groups: int = C.INDEX_AGG_MAX_GROUPS_DEFAULT,
     sample_rows: int = C.INDEX_AGG_SAMPLE_ROWS_DEFAULT,
     group_keys: Optional[Tuple[str, ...]] = None,
-    read_s_out: Optional[List[float]] = None,
+    stats: Optional[Dict[str, float]] = None,
+    turn: Optional[threading.Lock] = None,
 ) -> Tuple[dict, Optional[pa.Table]]:
     """(sidecar entry, stratified sample table) for ONE index data file,
     computed from the file itself — the single definition shared by
-    build-time capture and the serve path's lazy backfill. Partials run
-    through ``pipeline_compiler.partials_from_batch`` (the fused sweep's
-    numpy twin), so the stored state is bit-identical to what the serve
-    kernel would have produced over the same rows.
+    build-time capture and the serve path's lazy backfill. Each row
+    group's partials are swept by the kernel serve itself runs
+    (:func:`_sweep`: ``hs_fused_filter_agg``, or its numpy twin
+    ``pipeline_compiler.partials_from_batch`` where the kernel cannot
+    run), so the stored state is bit-identical to what the serve kernel
+    would have produced over the same rows.
 
     ``group_keys`` restricts grouped-partial capture to those columns
     (lowercase match): the serve-path backfill passes the ONE key the
     query groups by, so a first serve over an unsidecar'd index pays one
     grouped sweep instead of one per numeric column; build-time capture
-    leaves it None (every fusable candidate). ``read_s_out`` receives
-    the seconds spent reading and decoding each row group, so capture
-    can tell its read from its partials."""
+    leaves it None (every fusable candidate). ``stats`` has the
+    ``_FILE_STATS`` keys added to: the seconds spent reading and
+    decoding the row groups, the sweeps by the implementation that ran
+    them, and the grouped sweeps abandoned at the cap. ``turn`` is a
+    lock the caller holds, shared with other files' calls: it is put
+    down around the reads and the sweeps (:func:`_outside`)."""
     from hyperspace_tpu.execution import pipeline_compiler as PC
     from hyperspace_tpu.io.columnar import ColumnarBatch
 
-    pf = pq.ParquetFile(path)
+    with _outside(turn):
+        pf = pq.ParquetFile(path)
     schema = pf.schema_arrow
     count_only, numeric = _capture_spec(schema)
     ops, slots = _capture_ops(count_only, numeric)
@@ -269,19 +398,22 @@ def file_agg_doc(
         key_candidates = [c for c in key_candidates if c.lower() in wanted]
     for c in key_candidates:
         entry["groups"][c] = []
+    if stats is None:
+        stats = dict.fromkeys(_FILE_STATS, 0)
+    ungrouped = _sweep_plan(PC, schema, None, ops)
+    by_key = {kc: _sweep_plan(PC, schema, kc, ops) for kc in key_candidates}
     samples: List[pa.Table] = []
     for gi in range(pf.metadata.num_row_groups):
         t_read = _time.perf_counter()
-        table = pf.read_row_group(gi)
+        with _outside(turn):
+            table = pf.read_row_group(gi)
         batch = ColumnarBatch.from_arrow(table)
-        if read_s_out is not None:
-            read_s_out.append(_time.perf_counter() - t_read)
+        stats["read_s"] += _time.perf_counter() - t_read
         n = batch.num_rows
         entry["rg_rows"].append(n)
-        pt = PC.partials_from_batch(_CaptureSpec((), ops), batch)
-        if pt is None:  # a column decoded outside the expected set
-            raise ValueError(f"uncapturable column set in {path}")
-        cols = _partials_to_cols(pt, slots)
+        cols = _partials_to_cols(
+            _sweep(PC, ungrouped, batch, 1, stats, turn), slots
+        )
         for c, cell in cols.items():
             dst = entry["cols"][c]
             for k, vals in cell.items():
@@ -293,9 +425,8 @@ def file_agg_doc(
         # A 4·cap-row PREFIX probe (canonical key_rep over a prefix
         # slice, O(cap) not O(rows)) rejects high-cardinality columns
         # cheaply — a prefix can only UNDER-count distincts, so it never
-        # rejects an eligible column; the full pass's own factorize then
-        # decides exactly (probe-passing over-cap columns are discarded
-        # by the n_groups check below).
+        # rejects an eligible column; the sweep then decides exactly,
+        # and stops at the first group past the cap.
         for kc in key_candidates:
             if n == 0 or max_groups <= 0:
                 entry["groups"][kc].append(None)
@@ -306,18 +437,18 @@ def file_agg_doc(
             if len(np.unique(probe)) > max_groups:
                 entry["groups"][kc].append(None)
                 continue
-            gpt = PC.partials_from_batch(_CaptureSpec((kc,), ops), batch)
-            if gpt is None or gpt.n_groups > max_groups:
+            gpt = _sweep(PC, by_key[kc], batch, max_groups, stats, turn)
+            if gpt is None:
                 entry["groups"][kc].append(None)
                 continue
             gcols = _partials_to_cols(gpt, slots)
             gentry: dict = {
-                "kv": [int(v) for v in gpt.g_kvals[0]],
-                "n": [int(v) for v in gpt.acc_cnt[0]],
+                "kv": gpt.g_kvals[0].tolist(),
+                "n": gpt.acc_cnt[0].tolist(),
                 "cols": gcols,
             }
             if gpt.key_has_validity[0]:
-                gentry["kn"] = [int(v) for v in gpt.g_kvalid[0]]
+                gentry["kn"] = gpt.g_kvalid[0].tolist()
             entry["groups"][kc].append(gentry)
         if sample_rows > 0 and n > 0:
             k = min(sample_rows, n)
@@ -379,6 +510,24 @@ def capture_index_dir(dir_path: str, index, conf=None) -> bool:
         return _capture_files(dir_path, max_groups, sample_rows, sp)
 
 
+def _map_files(fn, files: List[str]):
+    """(``[fn(f) for f in files]``, workers): on a bounded pool of this
+    call's own, as ``io/parquet._pool_map`` is — inline up to 4 files (a
+    small refresh), and never ``scan_pool``, whose tasks may not wait on
+    each other."""
+    from hyperspace_tpu import native
+
+    if len(files) <= 4:
+        return [fn(f) for f in files], 1
+    from concurrent.futures import ThreadPoolExecutor
+
+    workers = min(16, native._cores(), len(files))
+    with ThreadPoolExecutor(
+        max_workers=workers, thread_name_prefix="hs-aggcapture"
+    ) as pool:
+        return list(pool.map(fn, files)), workers
+
+
 def _capture_files(
     dir_path: str, max_groups: int, sample_rows: int, sp
 ) -> bool:
@@ -391,29 +540,47 @@ def _capture_files(
         return False
     if not files:
         return False
-    doc: dict = {"version": _SIDECAR_VERSION, "files": {}}
-    sample_tables: List[pa.Table] = []
-    read_s: List[float] = []
-    t_files = _time.perf_counter()
-    for f in files:
-        entry, sample = file_agg_doc(
-            f, max_groups, sample_rows, read_s_out=read_s
-        )
+
+    turn = threading.Lock()
+
+    def file_doc(f: str):
+        stats = dict.fromkeys(_FILE_STATS, 0)
+        t0 = _time.perf_counter()
+        with turn:
+            entry, sample = file_agg_doc(
+                f, max_groups, sample_rows, stats=stats, turn=turn
+            )
         st = os.stat(f)
         entry["size"] = st.st_size
         entry["mtime_ns"] = st.st_mtime_ns
-        doc["files"][os.path.basename(f)] = entry
-        if sample is not None:
-            sample_tables.append(sample)
+        stats["partials_s"] = _time.perf_counter() - t0 - stats["read_s"]
+        return entry, sample, stats
+
+    # the files are independent, and their reads and sweeps run outside
+    # the interpreter lock: one task a file, the Python of the tasks by
+    # turns; the document and the sample table take the order of
+    # ``files`` whatever order the tasks finish in
+    t_files = _time.perf_counter()
+    docs, workers = _map_files(file_doc, files)
     t_publish = _time.perf_counter()
+    entries, samples, stats = zip(*docs)
+    doc: dict = {
+        "version": _SIDECAR_VERSION,
+        "files": {os.path.basename(f): e for f, e in zip(files, entries)},
+    }
+    sample_tables = [t for t in samples if t is not None]
     sp.set("files", len(files))
-    sp.set("read_s", round(sum(read_s), 6))
-    sp.set("partials_s", round(t_publish - t_files - sum(read_s), 6))
+    sp.set("workers", workers)
+    sp.set("files_s", round(t_publish - t_files, 6))
+    for k in (*_FILE_STATS, "partials_s"):  # sums over files, like sum_s
+        sp.set(k, round(sum(st[k] for st in stats), 6))
     side_path = os.path.join(dir_path, SIDECAR_NAME)
     tmp = os.path.join(dir_path, f".{SIDECAR_NAME}.tmp.{os.getpid()}")
     try:
         with open(tmp, "w", encoding="utf-8") as fh:
-            json.dump(doc, fh)
+            # one C-encoder pass: json.dump streams the same text through
+            # the Python encoder, five times slower on a 4.5 MB document
+            fh.write(json.dumps(doc))
             fh.flush()
             os.fsync(fh.fileno())
         faults.crash("mid_sidecar_publish", side_path)

@@ -252,7 +252,10 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
     "hyperspace_tpu.indexes.aggindex.capture_index_dir": (
         "span",
         "sidecar_capture (aggstate): build-tail I/O that re-reads every "
-        "file just written; files/read_s/partials_s/publish_s/bytes as "
+        "file just written, one pool task a file; files/workers/files_s "
+        "(the pool's wall), read_s/partials_s (summed over the files), "
+        "sweeps_native/sweeps_twin/early_rejects (row-group passes by "
+        "the implementation that ran them) and publish_s/bytes as "
         "attrs, never a span per file",
     ),
     "hyperspace_tpu.indexes.zonemaps.capture_index_dir": (

@@ -464,6 +464,289 @@ class TestPartialsTwin:
         _tables_bit_equal(a, b)
 
 
+# ---------------------------------------------------------------------------
+# Capture sweep: the native kernel against its twin, and the pool's order
+# ---------------------------------------------------------------------------
+
+_CAP = 8  # max_groups of the sweep cases; row groups of 512 rows
+
+
+def _case_nulls(rng, n):
+    k = rng.integers(0, 6, n)
+    return {
+        "k": pa.array([None if i % 11 == 0 else int(v) for i, v in enumerate(k)],
+                      type=pa.int64()),
+        "v": pa.array([None if i % 7 == 0 else float(x)
+                       for i, x in enumerate(rng.normal(0, 4, n))]),
+        "w": pa.array([None if i % 5 == 0 else int(x)
+                       for i, x in enumerate(rng.integers(-9, 9, n))],
+                      type=pa.int64()),
+    }
+
+
+def _case_float_specials(rng, n):
+    # every column is a measure too: a NaN sum — of data NaNs and of
+    # inf - inf — only in the later row groups, of key and value alike
+    specials = np.array([-0.0, 0.0, np.inf, 1.5, -2.5, np.nan, -np.inf])
+    k = specials[rng.integers(0, 5, n)]
+    k[n // 2 :] = specials[rng.integers(0, 7, n - n // 2)]
+    v = rng.normal(0, 1e3, n)
+    v[::13] = -0.0
+    v[::17] = np.inf
+    v[n // 2 :: 9] = np.nan
+    v[n // 2 :: 19] = -np.inf
+    return {"k": pa.array(k), "v": pa.array(v)}
+
+
+def _case_int_wrap(rng, n):
+    big = np.full(n, (1 << 62) + 12345, dtype=np.int64)
+    big[::3] = -(1 << 61)
+    return {"k": pa.array(rng.integers(0, 4, n), type=pa.int64()),
+            "w": pa.array(big)}
+
+
+def _case_exact_cap(rng, n):
+    return {"k": pa.array(np.arange(n, dtype=np.int64) % _CAP),
+            "v": pa.array(rng.normal(0, 1, n))}
+
+
+def _case_cap_plus_one(rng, n):
+    # a ninth value, once a row group, past the probe's 4 x cap = 32 rows
+    k = np.arange(n, dtype=np.int64) % _CAP
+    k[np.arange(n) % 512 == 300] = _CAP
+    return {"k": pa.array(k), "v": pa.array(rng.normal(0, 1, n))}
+
+
+def _case_late_overflow(rng, n):
+    # few distinct keys in every row group's prefix, many behind it
+    pos = np.arange(n) % 512
+    k = np.where(pos < 300, pos % 3, 100 + pos).astype(np.int64)
+    return {"k": pa.array(k), "v": pa.array(rng.normal(0, 1, n))}
+
+
+def _case_string_column(rng, n):
+    names = np.array(["ash", "birch", "cedar", None], dtype=object)
+    return {"k": pa.array(rng.integers(0, 5, n), type=pa.int64()),
+            "s": pa.array(list(names[rng.integers(0, 4, n)]), type=pa.string()),
+            "d": pa.array(rng.integers(9000, 9040, n).astype(np.int32),
+                          type=pa.date32())}
+
+
+def _case_empty_file(rng, n):
+    return {"k": pa.array([], type=pa.int64()), "v": pa.array([], type=pa.float64())}
+
+
+def _case_group_keys(rng, n):
+    return {"k": pa.array(rng.integers(0, 5, n), type=pa.int64()),
+            "g": pa.array(rng.integers(0, 3, n), type=pa.int64()),
+            "v": pa.array(rng.normal(0, 1, n))}
+
+
+# case -> (columns, group_keys, grouped keys kept, grouped passes abandoned)
+_SWEEP_CASES = {
+    "nulls_in_measure_and_key": (_case_nulls, None, {"k"}, 0),
+    "nan_negzero_inf": (_case_float_specials, None, {"k"}, 0),
+    "int64_sum_wraps": (_case_int_wrap, None, {"k", "w"}, 0),
+    "key_with_exactly_max_groups": (_case_exact_cap, None, {"k"}, 0),
+    "key_with_max_groups_plus_one": (_case_cap_plus_one, None, set(), 4),
+    "key_over_cap_behind_the_probe": (_case_late_overflow, None, set(), 4),
+    "string_count_only_column": (_case_string_column, None, {"k"}, 0),
+    "empty_file": (_case_empty_file, None, set(), 0),
+    "group_keys_of_the_backfill": (_case_group_keys, ("G",), {"g"}, 0),
+}
+
+
+class TestCaptureSweep:
+    """``file_agg_doc`` sweeps with the kernel serve runs and falls back
+    to its numpy twin; the sidecar entry and the sample may not tell
+    which of the two ran, nor in what order a pool finished the files."""
+
+    @staticmethod
+    def _file(tmp_path, case, n=2048):
+        cols = _SWEEP_CASES[case][0](np.random.default_rng(41), n)
+        path = str(tmp_path / f"{case}.parquet")
+        pq.write_table(pa.table(cols), path, row_group_size=512)
+        return path
+
+    @staticmethod
+    def _doc(path, group_keys=None):
+        stats = dict.fromkeys(aggindex._FILE_STATS, 0)
+        entry, sample = aggindex.file_agg_doc(
+            path, _CAP, 16, group_keys, stats=stats
+        )
+        return entry, sample, stats
+
+    @pytest.mark.parametrize("case", list(_SWEEP_CASES))
+    def test_native_sweep_equals_twin(self, case, tmp_path, monkeypatch):
+        from hyperspace_tpu import native
+
+        if native.load() is None:
+            pytest.skip("native kernels unavailable")
+        _cols, group_keys, kept, early = _SWEEP_CASES[case]
+        path = self._file(tmp_path, case)
+        entry_n, sample_n, stats_n = self._doc(path, group_keys)
+        monkeypatch.setattr(native, "fused_filter_agg", lambda *a, **k: None)
+        entry_t, sample_t, stats_t = self._doc(path, group_keys)
+
+        assert json.dumps(entry_n) == json.dumps(entry_t)
+        if sample_n is None:
+            assert sample_t is None
+        else:
+            _tables_bit_equal(sample_n, sample_t)
+        assert set(entry_n["groups"]) == kept
+        # every pass counted once, under the implementation that ran it
+        passes = stats_n["sweeps_native"] + stats_n["sweeps_twin"]
+        assert passes == stats_t["sweeps_native"] + stats_t["sweeps_twin"]
+        assert stats_n["early_rejects"] == early
+        assert stats_t["early_rejects"] == 0
+        if case == "empty_file":
+            return  # a row group of no rows reaches neither
+        assert stats_n["sweeps_native"] > 0 and stats_t["sweeps_native"] == 0
+        # the twin decides a float sum that came out NaN, and nothing else
+        assert (stats_n["sweeps_twin"] > 0) == (case == "nan_negzero_inf")
+
+    def test_cap_is_exact_and_rejects_at_the_row(self, tmp_path):
+        """Eight distinct keys are kept, a ninth rejects the row group —
+        through the sweep itself, not the prefix probe — and the kernel
+        stops there: what it had consumed is less than the row group."""
+        from hyperspace_tpu import native
+
+        if native.load() is None:
+            pytest.skip("native kernels unavailable")
+        kept, _s, st = self._doc(
+            self._file(tmp_path, "key_with_exactly_max_groups")
+        )
+        assert [len(g["kv"]) for g in kept["groups"]["k"]] == [_CAP] * 4
+        assert st["early_rejects"] == 0
+        for case in ("key_with_max_groups_plus_one",
+                     "key_over_cap_behind_the_probe"):
+            entry, _s, st = self._doc(self._file(tmp_path, case))
+            assert entry["groups"] == {}
+            assert st["early_rejects"] == 4, case  # one a row group
+            # 4 ungrouped + 4 abandoned "k" passes ("v" fails the probe)
+            assert st["sweeps_native"] == 8, case
+
+    @staticmethod
+    def _version_dir(tmp_path, n_files=12):
+        rng = np.random.default_rng(43)
+        d = tmp_path / "v__=0"
+        d.mkdir()
+        for i in range(n_files):
+            n = 700 + 13 * i
+            pq.write_table(
+                pa.table({
+                    "k": pa.array(rng.integers(0, 5, n), type=pa.int64()),
+                    "v": pa.array(rng.normal(0, 2, n)),
+                    "s": pa.array([f"r{j % 3}" for j in range(n)]),
+                }),
+                str(d / f"part-{i:05d}-bucket_{i}.parquet"),
+                row_group_size=512,
+            )
+        return str(d)
+
+    @pytest.mark.parametrize("how", ["reversed_completion", "short_switch_interval"])
+    def test_many_files_keep_file_order(self, how, tmp_path, monkeypatch):
+        """Twelve tasks forced to finish in reverse, or 24 with more
+        threads than cores switching every 10 us: the sidecars are the
+        parent's serial loop over the twin, byte for byte."""
+        import sys
+        import threading
+
+        from hyperspace_tpu import native
+
+        d = self._version_dir(tmp_path, 12 if how == "reversed_completion" else 24)
+        files = pio.list_format_files(d, "parquet")
+        with monkeypatch.context() as m:
+            m.setattr(native, "fused_filter_agg", lambda *a, **k: None)
+            golden = {"version": 1, "files": {}}
+            golden_samples = []
+            for f in files:
+                entry, sample = aggindex.file_agg_doc(f)
+                st = os.stat(f)
+                entry["size"], entry["mtime_ns"] = st.st_size, st.st_mtime_ns
+                golden["files"][os.path.basename(f)] = entry
+                golden_samples.append(sample)
+
+        # every task waits for the file after it: completion is reversed
+        real, finished = aggindex.file_agg_doc, []
+        done = {f: threading.Event() for f in files}
+
+        def after_the_next(path, *a, **k):
+            out = real(path, *a, **k)
+            i = files.index(path)
+            if i + 1 < len(files):
+                with aggindex._outside(k["turn"]):  # as a read would wait
+                    assert done[files[i + 1]].wait(30), "tasks did not overlap"
+            finished.append(path)
+            done[path].set()
+            return out
+
+        monkeypatch.setattr(native, "_cores", lambda: 16)  # a thread a file
+        interval = sys.getswitchinterval()
+        try:
+            if how == "reversed_completion":
+                monkeypatch.setattr(aggindex, "file_agg_doc", after_the_next)
+            else:
+                sys.setswitchinterval(1e-5)
+            assert aggindex.capture_index_dir(d, _CoveringKind())
+        finally:
+            sys.setswitchinterval(interval)
+        if how == "reversed_completion":
+            assert finished == files[::-1]
+
+        with open(os.path.join(d, aggindex.SIDECAR_NAME), encoding="utf-8") as fh:
+            text = fh.read()
+        assert list(json.loads(text)["files"]) == [
+            os.path.basename(f) for f in files
+        ]
+        assert text == json.dumps(golden)
+        sample = pq.read_table(os.path.join(d, aggindex.SAMPLE_NAME))
+        assert sample.equals(
+            pa.concat_tables(golden_samples, promote_options="permissive")
+        )
+
+    def test_one_file_failing_fails_no_build_and_publishes_nothing(
+        self, s1, tmp_path, monkeypatch
+    ):
+        rng = np.random.default_rng(47)
+        n = 6000
+        d = _write_files(tmp_path, "onebad", pa.table({
+            "c": pa.array(rng.integers(0, 40_000, n), type=pa.int64()),
+            "p": pa.array(rng.integers(0, 6, n), type=pa.int64()),
+        }))
+        real, seen = aggindex.file_agg_doc, []
+
+        def third_file_bad(path, *a, **k):
+            seen.append(path)
+            if len(seen) == 3:
+                raise ValueError("uncapturable column set")
+            return real(path, *a, **k)
+
+        monkeypatch.setattr(aggindex, "file_agg_doc", third_file_bad)
+        s1.conf.set(C.INDEX_NUM_BUCKETS, 8)
+        hs = Hyperspace(s1)
+        hs.create_index(
+            s1.read.parquet(d), CoveringIndexConfig("ci_onebad", ["c"], ["p"])
+        )
+        idx_root = os.path.join(s1.conf.get(C.INDEX_SYSTEM_PATH), "ci_onebad")
+        assert len(seen) >= 3
+        assert _sidecar_paths(idx_root) == []
+        left = [
+            n_ for _r, _d, names in os.walk(idx_root) for n_ in names
+            if "_agg" in n_
+        ]
+        assert left == [], left  # no half-written sidecar, no temp file
+        # the build stands, and the serve path backfills what is missing
+        monkeypatch.setattr(aggindex, "file_agg_doc", real)
+        fresh = s1.read.parquet(d)
+        _four_way(s1, lambda: fresh.filter(fresh["c"] >= 0).group_by("p").agg(
+            F.count().alias("n")).collect())
+
+
+class _CoveringKind:
+    kind = "CoveringIndex"
+
+
 class TestLifecycle:
     def _mk(self, s1, tmp_path, name="lc", n=6000):
         hs = Hyperspace(s1)

@@ -570,8 +570,49 @@ class TestActionAccount:
             if sp.name == "sidecar_capture"
         }
         assert set(sidecars) == {"aggstate", "zonemap"}
-        for key in ("files", "read_s", "partials_s", "publish_s", "bytes"):
+        for key in ("files", "read_s", "partials_s", "publish_s", "bytes",
+                    "workers", "files_s", "sweeps_native", "sweeps_twin",
+                    "early_rejects"):
             assert key in sidecars["aggstate"], key
+
+    def test_aggstate_span_counts_its_sweeps(
+        self, session_factory, tmp_path, monkeypatch
+    ):
+        """Still ONE span for the capture, however many files a pool
+        takes: what ran on it is attrs — the files, the workers, the
+        passes by the implementation that made them."""
+        import itertools
+
+        from hyperspace_tpu import native
+        from hyperspace_tpu.indexes import aggindex
+
+        passes, real = itertools.count(), aggindex._sweep
+
+        def counted(*a, **k):
+            next(passes)
+            return real(*a, **k)
+
+        monkeypatch.setattr(aggindex, "_sweep", counted)
+        monkeypatch.setattr(native, "_cores", lambda: 3)
+        _build(session_factory, tmp_path, warm=False, buckets=8)
+        root = trace.finished("action.CreateAction")[-1]
+        spans = [
+            sp for sp in root.spans
+            if sp.name == "sidecar_capture" and sp.attrs["sidecar"] == "aggstate"
+        ]
+        assert len(spans) == 1
+        assert {sp.name for sp in root.spans if sp.parent_id == spans[0].span_id} == set()
+        at = spans[0].attrs
+        assert at["files"] == root.attrs["index_files"] == 8
+        assert at["workers"] == 3  # min(16, cores, files), past 4 files
+        assert at["sweeps_native"] + at["sweeps_twin"] == next(passes) > 0
+        if native.load() is not None:
+            assert at["sweeps_twin"] == 0
+        assert 0 <= at["early_rejects"] <= at["sweeps_native"]
+        # read_s and partials_s are sums over the files, on their threads
+        for key in ("read_s", "partials_s", "files_s", "publish_s"):
+            assert at[key] >= 0.0, key
+        assert at["files_s"] + at["publish_s"] <= spans[0].duration_s
 
     def test_breakdown_is_the_same_measurement(
         self, session_factory, tmp_path
