@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import logging
 import os
 import threading as _threading
 import time as _time
@@ -56,6 +57,8 @@ from hyperspace_tpu.io.columnar import Column, ColumnarBatch
 from hyperspace_tpu.ops.hash import bucket_ids_np
 from hyperspace_tpu.ops.sort import sort_permutation
 from hyperspace_tpu.utils import resolver
+
+_log = logging.getLogger("hyperspace_tpu.build")
 
 
 # ---------------------------------------------------------------------------
@@ -417,11 +420,14 @@ def prepare_covering_index(ctx, source_df, config, properties: Dict[str, str]):
 # moving parquet decode on-device). Keys are the names ``stage`` below
 # was entered with: the four long-standing ones (scan / hash_shuffle /
 # sort / write) and whatever else the op ran (resolve, dict_probe,
-# sidecar_capture, the exchange's pack / exchange / unpack). Under the
-# sharded tail the sort/write stages run per shard concurrently, so
-# those values are BUSY time summed across shards (may exceed wall time
-# — the excess over ``tail_wall`` is the sharding win); ``tail_shards``
-# records how many shard tails ran.
+# sidecar_capture), plus — on a mesh — the exchange's exchange_plan /
+# pack / exchange / unpack, whose seconds are those of the same-named
+# spans ``parallel/shuffle.py`` records under ``hash_shuffle``
+# (``_record_shuffle_telemetry``). Under the sharded tail the sort/write
+# stages run per shard concurrently, so those values are BUSY time
+# summed across shards (may exceed wall time — the excess over
+# ``tail_wall`` is the sharding win); ``tail_shards`` records how many
+# shard tails ran.
 #
 # Obs plane (docs/observability.md): this dict is the backing storage
 # of a REGISTERED instrument — ``registry.stage_timer`` below adopts
@@ -439,12 +445,15 @@ _obs_metrics.registry.stage_timer(
     lock=_build_bd_lock,
 )
 
-# Non-timing telemetry of the most recent build: the exchange plane's
-# snapshot (``parallel/shuffle.last_shuffle_stats`` — chosen strategy,
-# pack/exchange/unpack seconds, capacity, per-(shard, peer) skew),
-# folded in per exchange by ``_record_shuffle_telemetry`` (stage seconds
-# summed across waves, skew carried as max/mean + wave count) so the
-# bench and operators read one coherent snapshot.
+# Telemetry of the most recent build beside the stage seconds: the
+# exchange plane's snapshot (``parallel/shuffle.last_shuffle_stats`` —
+# chosen strategy, capacity, per-(shard, peer) skew, and the seconds and
+# bytes of its spans: ``shuffle_plan_s`` / ``pack_s`` / ``exchange_s`` /
+# ``unpack_s``, ``shuffle_wire_bytes`` / ``slot_bytes`` / ``h2d_bytes`` /
+# ``d2h_bytes``), folded in per exchange by ``_record_shuffle_telemetry``
+# (seconds and bytes summed across waves, skew carried as max/mean +
+# wave count) so the bench and operators read one coherent snapshot.
+# Every second in it is a span's: the action trace holds the intervals.
 last_build_telemetry: Dict[str, object] = {}
 
 
@@ -477,14 +486,6 @@ def stage(name: str, **attrs):
         if seconds is None:  # no live trace: the stage's own clock
             seconds = (_time.perf_counter_ns() - t0) / 1e9
         _breakdown_add(name, seconds)
-
-
-def _stage_summed(name: str, seconds: float) -> None:
-    """A stage whose busy seconds the pass summed itself (the exchange's
-    pack/exchange/unpack over its waves): no interval, so its span is
-    marked ``summed`` and stays out of every union and self time."""
-    _breakdown_add(name, seconds)
-    _obs_trace.stage(name, seconds=seconds)
 
 
 def sidecar_published(sp, paths: Sequence[str], publish_s: float) -> None:
@@ -684,23 +685,25 @@ def _hash_shuffle(
     return buckets, reps, batch, shard_offs
 
 
-_EXCHANGE_STAGE_KEYS = ("pack_s", "exchange_s", "unpack_s")
-
-
 def _record_shuffle_telemetry(stats: Dict) -> None:
     """Fold one exchange's snapshot into the build telemetry: latest
-    value for every ``shuffle_<key>``, pack/exchange/unpack seconds
+    value for every ``shuffle_<key>``, the stage seconds and the bytes
     SUMMED across waves, and the per-wave skew carried as a max/mean
     pair plus the wave count (a streaming build runs one exchange per
     wave; a single hot wave must stay visible in the max while the mean
-    says whether it was the rule or the exception)."""
+    says whether it was the rule or the exception). The stage seconds
+    are the exchange's own spans' (``shuffle._timed``): they also feed
+    ``last_build_breakdown`` under the spans' names, as ``stage`` does
+    for the build's stages — one measurement, no second timer."""
+    from hyperspace_tpu.parallel.shuffle import BYTES_KEYS, STAGE_SECONDS_KEYS
+
     with _build_bd_lock:
         t = last_build_telemetry
         waves = t.get("shuffle_waves", 0.0) + 1.0
         for k, v in stats.items():
             key = "shuffle_" + k
-            if k in _EXCHANGE_STAGE_KEYS:
-                t[key] = round(t.get(key, 0.0) + float(v), 4)
+            if k in STAGE_SECONDS_KEYS or k in BYTES_KEYS:
+                t[key] = t.get(key, 0.0) + float(v)
             else:
                 t[key] = v
         skew = float(stats.get("skew_ratio", 1.0))
@@ -712,11 +715,9 @@ def _record_shuffle_telemetry(stats: Dict) -> None:
         t["shuffle_skew_ratio_mean"] = round(
             prev_mean + (skew - prev_mean) / waves, 3
         )
-    # the exchange is one fused pass, opaque to any outer timer: its own
-    # measured seconds are the only account of it
-    for k in _EXCHANGE_STAGE_KEYS:
+    for k, span_name in STAGE_SECONDS_KEYS.items():
         if stats.get(k):
-            _stage_summed(k.removesuffix("_s"), float(stats[k]))
+            _breakdown_add(span_name, float(stats[k]))
 
 
 def _partition_first(ctx) -> bool:
@@ -936,10 +937,12 @@ def _write_bucketed_pipelined(
     os.makedirs(ctx.index_data_path, exist_ok=True)
     shard_offs = _sharded_tail_offsets(ctx, shard_offs)
     if shard_offs is not None:
-        return _write_bucketed_sharded(
+        written = _write_bucketed_sharded(
             ctx, buckets, reps, batch, file_idx_offset, use_dict,
             num_buckets, shard_offs,
         )
+        if written is not None:
+            return written
     sort_s: List[float] = []
     with ThreadPoolExecutor(max_workers=1) as writer:
         futures = []
@@ -975,6 +978,10 @@ def _write_bucketed_pipelined(
     return written
 
 
+class _ForeignBuckets(Exception):
+    """A shard's slice holds rows of a bucket another shard owns."""
+
+
 def _write_bucketed_sharded(
     ctx,
     buckets: np.ndarray,
@@ -984,7 +991,7 @@ def _write_bucketed_sharded(
     use_dict,
     num_buckets: int,
     shard_offs: np.ndarray,
-) -> List[str]:
+) -> Optional[List[str]]:
     """Device-local tail of the in-memory sharded build: each mesh
     shard's post-exchange slice (exactly the buckets it owns) runs the
     partition-first pipeline — counting scatter, per-bucket key sorts,
@@ -999,6 +1006,13 @@ def _write_bucketed_sharded(
     the global stable (bucket, keys...) sort restricted to that bucket.
     The encoding decision (``use_dict``) was computed once by the caller
     on the shared pre-sort input.
+
+    One writer a bucket file rests on that ownership. A slice that holds
+    rows of a bucket its shard does not own (bucket ids that diverged
+    from the exchange's plan) would have two shards write one file at
+    once, so such a shard writes nothing, what the others wrote is taken
+    away and None is returned: the caller's single tail then writes what
+    the ids say, as a one-device build does.
 
     Stage accounting: "sort"/"write" accumulate per-shard BUSY time
     (their sum can exceed wall time — the excess is the sharding win);
@@ -1015,8 +1029,11 @@ def _write_bucketed_sharded(
     )
 
     t_tail = _time.perf_counter()
-    planes = _order_words_np(reps.astype(np.int64, copy=False))
-    table = batch.to_arrow()
+    n_shards = len(shard_offs) - 1
+    with _obs_trace.span("partition"):
+        planes = _order_words_np(reps.astype(np.int64, copy=False))
+    with _obs_trace.span("to_arrow"):
+        table = batch.to_arrow()
     shards, threads = shard_tail_plan(shard_offs)
 
     def run_shard(s: int) -> List[Tuple[int, str]]:
@@ -1030,6 +1047,9 @@ def _write_bucketed_sharded(
                 order, offsets = partition_by_bucket(
                     buckets[lo:hi], num_buckets
                 )
+                held = np.flatnonzero(np.diff(offsets))
+                if (held % n_shards != s).any():
+                    raise _ForeignBuckets(s)
                 order += lo  # global row coordinates into planes/table
                 for b, final_idx in bucket_key_sort_runs(
                     planes, order, offsets, workers=1, n_threads=threads,
@@ -1061,13 +1081,25 @@ def _write_bucketed_sharded(
 
     # the shard tails run on pool threads: hand them the action's span
     run_shard = _obs_trace.carry(run_shard)
-    if len(shards) == 1:
-        results = [run_shard(shards[0])]
-    else:
-        with ThreadPoolExecutor(
-            max_workers=len(shards), thread_name_prefix="hs-shardtail"
-        ) as pool:
-            results = list(pool.map(run_shard, shards))
+    results, foreign = [], []
+    with ThreadPoolExecutor(
+        max_workers=len(shards), thread_name_prefix="hs-shardtail"
+    ) as pool:
+        for fut in [pool.submit(run_shard, s) for s in shards]:
+            try:
+                results.append(fut.result())
+            except _ForeignBuckets as e:
+                foreign.append(e.args[0])
+    if foreign:
+        _log.warning(
+            "sharded tail: the slices of shards %s hold rows of buckets "
+            "they do not own (bucket ids diverged from the exchange's "
+            "plan); writing through the single tail",
+            foreign,
+        )
+        for _b, path in (p for r in results for p in r):
+            os.remove(path)
+        return None
     with _build_bd_lock:
         last_build_breakdown["tail_wall"] = (
             last_build_breakdown.get("tail_wall", 0.0)

@@ -101,7 +101,11 @@ BUILD_STAGES = (
     "partition",
     "to_arrow",
     "bucket_sorts",
-    # the exchange's own busy seconds, summed over waves (no interval)
+    # under hash_shuffle on a mesh (parallel/shuffle.py): the host's
+    # plan and pack, the host-seen device leg — h2d / kernel / d2h under
+    # it, the names the single-chip hash uses for the same three things
+    # — and the host's unpack
+    "exchange_plan",
     "pack",
     "exchange",
     "unpack",
@@ -199,12 +203,6 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
         "profiler annotation) and the breakdown increment are the same "
         "measurement, so they cannot disagree",
     ),
-    "hyperspace_tpu.indexes.covering_build._stage_summed": (
-        "span",
-        "pack/exchange/unpack from the exchange's own measured seconds: "
-        "the fused shuffle pass is opaque to any outer timer, so only "
-        "its built-in measurement can explain it (a summed span)",
-    ),
     "hyperspace_tpu.indexes.covering_build.prepare_covering_index": (
         "span",
         "resolve stage: schema resolution, lineage ids and per-file "
@@ -240,8 +238,24 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
     ),
     "hyperspace_tpu.indexes.covering_build._write_bucketed_sharded": (
         "span",
-        "one sort and one write span per SHARD tail (attr shard), "
-        "carried onto the shard pool's threads",
+        "partition (order words) and to_arrow once before the shard "
+        "pool, then one sort and one write span per SHARD tail (attr "
+        "shard), carried onto the shard pool's threads",
+    ),
+    "hyperspace_tpu.parallel.shuffle._device_leg": (
+        "span",
+        "exchange and its h2d / kernel / d2h children: the device leg "
+        "of every strategy that has one goes through this one function, "
+        "so a 1.5 ms all_to_all inside seconds of host-seen exchange is "
+        "told from its transfers",
+    ),
+    "hyperspace_tpu.parallel.shuffle._timed": (
+        "span",
+        "exchange_plan / pack / exchange / unpack of every exchange "
+        "strategy (flat, host, compact, both twostage legs): each "
+        "span's seconds are also the exchange's account, so the host "
+        "packing that outweighs the device leg (PERF.md) is readable "
+        "apart from it; host has no device leg and no unpack",
     ),
     "hyperspace_tpu.ops.hash.bucket_ids_np": (
         "span",

@@ -66,10 +66,11 @@ exercise it (HS703/HS804).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import logging
 import time as _time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -77,15 +78,20 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from hyperspace_tpu.obs import trace as _obs_trace
+
 _log = logging.getLogger("hyperspace_tpu.shuffle")
 
 # Telemetry of the most recent ``bucket_shuffle`` (host-observed):
-# strategy name, pack/exchange/unpack stage seconds, exchange capacity
-# and the per-(shard, peer) send-count skew. The padded-buffer
-# strategies size slots from the MAX count, so one hot bucket inflates
-# exchange memory by ~skew× silently — the build copies this into its
-# telemetry (accumulating per-wave skew as max/mean) and the bench
-# publishes it.
+# strategy name, the seconds of its stages (``plan_s`` / ``pack_s`` /
+# ``exchange_s`` / ``unpack_s`` — the SAME measurements as the
+# ``exchange_plan`` / ``pack`` / ``exchange`` / ``unpack`` spans, see
+# :func:`_timed`), exchange capacity, the bytes that had to cross chips
+# (``wire_bytes``) beside the bytes that were sent (``slot_bytes``) and
+# the per-(shard, peer) send-count skew. The padded-buffer strategies
+# size slots from the MAX count, so one hot bucket inflates exchange
+# memory by ~skew× silently — the build copies this into its telemetry
+# (accumulating per-wave skew as max/mean) and the bench publishes it.
 last_shuffle_stats: Dict[str, float] = {}
 
 # Once-per-build latch for the shuffle-skew warning: the streaming build
@@ -116,6 +122,17 @@ STRATEGIES = (
     STRATEGY_HOST,
     STRATEGY_TWOSTAGE,
 )
+
+# ``last_shuffle_stats`` key -> the span whose seconds it holds
+STAGE_SECONDS_KEYS = {
+    "plan_s": "exchange_plan",
+    "pack_s": "pack",
+    "exchange_s": "exchange",
+    "unpack_s": "unpack",
+}
+# ``last_shuffle_stats`` keys that are bytes: each also a root counter
+# ``exchange_<key>`` of the running action, summed over waves
+BYTES_KEYS = ("h2d_bytes", "d2h_bytes", "wire_bytes", "slot_bytes")
 
 
 def reset_skew_warning() -> None:
@@ -160,10 +177,99 @@ def _peer_counts(
     return np.bincount(src * D + owner, minlength=D * D).reshape(D, D)
 
 
+@contextlib.contextmanager
+def _timed(acct: Dict, key: str):
+    """One measurement, two views (as ``covering_build.stage`` does for
+    the build's named stages): enter the span ``STAGE_SECONDS_KEYS``
+    names for ``key`` — a ``trace.span`` of the running action, with its
+    interval on the one clock and its ``hs.<name>`` profiler annotation —
+    and add that span's OWN seconds to ``acct[key]``, the exchange's
+    account that ``_publish_stats`` turns into ``last_shuffle_stats``.
+    Outside an action (a bare ``bucket_shuffle``, the calibration probe)
+    the span is the no-op singleton and the block's own clock feeds the
+    account."""
+    t0 = _time.perf_counter_ns()
+    sp = _obs_trace.NOOP
+    try:
+        with _obs_trace.span(STAGE_SECONDS_KEYS[key]) as sp:
+            yield sp
+    finally:
+        seconds = sp.duration_s
+        if seconds is None:
+            seconds = (_time.perf_counter_ns() - t0) / 1e9
+        acct[key] = acct.get(key, 0.0) + seconds
+
+
+def _skew_ratio(counts: np.ndarray) -> float:
+    """Hottest (source, peer) slot over the mean slot."""
+    mean_count = float(counts.mean()) if counts.size else 0.0
+    return float(counts.max()) / mean_count if mean_count > 0 else 1.0
+
+
+def _plan_attrs(sp, strategy: str, D: int, cap: int, counts: np.ndarray) -> None:
+    """What the ``exchange_plan`` span decided, on the span."""
+    sp.set("strategy", strategy)
+    sp.set("devices", D)
+    sp.set("cap", int(cap))
+    sp.set("skew_ratio", round(_skew_ratio(counts), 2))
+
+
+def _nbytes(arrays) -> int:
+    return int(sum(a.nbytes for a in jax.tree_util.tree_leaves(arrays)))
+
+
+def _transferred(sp, acct: Dict, key: str, arrays) -> None:
+    """The bytes of one transfer: on its span and into the account."""
+    n = _nbytes(arrays)
+    sp.set("bytes", n)
+    acct[key] = acct.get(key, 0) + n
+
+
+def _off_chip_rows(counts: np.ndarray) -> int:
+    """Rows of the ``[D, D]`` (source, owner) matrix whose owner is
+    another chip than their source."""
+    return int(counts.sum() - np.trace(counts))
+
+
+def _device_leg(acct: Dict, put: Callable, run: Callable, fetch: Callable):
+    """The device leg of an exchange, as the host sees it — the
+    ``exchange`` span and its three children: ``h2d`` (operands placed
+    shard by shard, to ``block_until_ready``), ``kernel`` (the program's
+    launch under ``mesh_dispatch_lock`` to ``block_until_ready`` of its
+    outputs) and ``d2h`` (``fetch``: the outputs back as numpy). The
+    bytes of both transfers go on their spans and into the account."""
+    with _timed(acct, "exchange_s"):
+        with _obs_trace.span("h2d") as sp:
+            operands = jax.block_until_ready(put())
+            _transferred(sp, acct, "h2d_bytes", operands)
+        with _obs_trace.span("kernel"):
+            with mesh_dispatch_lock:
+                out = run(operands)
+            out = jax.block_until_ready(out)
+        with _obs_trace.span("d2h") as sp:
+            host = fetch(out)
+            _transferred(sp, acct, "d2h_bytes", host)
+    return host
+
+
 def _publish_stats(
-    strategy: str, D: int, cap: int, counts: np.ndarray, extra: Dict
+    strategy: str,
+    D: int,
+    cap: int,
+    counts: np.ndarray,
+    acct: Dict,
+    wire_bytes: int = 0,
+    slot_bytes: int = 0,
+    extra: Optional[Dict] = None,
 ) -> None:
-    """Build the telemetry snapshot + once-per-build skew warning.
+    """Build the telemetry snapshot + once-per-build skew warning, and
+    add this exchange's bytes to the running action's root counters
+    (``exchange_*``: summed over a streaming build's waves).
+
+    ``wire_bytes`` is what any implementation must move — the payload
+    bytes of the rows whose owner is another chip than their source —
+    and ``slot_bytes`` what this strategy sent, padding and extra planes
+    included; both 0 for ``host``, which has no device leg.
 
     Publishes as ONE atomic rebind, never clear()+update(): a concurrent
     build copying the snapshot (covering_build telemetry) must see a
@@ -176,16 +282,24 @@ def _publish_stats(
 
     max_count = int(counts.max()) if counts.size else 0
     mean_count = float(counts.mean()) if counts.size else 0.0
-    skew = max_count / mean_count if mean_count > 0 else 1.0
+    skew = _skew_ratio(counts)
     stats: Dict = {
         "strategy": strategy,
         "devices": float(D),
         "cap": float(cap),
+        "wire_bytes": float(wire_bytes),
+        "slot_bytes": float(slot_bytes),
+        "h2d_bytes": float(acct.get("h2d_bytes", 0)),
+        "d2h_bytes": float(acct.get("d2h_bytes", 0)),
         "max_peer_count": float(max_count),
         "mean_peer_count": round(mean_count, 1),
         "skew_ratio": round(skew, 2),
     }
-    stats.update(extra)
+    stats.update({k: acct.get(k, 0.0) for k in STAGE_SECONDS_KEYS})
+    stats.update(extra or {})
+    for key in BYTES_KEYS:
+        _obs_trace.accumulate("exchange_" + key, int(stats[key]))
+    _obs_trace.accumulate("exchange_waves", 1)
     global last_shuffle_stats, _skew_warned
     last_shuffle_stats = stats
     if (
@@ -378,58 +492,71 @@ def _flat_exchange(mesh, key_reps, payloads, num_buckets, seed):
 
     D = mesh.devices.size
     n = key_reps.shape[1]
-    t0 = _time.perf_counter()
+    acct: Dict = {}
     # power-of-two row count (ops/__init__ shape policy), then round up
     # to a multiple of D so shard_map divides evenly
     target = pad_len(n)
     target += (-target) % D
     pad = target - n
-    if pad:
-        key_reps = np.pad(key_reps, ((0, 0), (0, pad)))
-        payloads = [np.pad(p, (0, pad)) for p in payloads]
-    valid = np.ones(n + pad, dtype=bool)
-    if pad:
-        valid[n:] = False
-    bucket_host = _host_bucket_ids(key_reps, num_buckets, seed)
-    cap, counts = _flat_cap(bucket_host, valid, D)
-    pack_s = _time.perf_counter() - t0
-    t0 = _time.perf_counter()
+    with _timed(acct, "plan_s") as sp:
+        if pad:
+            key_reps = np.pad(key_reps, ((0, 0), (0, pad)))
+        valid = np.ones(n + pad, dtype=bool)
+        if pad:
+            valid[n:] = False
+        bucket_host = _host_bucket_ids(key_reps, num_buckets, seed)
+        cap, counts = _flat_cap(bucket_host, valid, D)
+        _plan_attrs(sp, STRATEGY_FLAT, D, cap, counts)
+    row_bytes = sum(p.dtype.itemsize for p in payloads)
+    with _timed(acct, "pack_s") as sp:
+        # the device scatters rows into their slots; the host's share of
+        # the pack is the padding to the program's row count
+        if pad:
+            payloads = [np.pad(p, (0, pad)) for p in payloads]
+        sp.set("bytes", _nbytes(payloads))
     # operands go host -> owning device shard by shard (put_sharded);
     # the program's in_specs then find them already in place
-    operands = (
-        put_sharded(mesh, bucket_host),
-        put_sharded(mesh, valid),
-        tuple(put_sharded(mesh, p) for p in payloads),
+    bucket, vmask, cols = _device_leg(
+        acct,
+        lambda: (
+            put_sharded(mesh, bucket_host),
+            put_sharded(mesh, valid),
+            tuple(put_sharded(mesh, p) for p in payloads),
+        ),
+        lambda ops: _flat_program(
+            mesh, *ops, num_buckets, len(payloads), cap
+        ),
+        lambda out: (
+            np.asarray(out[0]),
+            np.asarray(out[1]),
+            [np.asarray(c) for c in out[2]],
+        ),
     )
-    with mesh_dispatch_lock:
-        bucket, vmask, cols = _flat_program(
-            mesh, *operands, num_buckets, len(payloads), cap
+    with _timed(acct, "unpack_s") as sp:
+        keep = np.nonzero(vmask)[0]
+        if len(keep) != n:
+            raise RuntimeError(
+                f"bucket shuffle lost rows: sent {n}, received {len(keep)} "
+                f"(cap={cap}) — host/device hash divergence?"
+            )
+        out_bucket = bucket[keep]
+        out_cols = [c[keep] for c in cols]
+        # shard s's post-exchange slice is rows [s*D*cap, (s+1)*D*cap) of
+        # the flat output; its compacted extent is the valid count per slice
+        per_shard = vmask.reshape(D, D * cap).sum(axis=1)
+        offsets = np.concatenate(
+            [np.zeros(1, dtype=np.int64), np.cumsum(per_shard, dtype=np.int64)]
         )
-    bucket = np.asarray(bucket)
-    vmask = np.asarray(vmask)
-    exchange_s = _time.perf_counter() - t0
-    t0 = _time.perf_counter()
-    keep = np.nonzero(vmask)[0]
-    if len(keep) != n:
-        raise RuntimeError(
-            f"bucket shuffle lost rows: sent {n}, received {len(keep)} "
-            f"(cap={cap}) — host/device hash divergence?"
-        )
-    out_bucket = bucket[keep]
-    out_cols = [np.asarray(c)[keep] for c in cols]
-    # shard s's post-exchange slice is rows [s*D*cap, (s+1)*D*cap) of
-    # the flat output; its compacted extent is the valid count per slice
-    per_shard = vmask.reshape(D, D * cap).sum(axis=1)
-    offsets = np.concatenate(
-        [np.zeros(1, dtype=np.int64), np.cumsum(per_shard, dtype=np.int64)]
-    )
-    unpack_s = _time.perf_counter() - t0
+        sp.set("bytes", _nbytes(out_cols))
     _publish_stats(
         STRATEGY_FLAT,
         D,
         cap,
         counts,
-        _timing(pack_s, exchange_s, unpack_s),
+        acct,
+        wire_bytes=_off_chip_rows(counts) * row_bytes,
+        # every slot carries its payloads, its bucket id and its validity
+        slot_bytes=D * D * cap * (row_bytes + 4 + 1),
     )
     return out_bucket, out_cols, offsets
 
@@ -446,14 +573,6 @@ def _flat_cap(
     counts = _peer_counts(bucket_host % D, valid, n_local, D)
     max_count = max(int(counts.max()), 1)
     return min(pad_len(max_count), n_local), counts
-
-
-def _timing(pack_s: float, exchange_s: float, unpack_s: float) -> Dict:
-    return {
-        "pack_s": round(pack_s, 4),
-        "exchange_s": round(exchange_s, 4),
-        "unpack_s": round(unpack_s, 4),
-    }
 
 
 def _exchange_cap(
@@ -486,26 +605,27 @@ def _host_exchange(mesh, key_reps, payloads, num_buckets, seed):
     argsorts, host↔device copies) is pure overhead. The canonical
     permutation is computed once from the host bucket ids and applied
     with threaded native/numpy gathers. Also the per-host leg of a
-    multi-host decomposition (each host regrouping its local rows)."""
+    multi-host decomposition (each host regrouping its local rows).
+
+    No device leg: ``exchange`` is the gathers and has no ``h2d`` /
+    ``kernel`` / ``d2h`` children, nothing crosses a wire
+    (``wire_bytes`` = ``slot_bytes`` = 0) and there is no ``unpack``."""
     D = mesh.devices.size
     n = key_reps.shape[1]
-    t0 = _time.perf_counter()
-    bucket_ids = _host_bucket_ids(key_reps, num_buckets, seed)
-    n_local = -(-n // D) if n else 1
-    counts = _peer_counts(bucket_ids % D, None, n_local, D)
-    perm, shard_offsets = canonical_order(bucket_ids, num_buckets, D)
-    pack_s = _time.perf_counter() - t0
-    t0 = _time.perf_counter()
-    out_cols = _threaded_gather(payloads, perm)
-    out_bucket = bucket_ids[perm]
-    exchange_s = _time.perf_counter() - t0
-    _publish_stats(
-        STRATEGY_HOST,
-        D,
-        int(counts.max()) if counts.size else 0,
-        counts,
-        _timing(pack_s, exchange_s, 0.0),
-    )
+    acct: Dict = {}
+    with _timed(acct, "plan_s") as sp:
+        bucket_ids = _host_bucket_ids(key_reps, num_buckets, seed)
+        n_local = -(-n // D) if n else 1
+        counts = _peer_counts(bucket_ids % D, None, n_local, D)
+        cap = int(counts.max()) if counts.size else 0
+        _plan_attrs(sp, STRATEGY_HOST, D, cap, counts)
+    with _timed(acct, "pack_s") as sp:
+        perm, shard_offsets = canonical_order(bucket_ids, num_buckets, D)
+        sp.set("bytes", int(perm.nbytes))
+    with _timed(acct, "exchange_s"):
+        out_cols = _threaded_gather(payloads, perm)
+        out_bucket = bucket_ids[perm]
+    _publish_stats(STRATEGY_HOST, D, cap, counts, acct)
     return out_bucket, out_cols, shard_offsets
 
 
@@ -544,41 +664,47 @@ def _compact_exchange(mesh, key_reps, payloads, num_buckets, seed):
     ``D*D*cap`` slots per payload."""
     D = mesh.devices.size
     n = key_reps.shape[1]
-    t0 = _time.perf_counter()
-    bucket_ids = _host_bucket_ids(key_reps, num_buckets, seed)
-    owner = bucket_ids % D
-    n_local = -(-n // D) if n else 1
-    src = (np.arange(n, dtype=np.int64) // n_local).astype(np.int64)
-    counts = _peer_counts(owner, None, n_local, D)
-    cap = _shape_cap(counts.max())
-    slot = (src * D + owner).astype(np.int32)
-    rank = _pair_ranks(slot, D * D)
-    send_pos = slot.astype(np.int64) * cap + rank
-    recv_pos = (owner.astype(np.int64) * D + src) * cap + rank
-    sends = []
-    for p in payloads:
-        buf = np.zeros(D * D * cap, dtype=p.dtype)
-        buf[send_pos] = p
-        sends.append(buf.reshape(D * D, cap))
-    pack_s = _time.perf_counter() - t0
-    t0 = _time.perf_counter()
-    operands = tuple(put_sharded(mesh, s) for s in sends)
-    with mesh_dispatch_lock:
-        out = _compact_program(mesh, operands)
-    flats = [np.asarray(o).reshape(-1) for o in out]
-    exchange_s = _time.perf_counter() - t0
-    t0 = _time.perf_counter()
-    out_perm, shard_offsets = canonical_order(bucket_ids, num_buckets, D)
-    gather_idx = recv_pos[out_perm]
-    out_cols = _threaded_gather(flats, gather_idx)
-    out_bucket = bucket_ids[out_perm]
-    unpack_s = _time.perf_counter() - t0
+    acct: Dict = {}
+    with _timed(acct, "plan_s") as sp:
+        bucket_ids = _host_bucket_ids(key_reps, num_buckets, seed)
+        owner = bucket_ids % D
+        n_local = -(-n // D) if n else 1
+        src = (np.arange(n, dtype=np.int64) // n_local).astype(np.int64)
+        counts = _peer_counts(owner, None, n_local, D)
+        cap = _shape_cap(counts.max())
+        _plan_attrs(sp, STRATEGY_COMPACT, D, cap, counts)
+    with _timed(acct, "pack_s") as sp:
+        slot = (src * D + owner).astype(np.int32)
+        rank = _pair_ranks(slot, D * D)
+        send_pos = slot.astype(np.int64) * cap + rank
+        recv_pos = (owner.astype(np.int64) * D + src) * cap + rank
+        sends = []
+        for p in payloads:
+            buf = np.zeros(D * D * cap, dtype=p.dtype)
+            buf[send_pos] = p
+            sends.append(buf.reshape(D * D, cap))
+        sp.set("bytes", _nbytes(sends))
+    flats = _device_leg(
+        acct,
+        lambda: tuple(put_sharded(mesh, s) for s in sends),
+        lambda ops: _compact_program(mesh, ops),
+        lambda out: [np.asarray(o).reshape(-1) for o in out],
+    )
+    with _timed(acct, "unpack_s") as sp:
+        out_perm, shard_offsets = canonical_order(bucket_ids, num_buckets, D)
+        gather_idx = recv_pos[out_perm]
+        out_cols = _threaded_gather(flats, gather_idx)
+        out_bucket = bucket_ids[out_perm]
+        sp.set("bytes", _nbytes(out_cols))
+    row_bytes = sum(p.dtype.itemsize for p in payloads)
     _publish_stats(
         STRATEGY_COMPACT,
         D,
         cap,
         counts,
-        _timing(pack_s, exchange_s, unpack_s),
+        acct,
+        wire_bytes=_off_chip_rows(counts) * row_bytes,
+        slot_bytes=_nbytes(sends),
     )
     return out_bucket, out_cols, shard_offsets
 
@@ -667,97 +793,113 @@ def _twostage_exchange_mp(mesh, key_reps, payloads, num_buckets, seed):
     D = mesh.devices.size
     L = D // H
     n = key_reps.shape[1]
-    t0 = _time.perf_counter()
-    bucket_ids = _host_bucket_ids(key_reps, num_buckets, seed)
-    owner = bucket_ids % D
-    dst_h = owner // L
-    lane = owner % L
-    rnd = (dst_h - pid) % H
-    hl_local = np.bincount(dst_h * L + lane, minlength=H * L).reshape(H, L)
-    hl_all = np.asarray(mhu.process_allgather(hl_local))  # [H, H, L]
-    caps = tuple(
-        _shape_cap(hl_all[np.arange(H), (np.arange(H) + r) % H, :].max())
-        for r in range(H)
-    )
-    offs = np.concatenate([[0], np.cumsum(caps)]).astype(np.int64)
-    B = int(offs[-1])
-    rank = _pair_ranks(owner.astype(np.int32), D)
-    send_pos = lane * B + offs[rnd] + rank
-    sends = []
-    for p in [bucket_ids] + list(payloads):
-        buf = np.zeros(L * B, dtype=p.dtype)
-        buf[send_pos] = p
-        sends.append(buf.reshape(1, L, B))
-    pack_s = _time.perf_counter() - t0
-    t0 = _time.perf_counter()
+    acct: Dict = {}
+    with _timed(acct, "plan_s") as sp:
+        bucket_ids = _host_bucket_ids(key_reps, num_buckets, seed)
+        owner = bucket_ids % D
+        dst_h = owner // L
+        lane = owner % L
+        rnd = (dst_h - pid) % H
+        hl_local = np.bincount(
+            dst_h * L + lane, minlength=H * L
+        ).reshape(H, L)
+        hl_all = np.asarray(mhu.process_allgather(hl_local))  # [H, H, L]
+        caps = tuple(
+            _shape_cap(hl_all[np.arange(H), (np.arange(H) + r) % H, :].max())
+            for r in range(H)
+        )
+        offs = np.concatenate([[0], np.cumsum(caps)]).astype(np.int64)
+        B = int(offs[-1])
+        _plan_attrs(sp, STRATEGY_TWOSTAGE, D, int(max(caps)), hl_all[pid])
+    with _timed(acct, "pack_s") as sp:
+        rank = _pair_ranks(owner.astype(np.int32), D)
+        send_pos = lane * B + offs[rnd] + rank
+        sends = []
+        for p in [bucket_ids] + list(payloads):
+            buf = np.zeros(L * B, dtype=p.dtype)
+            buf[send_pos] = p
+            sends.append(buf.reshape(1, L, B))
+        sp.set("bytes", _nbytes(sends))
     hmesh = hierarchical_view(mesh, H)
-    operands = tuple(_process_local_operand(hmesh, s) for s in sends)
-    with mesh_dispatch_lock:
-        out = _twostage_program(hmesh, operands, caps)
-    local = []
-    for arr in out:
-        shards = sorted(arr.addressable_shards, key=lambda s: s.index)
-        local.append(
-            np.concatenate(
-                [np.asarray(s.data).reshape(-1) for s in shards]
-            ).reshape(L, B)
-        )
-    exchange_s = _time.perf_counter() - t0
-    t0 = _time.perf_counter()
-    recv_ids, recv_cols = local[0], local[1:]
-    # valid extents per (lane, round) from the global count matrix; round
-    # r of lane l carries hl_all[(pid - r) % H, pid, l] rows — reorder
-    # rounds by SOURCE HOST so concatenation follows global row order
-    out_bucket_parts: List[np.ndarray] = []
-    out_col_parts: List[List[np.ndarray]] = [[] for _ in recv_cols]
-    per_shard = np.zeros(D, dtype=np.int64)
-    for l in range(L):
-        ids_parts, col_parts = [], [[] for _ in recv_cols]
-        for src_h in range(H):
-            r = (pid - src_h) % H
-            cnt = int(hl_all[src_h, pid, l])
-            lo = int(offs[r])
-            ids_parts.append(recv_ids[l, lo : lo + cnt])
-            for i, c in enumerate(recv_cols):
-                col_parts[i].append(c[l, lo : lo + cnt])
-        ids_l = np.concatenate(ids_parts)
-        order = np.argsort(ids_l, kind="stable")
-        out_bucket_parts.append(ids_l[order])
-        for i in range(len(recv_cols)):
-            out_col_parts[i].append(np.concatenate(col_parts[i])[order])
-        per_shard[pid * L + l] = len(ids_l)
-    out_bucket = (
-        np.concatenate(out_bucket_parts)
-        if out_bucket_parts
-        else np.zeros(0, dtype=np.int32)
+
+    def fetch(out):
+        local = []
+        for arr in out:
+            shards = sorted(arr.addressable_shards, key=lambda s: s.index)
+            local.append(
+                np.concatenate(
+                    [np.asarray(s.data).reshape(-1) for s in shards]
+                ).reshape(L, B)
+            )
+        return local
+
+    local = _device_leg(
+        acct,
+        lambda: tuple(_process_local_operand(hmesh, s) for s in sends),
+        lambda ops: _twostage_program(hmesh, ops, caps),
+        fetch,
     )
-    out_cols = [
-        np.concatenate(parts)
-        if parts
-        else np.zeros(0, dtype=c.dtype)
-        for parts, c in zip(out_col_parts, recv_cols)
-    ]
-    shard_offsets = np.concatenate(
-        [np.zeros(1, dtype=np.int64), np.cumsum(per_shard)]
-    )
-    expect = int(hl_all[:, pid, :].sum())
-    if len(out_bucket) != expect:
-        raise RuntimeError(
-            f"multi-host bucket shuffle lost rows on process {pid}: "
-            f"expected {expect}, received {len(out_bucket)}"
+    with _timed(acct, "unpack_s") as sp:
+        recv_ids, recv_cols = local[0], local[1:]
+        # valid extents per (lane, round) from the global count matrix;
+        # round r of lane l carries hl_all[(pid - r) % H, pid, l] rows —
+        # reorder rounds by SOURCE HOST so concatenation follows global
+        # row order
+        out_bucket_parts: List[np.ndarray] = []
+        out_col_parts: List[List[np.ndarray]] = [[] for _ in recv_cols]
+        per_shard = np.zeros(D, dtype=np.int64)
+        for l in range(L):
+            ids_parts, col_parts = [], [[] for _ in recv_cols]
+            for src_h in range(H):
+                r = (pid - src_h) % H
+                cnt = int(hl_all[src_h, pid, l])
+                lo = int(offs[r])
+                ids_parts.append(recv_ids[l, lo : lo + cnt])
+                for i, c in enumerate(recv_cols):
+                    col_parts[i].append(c[l, lo : lo + cnt])
+            ids_l = np.concatenate(ids_parts)
+            order = np.argsort(ids_l, kind="stable")
+            out_bucket_parts.append(ids_l[order])
+            for i in range(len(recv_cols)):
+                out_col_parts[i].append(np.concatenate(col_parts[i])[order])
+            per_shard[pid * L + l] = len(ids_l)
+        out_bucket = (
+            np.concatenate(out_bucket_parts)
+            if out_bucket_parts
+            else np.zeros(0, dtype=np.int32)
         )
-    unpack_s = _time.perf_counter() - t0
+        out_cols = [
+            np.concatenate(parts)
+            if parts
+            else np.zeros(0, dtype=c.dtype)
+            for parts, c in zip(out_col_parts, recv_cols)
+        ]
+        shard_offsets = np.concatenate(
+            [np.zeros(1, dtype=np.int64), np.cumsum(per_shard)]
+        )
+        expect = int(hl_all[:, pid, :].sum())
+        if len(out_bucket) != expect:
+            raise RuntimeError(
+                f"multi-host bucket shuffle lost rows on process {pid}: "
+                f"expected {expect}, received {len(out_bucket)}"
+            )
+        sp.set("bytes", _nbytes(out_cols))
+    # bucket ids ride as one more int32 payload; rows bound for this
+    # process's own lanes never leave the host
+    row_bytes = 4 + sum(p.dtype.itemsize for p in payloads)
     _publish_stats(
         STRATEGY_TWOSTAGE,
         D,
         int(max(caps)),
         hl_all[pid],  # this process's per-(peer host, lane) send counts
-        {
+        acct,
+        wire_bytes=int(n - hl_local[pid].sum()) * row_bytes,
+        slot_bytes=_nbytes(sends),
+        extra={
             "hosts": float(H),
             "process_local": 1.0,
             "round_cap_max": float(max(caps)),
             "round_cap_min": float(min(caps)),
-            **_timing(pack_s, exchange_s, unpack_s),
         },
     )
     return out_bucket, out_cols, shard_offsets
@@ -791,65 +933,75 @@ def _twostage_exchange(mesh, key_reps, payloads, num_buckets, seed, hosts):
         H -= 1
     L = D // H
     n = key_reps.shape[1]
-    t0 = _time.perf_counter()
-    bucket_ids = _host_bucket_ids(key_reps, num_buckets, seed)
-    owner = bucket_ids % D
-    n_local = -(-n // D) if n else 1
-    counts = _peer_counts(owner, None, n_local, D)
-    src_dev = (np.arange(n, dtype=np.int64) // n_local).astype(np.int64)
-    src_h = src_dev // L
-    dst_h = owner // L
-    lane = owner % L
-    rnd = (dst_h - src_h) % H
-    # per-round slot caps from the count matrix, uniform over (host,
-    # lane) senders of that round (SPMD shapes must agree) but NOT over
-    # rounds — the skew-aware sizing
-    hl_counts = np.bincount(
-        (src_h * H + dst_h) * L + lane, minlength=H * H * L
-    ).reshape(H, H, L)
-    caps = tuple(
-        _shape_cap(hl_counts[np.arange(H), (np.arange(H) + r) % H, :].max())
-        for r in range(H)
-    )
-    offs = np.concatenate([[0], np.cumsum(caps)]).astype(np.int64)
-    B = int(offs[-1])
-    slot = ((src_h * H + dst_h) * L + lane).astype(np.int32)
-    rank = _pair_ranks(slot, H * H * L)
-    # sender of a row is device (src_h, lane): the host already moved it
-    # to its destination lane's buffer (the RAM ici leg)
-    send_pos = (src_h * L + lane) * B + offs[rnd] + rank
-    recv_pos = (dst_h * L + lane) * B + offs[rnd] + rank
-    sends = []
-    for p in payloads:
-        buf = np.zeros(D * B, dtype=p.dtype)
-        buf[send_pos] = p
-        sends.append(buf.reshape(H, L, B))
-    pack_s = _time.perf_counter() - t0
-    t0 = _time.perf_counter()
+    acct: Dict = {}
+    with _timed(acct, "plan_s") as sp:
+        bucket_ids = _host_bucket_ids(key_reps, num_buckets, seed)
+        owner = bucket_ids % D
+        n_local = -(-n // D) if n else 1
+        counts = _peer_counts(owner, None, n_local, D)
+        src_dev = (np.arange(n, dtype=np.int64) // n_local).astype(np.int64)
+        src_h = src_dev // L
+        dst_h = owner // L
+        lane = owner % L
+        rnd = (dst_h - src_h) % H
+        # per-round slot caps from the count matrix, uniform over (host,
+        # lane) senders of that round (SPMD shapes must agree) but NOT
+        # over rounds — the skew-aware sizing
+        hl_counts = np.bincount(
+            (src_h * H + dst_h) * L + lane, minlength=H * H * L
+        ).reshape(H, H, L)
+        caps = tuple(
+            _shape_cap(
+                hl_counts[np.arange(H), (np.arange(H) + r) % H, :].max()
+            )
+            for r in range(H)
+        )
+        offs = np.concatenate([[0], np.cumsum(caps)]).astype(np.int64)
+        B = int(offs[-1])
+        _plan_attrs(sp, STRATEGY_TWOSTAGE, D, int(max(caps)), counts)
+    with _timed(acct, "pack_s") as sp:
+        slot = ((src_h * H + dst_h) * L + lane).astype(np.int32)
+        rank = _pair_ranks(slot, H * H * L)
+        # sender of a row is device (src_h, lane): the host already moved
+        # it to its destination lane's buffer (the RAM ici leg)
+        send_pos = (src_h * L + lane) * B + offs[rnd] + rank
+        recv_pos = (dst_h * L + lane) * B + offs[rnd] + rank
+        sends = []
+        for p in payloads:
+            buf = np.zeros(D * B, dtype=p.dtype)
+            buf[send_pos] = p
+            sends.append(buf.reshape(H, L, B))
+        sp.set("bytes", _nbytes(sends))
     hmesh = hierarchical_view(mesh, H)
-    operands = tuple(
-        put_sharded(hmesh, s, P(DCN_AXIS, ICI_AXIS)) for s in sends
+    flats = _device_leg(
+        acct,
+        lambda: tuple(
+            put_sharded(hmesh, s, P(DCN_AXIS, ICI_AXIS)) for s in sends
+        ),
+        lambda ops: _twostage_program(hmesh, ops, caps),
+        lambda out: [np.asarray(o).reshape(-1) for o in out],
     )
-    with mesh_dispatch_lock:
-        out = _twostage_program(hmesh, operands, caps)
-    flats = [np.asarray(o).reshape(-1) for o in out]
-    exchange_s = _time.perf_counter() - t0
-    t0 = _time.perf_counter()
-    out_perm, shard_offsets = canonical_order(bucket_ids, num_buckets, D)
-    gather_idx = recv_pos[out_perm]
-    out_cols = _threaded_gather(flats, gather_idx)
-    out_bucket = bucket_ids[out_perm]
-    unpack_s = _time.perf_counter() - t0
+    with _timed(acct, "unpack_s") as sp:
+        out_perm, shard_offsets = canonical_order(bucket_ids, num_buckets, D)
+        gather_idx = recv_pos[out_perm]
+        out_cols = _threaded_gather(flats, gather_idx)
+        out_bucket = bucket_ids[out_perm]
+        sp.set("bytes", _nbytes(out_cols))
+    row_bytes = sum(p.dtype.itemsize for p in payloads)
     _publish_stats(
         STRATEGY_TWOSTAGE,
         D,
         int(max(caps)),
         counts,
-        {
+        acct,
+        # rows that left their source chip: between the chips of one
+        # simulated host they ride its RAM, the rest the dcn rounds
+        wire_bytes=_off_chip_rows(counts) * row_bytes,
+        slot_bytes=_nbytes(sends),
+        extra={
             "hosts": float(H),
             "round_cap_max": float(max(caps)),
             "round_cap_min": float(min(caps)),
-            **_timing(pack_s, exchange_s, unpack_s),
         },
     )
     return out_bucket, out_cols, shard_offsets

@@ -85,7 +85,7 @@ def timed_build(devices, rows, data_dir, num_buckets, strategy="auto"):
             "exchange_strategy": telem.get("shuffle_strategy", ""),
             "exchange_stage_seconds": {
                 stage: telem.get(f"shuffle_{stage}_s", 0.0)
-                for stage in ("pack", "exchange", "unpack")
+                for stage in ("plan", "pack", "exchange", "unpack")
             },
             "build_warm_s": round(warm, 3),
             "build_rows_per_sec": round(rows / warm),
