@@ -310,13 +310,13 @@ def _native_gather_min_rows() -> int:
     )
 
 
-def _gather(values: np.ndarray, idx) -> np.ndarray:
-    """``values[idx]`` with the native threaded gather for large
-    contiguous 8-byte-element arrays; numpy everywhere else. Bit-exact
-    either way: the kernel bounds-checks and returns None on any index
-    outside [0, n) (negative wrapping, IndexError), so numpy's exact
-    semantics are preserved by fallback, never emulated."""
-    if (
+def _gather_native(values: np.ndarray, idx) -> Optional[np.ndarray]:
+    """The native leg of :func:`_gather` alone: the threaded gather for
+    large contiguous 8-byte-element arrays, or None where it does not
+    apply (another width, a small or non-int64 index, the kernel
+    unavailable, an index outside [0, n)) — for callers that record
+    which leg moved their rows (the compact exchange's pack)."""
+    if not (
         isinstance(idx, np.ndarray)
         and idx.dtype == np.int64
         and values.ndim == 1
@@ -325,17 +325,23 @@ def _gather(values: np.ndarray, idx) -> np.ndarray:
         and values.flags.c_contiguous
         and len(idx) >= _native_gather_min_rows()
     ):
-        from hyperspace_tpu import native
+        return None
+    from hyperspace_tpu import native
 
-        if values.dtype == np.float64:
-            out = native.gather_f64(values, idx)
-        else:
-            out = native.gather_i64(values.view(np.int64), idx)
-            if out is not None:
-                out = out.view(values.dtype)
-        if out is not None:
-            return out
-    return values[idx]
+    if values.dtype == np.float64:
+        return native.gather_f64(values, idx)
+    out = native.gather_i64(values.view(np.int64), idx)
+    return None if out is None else out.view(values.dtype)
+
+
+def _gather(values: np.ndarray, idx) -> np.ndarray:
+    """``values[idx]`` with the native threaded gather for large
+    contiguous 8-byte-element arrays; numpy everywhere else. Bit-exact
+    either way: the kernel bounds-checks and returns None on any index
+    outside [0, n) (negative wrapping, IndexError), so numpy's exact
+    semantics are preserved by fallback, never emulated."""
+    out = _gather_native(values, idx)
+    return values[idx] if out is None else out
 
 
 def column_value_range(col: "Column"):

@@ -255,7 +255,9 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
         "strategy (flat, host, compact, both twostage legs): each "
         "span's seconds are also the exchange's account, so the host "
         "packing that outweighs the device leg (PERF.md) is readable "
-        "apart from it; host has no device leg and no unpack",
+        "apart from it; host has no device leg and no unpack; compact "
+        "counts gathers_native/gathers_numpy on pack (payloads by the "
+        "gather that moved them) and runs on unpack",
     ),
     "hyperspace_tpu.ops.hash.bucket_ids_np": (
         "span",

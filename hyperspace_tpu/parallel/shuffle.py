@@ -17,13 +17,24 @@ per-machine/topology resolution, see :func:`resolve_strategy`):
     is differential-tested against, and the default on a single-host
     accelerator mesh.
 ``compact``
-    Host-packed variable-length exchange: the host bucket ids computed
-    for capacity planning drive an exact-extent pack on the host (slot
-    per (source, peer) pair, cap = exact max count — no power-of-two
-    blowup), the device program is ONE ``all_to_all`` per payload with
-    no on-device hashing, scatter or argsort, and the host unpacks via
-    the closed-form receive position of every row. Moves only the
-    payload bytes (no bucket/validity planes).
+    Host-packed variable-length exchange, its host side driven by ONE
+    counting partition an exchange, keyed ``(source shard, owner,
+    bucket)``: the host bucket ids (hashed once, natively) become the
+    int32 key ``source * num_buckets + rank of the bucket in (owner,
+    bucket) order``, and the plan, the pack and the unpack are all read
+    off that partition's ``order`` and ``offsets``. The plan's
+    ``[D, D]`` peer counts are sums of its run lengths (cap = max count
+    at three significant bits — no power-of-two blowup); the pack is
+    one gather a payload by ``order`` (which lists every (source,
+    owner) slot's rows contiguously, grouped by bucket, original order
+    inside a bucket) and ``D*D`` contiguous copies into the zeroed
+    ``[D*D, cap]`` send buffer; the device program is ONE
+    ``all_to_all`` per payload with no on-device hashing, scatter or
+    argsort; the unpack copies at most ``D * num_buckets`` contiguous
+    runs a payload from the received slots straight into canonical
+    order — no ranks, no position arrays, no scatter and no random
+    gather of received rows. Moves only the payload bytes (no
+    bucket/validity planes).
 ``host``
     No device round-trip at all: rows are reordered in host RAM with the
     canonical post-exchange permutation (threaded native/numpy gathers).
@@ -70,7 +81,7 @@ import contextlib
 import functools
 import logging
 import time as _time
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -322,6 +333,16 @@ def _publish_stats(
         )
 
 
+def _owner_ranked(num_buckets: int, D: int) -> Tuple[np.ndarray, np.ndarray]:
+    """``(ranked, rank_of)``: the bucket ids in ``(owner = bucket % D,
+    bucket)`` order, and each bucket's rank in that order (both int32)."""
+    b = np.arange(num_buckets, dtype=np.int64)
+    ranked = np.lexsort((b, b % D)).astype(np.int32)
+    rank_of = np.empty(num_buckets, dtype=np.int32)
+    rank_of[ranked] = np.arange(num_buckets, dtype=np.int32)
+    return ranked, rank_of
+
+
 def canonical_order(
     bucket_ids: np.ndarray, num_buckets: int, D: int
 ) -> Tuple[np.ndarray, np.ndarray]:
@@ -336,10 +357,7 @@ def canonical_order(
     row index. Computed as a counting scatter over owner-major-remapped
     bucket ids (native ``hs_partition_by_bucket`` above its dispatch
     threshold), O(n)."""
-    b = np.arange(num_buckets, dtype=np.int64)
-    owner_rank = np.lexsort((b, b % D))  # buckets in (owner, bucket) order
-    remap = np.empty(num_buckets, dtype=np.int32)
-    remap[owner_rank] = np.arange(num_buckets, dtype=np.int32)
+    owner_rank, remap = _owner_ranked(num_buckets, D)
     order, offsets = partition_by_bucket(remap[bucket_ids], num_buckets)
     per_bucket = np.diff(offsets)
     per_owner = np.bincount(
@@ -380,6 +398,23 @@ def _pair_ranks(slot_ids: np.ndarray, num_slots: int) -> np.ndarray:
     return rank
 
 
+def _per_payload(fn: Callable, arrays: Sequence[np.ndarray], n_rows: int) -> List:
+    """``[fn(a) for a in arrays]``, one pool thread a payload above 64k
+    rows: the native gathers and numpy's large copies release the GIL,
+    so the payloads of an exchange move side by side."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from hyperspace_tpu import native
+
+    workers = min(len(arrays), max(1, min(native._cores(), 8)))
+    if workers <= 1 or n_rows < (1 << 16):
+        return [fn(a) for a in arrays]
+    with ThreadPoolExecutor(
+        max_workers=workers, thread_name_prefix="hs-exchange"
+    ) as pool:
+        return list(pool.map(fn, arrays))
+
+
 def _threaded_gather(
     arrays: Sequence[np.ndarray], idx: np.ndarray
 ) -> List[np.ndarray]:
@@ -387,18 +422,9 @@ def _threaded_gather(
     dtypes ride the threaded native gather (``hs_gather_*``, releases
     the GIL), the rest plain numpy. The "threaded numpy slicing" leg of
     the host-side exchange."""
-    from concurrent.futures import ThreadPoolExecutor
-
-    from hyperspace_tpu import native
     from hyperspace_tpu.io.columnar import _gather
 
-    workers = min(len(arrays), max(1, min(native._cores(), 8)))
-    if workers <= 1 or len(idx) < (1 << 16):
-        return [_gather(a, idx) for a in arrays]
-    with ThreadPoolExecutor(
-        max_workers=workers, thread_name_prefix="hs-exchange"
-    ) as pool:
-        return list(pool.map(lambda a: _gather(a, idx), arrays))
+    return _per_payload(lambda a: _gather(a, idx), arrays, len(idx))
 
 
 # ---------------------------------------------------------------------------
@@ -638,7 +664,7 @@ def _host_exchange(mesh, key_reps, payloads, num_buckets, seed):
 def _compact_program(mesh, payloads):
     """ONE tiled all_to_all per payload — no on-device hashing, scatter
     or argsort; the host packed exact (source, peer) extents and unpacks
-    by closed-form receive positions."""
+    by contiguous run copies."""
 
     def local(cols):
         return tuple(
@@ -650,40 +676,149 @@ def _compact_program(mesh, payloads):
     )(payloads)
 
 
+class _CompactPlan(NamedTuple):
+    """What ONE counting partition of the rows by ``(source shard,
+    owner, bucket)`` says — everything the compact exchange's plan, pack
+    and unpack need. ``R = num_buckets`` below; a bucket's *rank* is its
+    place in ``(owner = bucket % D, bucket)`` order, so owner ``o``'s
+    buckets are the ranks ``owner_lo[o]:owner_lo[o + 1]``."""
+
+    order: np.ndarray  # [n] int64: rows by (source, rank), stable
+    starts: np.ndarray  # [D, R + 1] int64: starts[s, r] = where source
+    # s's rows of rank r begin in ``order``; starts[s, R] = its end
+    ranked: np.ndarray  # [R] int32: bucket ids in rank order
+    owner_lo: np.ndarray  # [D + 1] int64: first rank of each owner
+    counts: np.ndarray  # [D, D] int64: rows source s sends owner o
+    cap: int  # slot capacity (``_shape_cap`` of the max count)
+
+
+def _compact_plan(
+    key_reps: np.ndarray, num_buckets: int, seed: int, D: int
+) -> _CompactPlan:
+    """Hash once, partition once. The key of a row is ``source *
+    num_buckets + rank(bucket)``: source-major, then owner-major, then
+    bucket — so the stable partition's ``order`` lists slot ``(source,
+    owner)``'s rows contiguously, grouped by ascending bucket, in
+    original row order inside a bucket, and its ``offsets`` are the run
+    table: the peer counts, the cap, the skew and the shard extents
+    follow from ``D * num_buckets`` run lengths with no further pass
+    over the rows."""
+    n = key_reps.shape[1]
+    R = int(num_buckets)
+    bucket_ids = bucket_ids_host(key_reps, R, seed)
+    ranked, rank_of = _owner_ranked(R, D)
+    key = rank_of[bucket_ids]
+    n_local = -(-n // D) if n else 1
+    for s in range(1, D):  # the n_local-row source blocks, in place
+        key[s * n_local : (s + 1) * n_local] += s * R
+    order, offsets = partition_by_bucket(key, D * R)
+    starts = np.empty((D, R + 1), dtype=np.int64)
+    starts[:, :R] = offsets[:-1].reshape(D, R)
+    starts[:, R] = offsets[R::R]
+    owner_lo = np.searchsorted(ranked % D, np.arange(D + 1)).astype(np.int64)
+    counts = np.diff(starts[:, owner_lo], axis=1)
+    return _CompactPlan(
+        order, starts, ranked, owner_lo, counts, _shape_cap(counts.max())
+    )
+
+
+def _compact_pack(
+    plan: _CompactPlan, payloads: Sequence[np.ndarray]
+) -> Tuple[List[np.ndarray], int]:
+    """The ``[D*D, cap]`` send buffer of every payload — slot ``s*D +
+    o`` holds the rows source ``s`` sends owner ``o``, zeros behind them
+    — and how many payloads took the threaded native gather (8-byte
+    dtypes; the rest fall to numpy ``take``). A payload is gathered once
+    by ``plan.order`` and lands by ``D*D`` contiguous copies: no ranks,
+    no position array, no scatter."""
+    from hyperspace_tpu.io.columnar import _gather_native
+
+    D = plan.counts.shape[0]
+    slot_lo = plan.starts[:, plan.owner_lo[:-1]]
+
+    def pack(p: np.ndarray) -> Tuple[np.ndarray, bool]:
+        rows = _gather_native(p, plan.order)
+        native_gather = rows is not None
+        if rows is None:
+            rows = np.take(p, plan.order)
+        buf = np.zeros((D * D, plan.cap), dtype=p.dtype)
+        for s in range(D):
+            for o in range(D):
+                cnt = plan.counts[s, o]
+                lo = slot_lo[s, o]
+                buf[s * D + o, :cnt] = rows[lo : lo + cnt]
+        return buf, native_gather
+
+    packed = _per_payload(pack, payloads, len(plan.order))
+    return [buf for buf, _ in packed], sum(nat for _, nat in packed)
+
+
+def _compact_unpack(
+    plan: _CompactPlan, flats: Sequence[np.ndarray]
+) -> Tuple[np.ndarray, List[np.ndarray], np.ndarray, int]:
+    """Received slots -> canonical order by contiguous run copies:
+    ``(bucket ids, payload columns, [D+1] shard extents, runs)``.
+
+    The rows of the bucket of rank ``r`` (owner ``o``) that source ``s``
+    sent sit contiguously in the received ``[D*D*cap]`` buffer at
+    ``(o*D + s)*cap + (rows of s's lower-ranked buckets owned by o)``;
+    canonical order is, for each rank ascending (owner-major, bucket
+    ascending), for each source ascending, that run — within a bucket
+    source-major with original order inside a source, i.e. ascending
+    original row index. No permutation, no index array, no gather."""
+    D = plan.counts.shape[0]
+    R = len(plan.ranked)
+    owner_of_rank = plan.ranked.astype(np.int64) % D
+    src = np.arange(D, dtype=np.int64)[:, None]
+    within_slot = plan.starts[:, :R] - plan.starts[src, plan.owner_lo[owner_of_rank]]
+    lo = ((owner_of_rank * D + src) * plan.cap + within_slot).T.ravel()
+    run_len = np.diff(plan.starts, axis=1)
+    hi = lo + run_len.T.ravel()
+    runs = [(a, b) for a, b in zip(lo.tolist(), hi.tolist()) if b > a]
+
+    def unpack(flat: np.ndarray) -> np.ndarray:
+        if not runs:
+            return np.zeros(0, dtype=flat.dtype)
+        return np.concatenate([flat[a:b] for a, b in runs])
+
+    out_cols = _per_payload(unpack, flats, len(plan.order))
+    out_bucket = np.repeat(plan.ranked, run_len.sum(axis=0))
+    shard_offsets = np.concatenate(
+        [np.zeros(1, dtype=np.int64), np.cumsum(plan.counts.sum(axis=0))]
+    )
+    return out_bucket, out_cols, shard_offsets, len(runs)
+
+
 def _compact_exchange(mesh, key_reps, payloads, num_buckets, seed):
     """Strategy ``compact`` — host-packed exact-extent device exchange.
 
-    The host bucket ids drive a counting-scatter pack into ``[D*D,
-    cap]`` send buffers (slot per (source, peer) pair, cap = the exact
-    max count — not power-of-two padded), each payload rides one
-    ``all_to_all``, and the unpack gathers each row from its closed-form
-    receive position ``(owner*D + source)*cap + rank`` straight into
-    canonical order. Compared to ``flat`` this drops the second hash
+    ONE counting partition of the rows by ``(source shard, owner,
+    bucket)`` (:func:`_compact_plan`) drives the whole host side:
+    ``exchange_plan`` is the native one-pass hash, the key remap and
+    that partition, whose run lengths give the peer counts and the cap
+    (the exact max count at three significant bits — not power-of-two
+    padded); ``pack`` is one gather a payload by the partition's order
+    plus ``D*D`` contiguous copies into ``[D*D, cap]`` send buffers
+    (attrs ``gathers_native`` / ``gathers_numpy``: payloads that took
+    the threaded native 8-byte gather against those that fell to numpy
+    ``take``); each payload rides one ``all_to_all``; ``unpack`` copies
+    at most ``D * num_buckets`` contiguous runs a payload (attr
+    ``runs``) from the received slots straight into canonical order.
+    Rows travel grouped by bucket inside a slot; the ``all_to_all`` does
+    not look inside one. Compared to ``flat`` this drops the second hash
     pass, both device argsorts, the bucket/validity planes from the
     wire, and the pow2 cap blowup; the exchanged bytes are exactly
     ``D*D*cap`` slots per payload."""
     D = mesh.devices.size
-    n = key_reps.shape[1]
     acct: Dict = {}
     with _timed(acct, "plan_s") as sp:
-        bucket_ids = _host_bucket_ids(key_reps, num_buckets, seed)
-        owner = bucket_ids % D
-        n_local = -(-n // D) if n else 1
-        src = (np.arange(n, dtype=np.int64) // n_local).astype(np.int64)
-        counts = _peer_counts(owner, None, n_local, D)
-        cap = _shape_cap(counts.max())
-        _plan_attrs(sp, STRATEGY_COMPACT, D, cap, counts)
+        plan = _compact_plan(key_reps, num_buckets, seed, D)
+        _plan_attrs(sp, STRATEGY_COMPACT, D, plan.cap, plan.counts)
     with _timed(acct, "pack_s") as sp:
-        slot = (src * D + owner).astype(np.int32)
-        rank = _pair_ranks(slot, D * D)
-        send_pos = slot.astype(np.int64) * cap + rank
-        recv_pos = (owner.astype(np.int64) * D + src) * cap + rank
-        sends = []
-        for p in payloads:
-            buf = np.zeros(D * D * cap, dtype=p.dtype)
-            buf[send_pos] = p
-            sends.append(buf.reshape(D * D, cap))
+        sends, gathers_native = _compact_pack(plan, payloads)
         sp.set("bytes", _nbytes(sends))
+        sp.set("gathers_native", gathers_native)
+        sp.set("gathers_numpy", len(sends) - gathers_native)
     flats = _device_leg(
         acct,
         lambda: tuple(put_sharded(mesh, s) for s in sends),
@@ -691,19 +826,17 @@ def _compact_exchange(mesh, key_reps, payloads, num_buckets, seed):
         lambda out: [np.asarray(o).reshape(-1) for o in out],
     )
     with _timed(acct, "unpack_s") as sp:
-        out_perm, shard_offsets = canonical_order(bucket_ids, num_buckets, D)
-        gather_idx = recv_pos[out_perm]
-        out_cols = _threaded_gather(flats, gather_idx)
-        out_bucket = bucket_ids[out_perm]
+        out_bucket, out_cols, shard_offsets, runs = _compact_unpack(plan, flats)
         sp.set("bytes", _nbytes(out_cols))
+        sp.set("runs", runs)
     row_bytes = sum(p.dtype.itemsize for p in payloads)
     _publish_stats(
         STRATEGY_COMPACT,
         D,
-        cap,
-        counts,
+        plan.cap,
+        plan.counts,
         acct,
-        wire_bytes=_off_chip_rows(counts) * row_bytes,
+        wire_bytes=_off_chip_rows(plan.counts) * row_bytes,
         slot_bytes=_nbytes(sends),
     )
     return out_bucket, out_cols, shard_offsets

@@ -201,6 +201,162 @@ class TestStrategyDifferential:
 
 
 # ---------------------------------------------------------------------------
+# compact: one (source, owner, bucket) counting partition drives the host
+# ---------------------------------------------------------------------------
+
+_LAYOUT_BUCKETS = 12
+
+
+def _layout_keys(shape, D, rng):
+    """[1, n] key reps for one pack-layout case (12 buckets)."""
+    from hyperspace_tpu.ops.hash import bucket_ids_host
+
+    if shape == "hot":  # every row in ONE bucket
+        return np.full((1, 2048), 7, dtype=np.int64)
+    if shape == "tiny":  # n < D * buckets: most runs are empty
+        return rng.integers(0, 10**6, (1, D * _LAYOUT_BUCKETS - 5)).astype(np.int64)
+    n = 2003 if shape == "ragged" else 2048  # 2003: no multiple of D
+    keys = rng.integers(0, 10**6, (1, 4 * n)).astype(np.int64)
+    if shape == "empty_owner":  # owner 1 receives no row
+        ids = bucket_ids_host(keys, _LAYOUT_BUCKETS, 42)
+        keys = keys[:, ids % D != 1]
+    return np.ascontiguousarray(keys[:, :n])
+
+
+class TestCompactOnePartition:
+    @pytest.mark.parametrize("D", [2, 4, 8])
+    @pytest.mark.parametrize(
+        "shape", ["uniform", "hot", "empty_owner", "ragged", "tiny"]
+    )
+    def test_pack_layout(self, D, shape, monkeypatch):
+        """Every send slot holds exactly the rows of its (source, owner)
+        pair, grouped by ascending bucket, in original order inside a
+        bucket, zeros behind — read off the buffers the forced
+        ``compact`` exchange hands to the devices."""
+        from hyperspace_tpu.ops.hash import bucket_ids_host
+
+        rng = np.random.default_rng(D * 7 + len(shape))
+        keys = _layout_keys(shape, D, rng)
+        n, nb = keys.shape[1], _LAYOUT_BUCKETS
+        row_id = np.arange(1, n + 1, dtype=np.int64)  # never 0: padding is
+        codes = (row_id % 251 + 1).astype(np.int32)
+        sent = []
+        put = sh.put_sharded
+        monkeypatch.setattr(
+            sh, "put_sharded", lambda m, a, *r: sent.append(a) or put(m, a, *r)
+        )
+        got = sh.bucket_shuffle(
+            _mesh(D), keys, [row_id, codes], nb, with_shard_offsets=True,
+            strategy=sh.STRATEGY_COMPACT,
+        )
+        assert len(sent) == 2
+        ids = bucket_ids_host(keys, nb, 42)
+        n_local = -(-n // D)
+        src = np.arange(n) // n_local
+        owner = ids % D
+        if shape == "empty_owner":
+            assert not (owner == 1).any()
+        cap = sent[0].shape[1]
+        most = max(
+            int(((src == s) & (owner == o)).sum())
+            for s in range(D) for o in range(D)
+        )
+        assert cap == sh._shape_cap(most)
+        for buf, payload in zip(sent, (row_id, codes)):
+            assert buf.shape == (D * D, cap) and buf.dtype == payload.dtype
+            for s in range(D):
+                for o in range(D):
+                    rows = np.nonzero((src == s) & (owner == o))[0]
+                    rows = rows[np.argsort(ids[rows], kind="stable")]
+                    slot = buf[s * D + o]
+                    np.testing.assert_array_equal(slot[: len(rows)], payload[rows])
+                    assert not slot[len(rows):].any()
+        ref = sh.bucket_shuffle(
+            _mesh(D), keys, [row_id, codes], nb, with_shard_offsets=True,
+            strategy=sh.STRATEGY_HOST,
+        )
+        np.testing.assert_array_equal(got[0], ref[0])
+        np.testing.assert_array_equal(got[2], ref[2])
+        for a, b in zip(got[1], ref[1]):
+            np.testing.assert_array_equal(a, b)
+
+    @pytest.mark.parametrize("D", [2, 4, 8])
+    @pytest.mark.parametrize("gather", ["native", "numpy"])
+    def test_mixed_widths_equal_flat_and_host(self, D, gather, monkeypatch):
+        """int64, float64 (crossing as int64), int32 codes and uint8
+        validity under two key columns: ``compact`` equals ``flat`` and
+        ``host`` element for element whichever gather packs it, and the
+        account says which did."""
+        from hyperspace_tpu import native
+        from hyperspace_tpu.io import columnar
+
+        monkeypatch.setattr(
+            columnar, "_NATIVE_GATHER_MIN_ROWS", 1 if gather == "native" else 1 << 40
+        )
+        rng = np.random.default_rng(D + len(gather))
+        n, nb = 3001, 16
+        keys = rng.integers(0, 97, (2, n)).astype(np.int64)
+        f = rng.normal(size=n)
+        f[::7] = np.nan
+        payloads = [
+            rng.integers(-(2**60), 2**60, n).astype(np.int64),
+            f,
+            rng.integers(0, 3, n).astype(np.int32),
+            rng.integers(0, 2, n).astype(np.uint8),
+        ]
+        mesh = _mesh(D)
+        plan = sh._compact_plan(keys, nb, 42, D)
+        _, gathers_native = sh._compact_pack(
+            plan, [p.view("i8") if p.dtype.kind == "f" else p for p in payloads]
+        )
+        lib = native.load(wait=True)
+        assert gathers_native == (2 if gather == "native" and lib else 0)
+        got = sh.bucket_shuffle(
+            mesh, keys, payloads, nb, with_shard_offsets=True,
+            strategy=sh.STRATEGY_COMPACT,
+        )
+        for other in (sh.STRATEGY_FLAT, sh.STRATEGY_HOST):
+            ref = sh.bucket_shuffle(
+                mesh, keys, payloads, nb, with_shard_offsets=True, strategy=other
+            )
+            np.testing.assert_array_equal(got[0], ref[0], err_msg=other)
+            np.testing.assert_array_equal(got[2], ref[2], err_msg=other)
+            for a, b in zip(got[1], ref[1]):
+                assert a.dtype == b.dtype, other
+                np.testing.assert_array_equal(
+                    a.view(f"u{a.dtype.itemsize}"), b.view(f"u{b.dtype.itemsize}"),
+                    err_msg=other,
+                )
+
+    @pytest.mark.parametrize("D", [2, 4, 8])
+    def test_span_attrs(self, D):
+        """``pack`` counts its gathers, ``unpack`` its runs, and the
+        three host spans stay children of ``hash_shuffle``."""
+        from hyperspace_tpu.obs import trace
+
+        rng = np.random.default_rng(D)
+        n, nb = 3001, 16
+        keys = rng.integers(0, 97, (1, n)).astype(np.int64)
+        payloads = [keys[0], rng.integers(0, 3, n).astype(np.int32),
+                    rng.integers(0, 2, n).astype(bool)]
+        root = trace.root("action.Test", always=True)
+        with trace.activate(root):
+            with trace.span("hash_shuffle") as parent:
+                sh.bucket_shuffle(
+                    _mesh(D), keys, payloads, nb, strategy=sh.STRATEGY_COMPACT
+                )
+        root.finish()
+        by_name = {s.name: s for s in root.spans}
+        for name in ("exchange_plan", "pack", "exchange", "unpack"):
+            assert by_name[name].parent_id == parent.span_id, name
+        pack, unpack = by_name["pack"].attrs, by_name["unpack"].attrs
+        assert pack["gathers_native"] + pack["gathers_numpy"] == len(payloads)
+        assert pack["gathers_numpy"] >= 2  # the codes and the validity
+        assert 0 < unpack["runs"] <= D * nb
+        assert by_name["exchange_plan"].attrs["strategy"] == "compact"
+
+
+# ---------------------------------------------------------------------------
 # Session-level: whole builds, parquet bytes
 # ---------------------------------------------------------------------------
 
