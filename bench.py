@@ -1261,7 +1261,7 @@ def main() -> None:
         # --- mesh build/serve ladder: the scale-out story (ROADMAP item
         # 2). Per (rows, devices) rung: a warm covering build — on >1
         # devices the shard_map all-to-all shuffle plus the sharded
-        # sort+write tail (hyperspace.build.shardedTail.enabled) — and
+        # sort+write tail — and
         # the co-bucketed indexed join served with per-shard prepare +
         # merge. Stage seconds are busy time (sort/write sum across
         # shard tails; the excess over tail_wall is the sharding win);

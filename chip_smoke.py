@@ -233,7 +233,6 @@ def main(argv=None) -> int:
     from hyperspace_tpu.parallel import shuffle as shuffle_ops
 
     roster = {
-        "parallel.shuffle._flat_program": shuffle_ops._flat_program,
         "parallel.shuffle._compact_program": shuffle_ops._compact_program,
         "ops.join._sharded_join": join_ops._sharded_join,
         "ops.join._jit_vmapped": join_ops._jit_vmapped,
@@ -396,7 +395,7 @@ def main(argv=None) -> int:
                     if isinstance(v, (int, float, str))
                 }
                 if device["platform"] != "cpu":
-                    check(strategy in ("flat", "compact"),
+                    check(strategy == "compact",
                           f"accelerator mesh resolved to {strategy!r}")
             peak = mesh_info["peak_bytes_after_build"] = peaks()
             if n_dev > 1 and device["platform"] != "cpu" and n_items >= 16_000_000:

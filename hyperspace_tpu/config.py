@@ -98,15 +98,6 @@ class Config:
         )
 
     @property
-    def build_partition_first(self) -> bool:
-        """Partition-then-sort build pipeline (bit-identical to the
-        global lexsort it replaces; False = legacy path)."""
-        return self.get_bool(
-            C.INDEX_BUILD_PARTITION_FIRST,
-            C.INDEX_BUILD_PARTITION_FIRST_DEFAULT,
-        )
-
-    @property
     def build_num_shards(self) -> int:
         """Device shards for the build plane (0 = the whole session
         mesh); a positive value caps the build mesh to the first N
@@ -116,9 +107,10 @@ class Config:
     @property
     def build_exchange_strategy(self) -> str:
         """Exchange strategy of the build's bucket shuffle
-        (``parallel/shuffle.py``): ``auto`` | ``flat`` | ``compact`` |
-        ``host`` | ``twostage`` — all bit-identical; ``auto`` resolves
-        per topology (see ``shuffle.resolve_strategy``)."""
+        (``parallel/shuffle.py``): ``auto`` | ``compact`` | ``host`` |
+        ``twostage`` — all bit-identical; ``auto`` resolves by platform
+        (see ``shuffle.resolve_strategy``); a diagnostic override and
+        test seam, not a tuning knob."""
         return self.get_str(
             C.BUILD_EXCHANGE_STRATEGY, C.BUILD_EXCHANGE_STRATEGY_DEFAULT
         )
@@ -130,16 +122,6 @@ class Config:
         return self.get_int(
             C.BUILD_EXCHANGE_TWOSTAGE_HOSTS,
             C.BUILD_EXCHANGE_TWOSTAGE_HOSTS_DEFAULT,
-        )
-
-    @property
-    def build_sharded_tail(self) -> bool:
-        """Device-local build/serve tail on a >1-device mesh: per-shard
-        sort + write and per-shard join prepare/merge, union at the
-        edge (bit-identical to the single-tail path; False = old path)."""
-        return self.get_bool(
-            C.BUILD_SHARDED_TAIL_ENABLED,
-            C.BUILD_SHARDED_TAIL_ENABLED_DEFAULT,
         )
 
     @property

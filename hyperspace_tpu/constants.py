@@ -116,38 +116,14 @@ EXPLAIN_DISPLAY_MODE_DEFAULT = "plaintext"
 INDEX_BUILD_MEMORY_BUDGET = "hyperspace.index.build.memoryBudgetBytes"
 INDEX_BUILD_MEMORY_BUDGET_DEFAULT = 0
 
-# Partition-first build sort: counting-scatter rows into per-bucket runs
-# first, then key-sort each bucket independently (working set ≈
-# rows/num_buckets) instead of one global lexsort by (bucket, keys) —
-# bit-identical output, fixes the 64M-row sort collapse (BASELINE.md:
-# permutation gathers walking a 512MB working set, TLB-bound). Off =
-# the legacy global lexsort, kept as a differential-test reference and
-# escape hatch.
-INDEX_BUILD_PARTITION_FIRST = "hyperspace.index.build.partitionFirst"
-INDEX_BUILD_PARTITION_FIRST_DEFAULT = True
-
-# Sharded build/serve tail (docs/MULTIHOST.md): on a >1-device mesh,
-# bucket ownership stays device-local past the exchange — each shard's
-# bucket range runs its own partition-first sort + bucketed parquet
-# write (build) and its own prepare + merge-join (serve) concurrently,
-# with a cheap per-bucket union at the edge, instead of serializing the
-# post-exchange tail through one global permutation on the host. Every
-# bucket file and every join row is bit-identical either way (a bucket
-# lives wholly on one shard); the flag restores the old single-tail
-# path for A/B timing and as an escape hatch. No effect on a 1-device
-# mesh.
-BUILD_SHARDED_TAIL_ENABLED = "hyperspace.build.shardedTail.enabled"
-BUILD_SHARDED_TAIL_ENABLED_DEFAULT = True
-
 # Exchange-strategy plane (parallel/shuffle.py, docs/MULTIHOST.md): the
-# build's bucket shuffle is a library of pluggable strategies behind one
-# interface — "auto" resolves per topology (multi-process job ->
-# "twostage" DCN/ICI decomposition; CPU mesh -> "host" pure-RAM reorder,
-# the simulation must never pay ICI-emulation costs; single-host
-# accelerator -> "compact" when the calibration probe measured it
-# beating "flat" at the build size, else "flat", the padded-[D, cap]
-# all_to_all baseline). Every strategy is differential-tested
-# bit-identical to "flat".
+# build's bucket shuffle has three strategies behind one interface and
+# "auto" picks by platform alone (multi-process job -> "twostage"
+# DCN/ICI decomposition; CPU mesh -> "host" pure-RAM reorder, the
+# simulation must never pay ICI-emulation costs; single-host accelerator
+# -> "compact"). A diagnostic override and test seam, not a tuning knob:
+# every strategy is differential-tested bit-identical to "host", the
+# numpy reference.
 BUILD_EXCHANGE_STRATEGY = "hyperspace.build.exchange.strategy"
 BUILD_EXCHANGE_STRATEGY_DEFAULT = "auto"
 

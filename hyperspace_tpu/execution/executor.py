@@ -262,14 +262,12 @@ def _stream_stats_add(key: str, amount: int = 1) -> None:
 
 
 def _serve_shards(session) -> int:
-    """Shard count for the device-local serve tail
-    (``hyperspace.build.shardedTail.enabled``, one flag for both
-    planes): the session mesh size when the flag is on and the mesh has
-    more than one device, else 1 (single-tail scheduling). The shard
+    """Shard count for the device-local serve tail: the session mesh
+    size, 1 without a session (single-tail scheduling). The shard
     layout is the build's bucket ownership (``bucket % D``) — each
     worker prepares and merges only the buckets its shard owns, with a
     per-bucket union at the edge (bit-identical output)."""
-    if session is None or not session.conf.build_sharded_tail:
+    if session is None:
         return 1
     return int(session.runtime.mesh.devices.size)
 

@@ -720,15 +720,10 @@ def _record_shuffle_telemetry(stats: Dict) -> None:
             _breakdown_add(span_name, float(stats[k]))
 
 
-def _partition_first(ctx) -> bool:
-    return ctx.session.conf.build_partition_first
-
-
-def _sharded_tail_offsets(ctx, shard_offs):
+def _sharded_tail_offsets(shard_offs):
     """The shard offsets when the device-local tail applies, else None:
-    flag on (``hyperspace.build.shardedTail.enabled``), an exchange
-    actually ran, and more than one shard holds rows."""
-    if shard_offs is None or not ctx.session.conf.build_sharded_tail:
+    an exchange ran and more than one shard holds rows."""
+    if shard_offs is None:
         return None
     occupied = int(np.count_nonzero(np.diff(shard_offs)))
     return shard_offs if occupied > 1 else None
@@ -738,13 +733,13 @@ def bucketize(ctx, batch: ColumnarBatch, indexed_cols: List[str], num_buckets: i
     """Route rows to buckets -> (bucket_ids, batch) in bucket-grouped,
     key-sorted order. Uses the mesh all-to-all when >1 device.
 
-    The sort half runs partition-first by default (stable counting
-    scatter into per-bucket runs, then per-bucket key sorts on a thread
-    pool — working set ≈ rows/num_buckets per sort) and produces a
-    permutation bit-identical to the legacy global lexsort by
-    (bucket, keys...) it replaces (``hyperspace.index.build.partitionFirst``
-    = false restores the old path). On a >1-device mesh with the sharded
-    tail on, each shard's slice sorts CONCURRENTLY
+    The sort half runs partition-first (stable counting scatter into
+    per-bucket runs, then per-bucket key sorts on a thread pool —
+    working set ≈ rows/num_buckets per sort) and produces the stable
+    lexsort permutation by (bucket, keys...)
+    (``ops/sort.sort_permutation``: the tests' reference). When the
+    exchange left more than one shard holding rows, each shard's slice
+    sorts CONCURRENTLY
     (``ops/sort.sharded_sort_permutation``): row order is then
     shard-major rather than globally bucket-ascending, but each bucket's
     rows and their key-sorted order are identical — the bucketed writers
@@ -759,18 +754,13 @@ def bucketize(ctx, batch: ColumnarBatch, indexed_cols: List[str], num_buckets: i
         ctx, batch, indexed_cols, num_buckets
     )
     with stage("sort"):
-        if _partition_first(ctx):
-            shard_offs = _sharded_tail_offsets(ctx, shard_offs)
-            if shard_offs is not None:
-                perm = sharded_sort_permutation(
-                    reps, buckets, num_buckets, shard_offs
-                )
-            else:
-                perm = partitioned_sort_permutation(
-                    reps, buckets, num_buckets
-                )
+        shard_offs = _sharded_tail_offsets(shard_offs)
+        if shard_offs is not None:
+            perm = sharded_sort_permutation(
+                reps, buckets, num_buckets, shard_offs
+            )
         else:
-            perm = sort_permutation(reps, buckets)
+            perm = partitioned_sort_permutation(reps, buckets, num_buckets)
         out = buckets[perm], batch.take(perm)
     return out
 
@@ -789,9 +779,13 @@ def write_bucketed(
     or a list mixing both (incremental refresh: appended scan + rewritten
     old data).
 
+    One chooser: a :class:`SourceScan` among the sources → streaming
+    waves; else hash and exchange, then the sharded tail if the exchange
+    returned more than one occupied shard, else the pipelined tail.
+
     The parquet dictionary-encoding decision is computed ONCE here, on
-    the pre-sort input, and passed to whichever writer runs — the legacy
-    and partition-first layouts must stay byte-identical, so they cannot
+    the pre-sort input, and passed to whichever tail runs: the sharded
+    and the single tail must write byte-identical files, so they cannot
     each sample a differently-ordered table.
     """
     sources = data if isinstance(data, list) else [data]
@@ -811,26 +805,12 @@ def write_bucketed(
         return []
     with stage("dict_probe"):
         use_dict = pio.dictionary_columns_for_batch(batch)
-    if _partition_first(ctx):
-        return _global_written(
-            ctx,
-            _write_bucketed_pipelined(
-                ctx, batch, indexed_cols, num_buckets, file_idx_offset,
-                use_dict,
-            ),
-        )
-    buckets, batch = bucketize(ctx, batch, indexed_cols, num_buckets)
-    with stage("write") as sp:
-        out = pio.write_bucket_files(
-            ctx.index_data_path,
-            buckets,
-            batch,
-            num_buckets,
-            file_idx_offset,
-            use_dictionary=use_dict,
-        )
-        _count_written(sp, out)
-    return _global_written(ctx, out)
+    return _global_written(
+        ctx,
+        _write_bucketed_pipelined(
+            ctx, batch, indexed_cols, num_buckets, file_idx_offset, use_dict
+        ),
+    )
 
 
 def _count_written(sp, paths: List[str]) -> None:
@@ -906,10 +886,11 @@ def _write_bucketed_pipelined(
     3. bucket *i*'s parquet write runs on a writer thread while bucket
        *i+1* is still sorting.
 
-    Output is bit-identical to the legacy global-lexsort layout: the
-    composed permutation equals the stable lexsort by (bucket, keys...)
-    and each file is written from the same rows in the same order with
-    the same encoding decision.
+    The composed permutation equals the stable lexsort by (bucket,
+    keys...) (``ops/sort.sort_permutation``), so each file holds what
+    ``pio.write_bucket_files`` would write from that order under the
+    same encoding decision — the layout the differential tests hold
+    this tail to.
 
     Stage accounting: "sort" spans ``partition`` (counting scatter +
     order words), ``to_arrow`` and ``bucket_sorts`` (all per-bucket
@@ -935,7 +916,7 @@ def _write_bucketed_pipelined(
         ctx, batch, indexed_cols, num_buckets
     )
     os.makedirs(ctx.index_data_path, exist_ok=True)
-    shard_offs = _sharded_tail_offsets(ctx, shard_offs)
+    shard_offs = _sharded_tail_offsets(shard_offs)
     if shard_offs is not None:
         written = _write_bucketed_sharded(
             ctx, buckets, reps, batch, file_idx_offset, use_dict,
@@ -1205,10 +1186,10 @@ def _write_bucketed_streaming(
                     bucket_parts.setdefault(b, []).append(path)
                 wave_idx += 1
         # merge: per bucket, read parts, key-sort, write the final file.
-        # On a >1-device mesh with the sharded tail on, each shard's
-        # bucket range (bucket % D) merges on its own worker — the
-        # streaming build's waves already sorted per shard (bucketize),
-        # and this keeps the merge tail device-local too.
+        # On a >1-device mesh each shard's bucket range (bucket % D)
+        # merges on its own worker — the streaming build's waves already
+        # sorted per shard (bucketize), and this keeps the merge tail
+        # device-local too.
         def merge_bucket(b: int) -> List[str]:
             merged = ColumnarBatch.from_arrow(
                 pio.read_table(bucket_parts[b], None)
@@ -1227,7 +1208,7 @@ def _write_bucketed_streaming(
         D = ctx.mesh.devices.size
         written: List[str] = []
         merge_workers = 1
-        if D > 1 and ctx.session.conf.build_sharded_tail and len(ordered) > 1:
+        if D > 1 and len(ordered) > 1:
             # The streaming build's contract is bounded peak memory (one
             # wave + one bucket); concurrent per-shard merges may only
             # widen that to k buckets when k of the LARGEST fit the wave

@@ -47,7 +47,7 @@ import numpy as np
 _log = logging.getLogger("hyperspace_tpu.native.calibrate")
 
 # Bump when the probe methodology changes; stale cache files re-probe.
-_PROBE_VERSION = 6
+_PROBE_VERSION = 7
 
 # Effectively-infinite row count: "this engine never loses on this
 # machine" (e.g. host vs device on a CPU backend, or an accelerator
@@ -78,7 +78,6 @@ class Thresholds:
     native_gather_min_rows: int = 0
     native_range_mask_min_rows: int = 0
     native_fused_pipeline_min_rows: int = 0
-    exchange_compact_min_rows: int = 0
     source: str = "defaults"
 
 
@@ -373,52 +372,6 @@ def _probe_native_fused_pipeline_min() -> int:
     return _NATIVE_PROBE_SIZES[-1] * 2
 
 
-def _probe_exchange_compact_min(platform: str) -> int:
-    """Exchange-strategy crossover (``parallel/shuffle.py``): the
-    smallest probe size where the ``compact`` host-packed exchange beats
-    the ``flat`` padded all_to_all on this machine's device mesh, or 0
-    when no crossover was measured (auto keeps ``flat``).
-
-    Skipped on CPU backends outright — ``auto`` resolves a CPU mesh to
-    the ``host`` strategy before ever consulting this threshold, so the
-    probe would only burn compiles. On an accelerator the probe pays one
-    compile per (strategy, size), cached per machine like the other
-    device probes."""
-    if platform in ("cpu", "none"):
-        return 0
-    import jax
-
-    if len(jax.devices()) < 2:
-        return 0
-    if jax.process_count() > 1:
-        # never run collectives from a lazily-triggered per-host probe
-        # (peers may not be probing -> hang), and a multi-process job
-        # coerces every strategy to twostage anyway — the threshold is
-        # never consulted there
-        return 0
-    from hyperspace_tpu.parallel.mesh import default_mesh
-    from hyperspace_tpu.parallel import shuffle as shuffle_mod
-
-    mesh = default_mesh()
-    rng = np.random.default_rng(50)
-    for n in _DEVICE_PROBE_SIZES:
-        reps = rng.integers(-(2**62), 2**62, size=(1, n), dtype=np.int64)
-        payloads = [reps[0], rng.normal(0.0, 1.0, n)]
-
-        def run(strategy):
-            shuffle_mod.bucket_shuffle(
-                mesh, reps, payloads, 200, strategy=strategy
-            )
-
-        run("flat")  # warm both compiles out of the measurement
-        run("compact")
-        if _time_best(lambda: run("compact")) < _time_best(
-            lambda: run("flat")
-        ):
-            return n
-    return 0
-
-
 def _probe_host_max(op: str, platform: str) -> int:
     """Smallest size where the device beats the host for ``op`` ("sort" |
     "hash"), extrapolated monotonic; _NEVER when the host wins at every
@@ -494,9 +447,6 @@ def _probe() -> Thresholds:
         native_gather_min_rows=_probe_native_gather_min(),
         native_range_mask_min_rows=_probe_native_range_mask_min(),
         native_fused_pipeline_min_rows=_probe_native_fused_pipeline_min(),
-        exchange_compact_min_rows=_probe_exchange_compact_min(
-            key["platform"]
-        ),
         source="calibrated",
     )
     _log.info(
@@ -531,7 +481,6 @@ def _load_cache() -> Optional[Thresholds]:
             native_fused_pipeline_min_rows=int(
                 t["native_fused_pipeline_min_rows"]
             ),
-            exchange_compact_min_rows=int(t["exchange_compact_min_rows"]),
             source="calibrated",
         )
     except (KeyError, TypeError, ValueError):
@@ -570,7 +519,6 @@ def _store_cache(t: Thresholds) -> None:
                             "native_gather_min_rows",
                             "native_range_mask_min_rows",
                             "native_fused_pipeline_min_rows",
-                            "exchange_compact_min_rows",
                         )
                     },
                 },

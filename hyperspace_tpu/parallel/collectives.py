@@ -65,14 +65,6 @@ COLLECTIVE_SITES: Dict[str, Tuple[str, str, str]] = {
         "parameters agree, and idempotent re-entry is a no-op everywhere",
     ),
     # -- exchange-strategy device programs (parallel/shuffle.py) -------------
-    "hyperspace_tpu.parallel.shuffle._flat_program": (
-        "all_to_all",
-        "symmetric-all",
-        "single-controller shard_map program: cap and payload structure "
-        "are computed from global inputs, so every trace sees identical "
-        "shapes (never reached on a multi-process job — resolve_strategy "
-        "coerces to twostage)",
-    ),
     "hyperspace_tpu.parallel.shuffle._compact_program": (
         "all_to_all",
         "symmetric-all",

@@ -9,15 +9,9 @@ strategies, not one engine-baked implementation. The strategy is chosen
 per build by ``hyperspace.build.exchange.strategy`` (default ``auto``:
 per-machine/topology resolution, see :func:`resolve_strategy`):
 
-``flat``
-    The original single ``lax.all_to_all`` over the flat shard axis:
-    every device scatters rows into a padded ``[D, cap]`` buffer (cap =
-    power-of-two-padded max per-(shard, peer) count) and sorts the
-    received rows by bucket on device. The baseline every other strategy
-    is differential-tested against, and the default on a single-host
-    accelerator mesh.
 ``compact``
-    Host-packed variable-length exchange, its host side driven by ONE
+    The single-host accelerator default. Host-packed variable-length
+    exchange, its host side driven by ONE
     counting partition an exchange, keyed ``(source shard, owner,
     bucket)``: the host bucket ids (hashed once, natively) become the
     int32 key ``source * num_buckets + rank of the bucket in (owner,
@@ -40,34 +34,34 @@ per-machine/topology resolution, see :func:`resolve_strategy`):
     canonical post-exchange permutation (threaded native/numpy gathers).
     The CPU-simulation default — an emulated ICI exchange on a CPU mesh
     pays real pack/argsort/copy costs to move rows between host buffers
-    that live in the same RAM (39s of the 51s 64M/mesh8 build,
-    MULTICHIP_r06) — and the per-host leg of a multi-host decomposition.
+    that live in the same RAM — the numpy reference the other
+    strategies are differential-tested against, and the per-host leg of
+    a multi-host decomposition.
 ``twostage``
     The DCN/ICI decomposition from docs/MULTIHOST.md: the intra-host leg
     runs host-side (each host re-groups its rows in RAM by destination
     lane), and the cross-host leg is one ``ppermute`` round per peer
     host over the ``dcn`` mesh axis with **per-peer slot caps** sized
-    from the per-(shard, peer) count matrix — the skew telemetry from
-    the ``[D, cap]`` era becomes the slot-sizing input instead of only a
-    warning (one hot destination host inflates only the rounds that
-    target it, not every slot).
+    from the per-(shard, peer) count matrix — the skew telemetry is the
+    slot-sizing input, not only a warning (one hot destination host
+    inflates only the rounds that target it, not every slot).
 
-Every strategy produces BIT-IDENTICAL output to ``flat``: the flat
-program's post-exchange order is exactly the valid rows stable-sorted by
-``(bucket % D, bucket)`` with ties in original row order (received rows
-concatenate source-major per peer, sources hold local row order, and the
-final per-shard sort is a stable sort by bucket), so
-:func:`canonical_order` reproduces it host-side from the bucket ids
-alone. ``tests/test_exchange_strategies.py`` makes that argument
-mechanical across mesh sizes, payload types and skews.
+Every strategy produces BIT-IDENTICAL output: the rows stable-sorted by
+``(bucket % D, bucket)`` with ties in original row order (shard ``s``
+holds the buckets it owns ascending; inside a bucket the received rows
+concatenate source-major, sources hold local row order), which
+:func:`canonical_order` computes host-side from the bucket ids alone.
+``tests/test_exchange_strategies.py`` holds each strategy to ``host``
+and ``canonical_order`` to a numpy lexsort across mesh sizes, payload
+types and skews.
 
 (For >HBM datasets the same exchange runs once per wave over chunked
 host batches — the wave loop is ``indexes/covering_build.
 _write_bucketed_streaming``, driven by
 ``hyperspace.index.build.memoryBudgetBytes``.)
 
-Every device program here that issues a collective (``_flat_program``,
-``_compact_program``, ``_twostage_program``, and the
+Every device program here that issues a collective
+(``_compact_program``, ``_twostage_program``, and the
 ``process_allgather`` in ``_twostage_exchange_mp``) is registered in
 ``COLLECTIVE_SITES`` (``parallel/collectives.py``) with its symmetry
 contract — add a collective without registering it and hslint HS802
@@ -123,16 +117,10 @@ from hyperspace_tpu.parallel.mesh import (
 )
 
 STRATEGY_AUTO = "auto"
-STRATEGY_FLAT = "flat"
 STRATEGY_COMPACT = "compact"
 STRATEGY_HOST = "host"
 STRATEGY_TWOSTAGE = "twostage"
-STRATEGIES = (
-    STRATEGY_FLAT,
-    STRATEGY_COMPACT,
-    STRATEGY_HOST,
-    STRATEGY_TWOSTAGE,
-)
+STRATEGIES = (STRATEGY_COMPACT, STRATEGY_HOST, STRATEGY_TWOSTAGE)
 
 # ``last_shuffle_stats`` key -> the span whose seconds it holds
 STAGE_SECONDS_KEYS = {
@@ -164,8 +152,7 @@ def _host_bucket_ids(
     """Chunked host murmur3 bucket ids — bit-identical to the device
     hash (``ops/hash.py`` twins) and computed ONCE per exchange: every
     strategy reuses these ids for capacity planning, packing and
-    ordering instead of re-hashing on device (the old flat program
-    hashed every row a second time)."""
+    ordering instead of re-hashing on device."""
     n = key_reps.shape[1]
     out = np.empty(n, dtype=np.int32)
     for start in range(0, n, chunk):
@@ -176,15 +163,11 @@ def _host_bucket_ids(
     return out
 
 
-def _peer_counts(
-    owner: np.ndarray, valid: Optional[np.ndarray], n_local: int, D: int
-) -> np.ndarray:
-    """``[D, D]`` count of valid rows each source shard (contiguous
-    ``n_local``-row blocks) sends to each owner shard — the slot-sizing
-    and skew-telemetry input of every padded strategy."""
+def _peer_counts(owner: np.ndarray, n_local: int, D: int) -> np.ndarray:
+    """``[D, D]`` count of rows each source shard (contiguous
+    ``n_local``-row blocks) sends to each owner shard — the
+    skew-telemetry input of ``host`` and ``twostage``."""
     src = (np.arange(len(owner)) // n_local).astype(np.int64)
-    if valid is not None:
-        src, owner = src[valid], owner[valid]
     return np.bincount(src * D + owner, minlength=D * D).reshape(D, D)
 
 
@@ -196,9 +179,8 @@ def _timed(acct: Dict, key: str):
     interval on the one clock and its ``hs.<name>`` profiler annotation —
     and add that span's OWN seconds to ``acct[key]``, the exchange's
     account that ``_publish_stats`` turns into ``last_shuffle_stats``.
-    Outside an action (a bare ``bucket_shuffle``, the calibration probe)
-    the span is the no-op singleton and the block's own clock feeds the
-    account."""
+    Outside an action (a bare ``bucket_shuffle``) the span is the no-op
+    singleton and the block's own clock feeds the account."""
     t0 = _time.perf_counter_ns()
     sp = _obs_trace.NOOP
     try:
@@ -350,7 +332,7 @@ def canonical_order(
     sorting rows by ``(owner = bucket % D, bucket)`` (ties keep original
     row order), plus the ``[D+1]`` per-owner-shard row extents.
 
-    This reproduces the flat ``all_to_all`` output exactly: shard ``s``
+    What an ``all_to_all`` of per-owner slots delivers: shard ``s``
     holds the buckets it owns in ascending bucket order, and within a
     bucket the received rows concatenate source-shard-major with each
     source's rows in local (= original) order — i.e. ascending original
@@ -374,8 +356,8 @@ def _shape_cap(exact: int) -> int:
     ``2^(floor(log2 n) - 2)``): a streaming build's waves have slightly
     different max peer counts, and an EXACT cap would re-trace the
     exchange program once per wave. Three significant bits bound the
-    padding at <25% (vs up to 2x for the flat path's power-of-two cap)
-    while keeping the number of distinct compile shapes per octave at 4.
+    padding at <25% (a power-of-two cap pads up to 2x) while keeping
+    the number of distinct compile shapes per octave at 4.
     Correctness never depends on it — the unpack reads exact per-peer
     extents from the count matrix either way."""
     exact = max(int(exact), 1)
@@ -427,76 +409,6 @@ def _threaded_gather(
     return _per_payload(lambda a: _gather(a, idx), arrays, len(idx))
 
 
-# ---------------------------------------------------------------------------
-# Strategy: flat all_to_all (the baseline)
-# ---------------------------------------------------------------------------
-
-
-@functools.partial(
-    jax.jit, static_argnames=("mesh", "num_buckets", "num_payload", "cap")
-)
-def _flat_program(mesh, bucket_host, valid, payloads, num_buckets, num_payload, cap):
-    """The compiled flat multi-chip shuffle. Shapes: bucket_host [N]
-    int32 (HOST-computed ids — the device no longer re-hashes; the host
-    twin is bit-exact and already computed for capacity planning), valid
-    [N], payloads tuple of [N]-arrays; N divisible by D = mesh size.
-
-    ``cap`` is the per-(shard, peer) send capacity, computed on the host
-    from the actual destination counts and padded to a power of two. The
-    exchange buffer is [D, cap] per shard — sized to the real traffic —
-    instead of the worst-case [D, n_local] (which inflates memory D× and
-    was flagged as the first thing to OOM on a large mesh)."""
-    del num_payload  # encoded in payloads pytree structure
-    D = mesh.devices.size
-
-    def local(bkt, vld, cols):
-        n = bkt.shape[0]
-        # invalid (padding) rows route to sentinel destination D: they
-        # never occupy exchange slots, so cap tracks VALID traffic only
-        # (host counts valid rows only)
-        dest = jnp.where(vld, bkt % D, jnp.int32(D))
-        order = jnp.argsort(dest, stable=True)
-        dest_s = dest[order]
-        counts = jnp.bincount(dest_s, length=D + 1)
-        offsets = jnp.concatenate(
-            [jnp.zeros(1, dtype=counts.dtype), jnp.cumsum(counts)[:-1]]
-        )
-        rank = jnp.arange(n) - offsets[dest_s]
-
-        def scatter(col, fill=0):
-            buf = jnp.full((D, cap), fill, dtype=col.dtype)
-            # valid rows have dest_s < D and rank < cap (host-sized);
-            # sentinel-dest rows index row D and are dropped by .at[]'s
-            # out-of-bounds semantics. bucket_shuffle re-checks the
-            # compacted row count, so an undersized cap fails loudly.
-            return buf.at[dest_s, rank].set(col[order])
-
-        exchange = lambda x: lax.all_to_all(x, SHARD_AXIS, 0, 0, tiled=True)
-        recv_bucket = exchange(scatter(bkt))
-        recv_valid = exchange(scatter(vld.astype(jnp.bool_), fill=False))
-        recv_cols = tuple(exchange(scatter(c)) for c in cols)
-        # Flatten the per-peer dimension; sort locally by (valid desc,
-        # bucket) so each bucket is one contiguous run and invalid
-        # slots sink to the tail.
-        flat_bucket = recv_bucket.reshape(-1)
-        flat_valid = recv_valid.reshape(-1)
-        flat_cols = tuple(c.reshape(-1) for c in recv_cols)
-        sort_bucket = jnp.where(flat_valid, flat_bucket, jnp.int32(num_buckets))
-        perm = jnp.argsort(sort_bucket, stable=True)
-        return (
-            flat_bucket[perm],
-            flat_valid[perm],
-            tuple(c[perm] for c in flat_cols),
-        )
-
-    return jax.shard_map(
-        local,
-        mesh=mesh,
-        in_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS)),
-        out_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS)),
-    )(bucket_host, valid, payloads)
-
-
 def _process_local_operand(hmesh, local_block: np.ndarray):
     """This process's ``[1, L, B]`` send block -> the globally-sharded
     ``[H, L, B]`` device operand, built via
@@ -509,113 +421,6 @@ def _process_local_operand(hmesh, local_block: np.ndarray):
         NamedSharding(hmesh, P(DCN_AXIS, ICI_AXIS)),
         np.ascontiguousarray(local_block),
     )
-
-
-def _flat_exchange(mesh, key_reps, payloads, num_buckets, seed):
-    """Strategy ``flat`` — the original padded-[D, cap] all_to_all path,
-    kept as the baseline (and single-host accelerator default)."""
-    from hyperspace_tpu.ops import pad_len
-
-    D = mesh.devices.size
-    n = key_reps.shape[1]
-    acct: Dict = {}
-    # power-of-two row count (ops/__init__ shape policy), then round up
-    # to a multiple of D so shard_map divides evenly
-    target = pad_len(n)
-    target += (-target) % D
-    pad = target - n
-    with _timed(acct, "plan_s") as sp:
-        if pad:
-            key_reps = np.pad(key_reps, ((0, 0), (0, pad)))
-        valid = np.ones(n + pad, dtype=bool)
-        if pad:
-            valid[n:] = False
-        bucket_host = _host_bucket_ids(key_reps, num_buckets, seed)
-        cap, counts = _flat_cap(bucket_host, valid, D)
-        _plan_attrs(sp, STRATEGY_FLAT, D, cap, counts)
-    row_bytes = sum(p.dtype.itemsize for p in payloads)
-    with _timed(acct, "pack_s") as sp:
-        # the device scatters rows into their slots; the host's share of
-        # the pack is the padding to the program's row count
-        if pad:
-            payloads = [np.pad(p, (0, pad)) for p in payloads]
-        sp.set("bytes", _nbytes(payloads))
-    # operands go host -> owning device shard by shard (put_sharded);
-    # the program's in_specs then find them already in place
-    bucket, vmask, cols = _device_leg(
-        acct,
-        lambda: (
-            put_sharded(mesh, bucket_host),
-            put_sharded(mesh, valid),
-            tuple(put_sharded(mesh, p) for p in payloads),
-        ),
-        lambda ops: _flat_program(
-            mesh, *ops, num_buckets, len(payloads), cap
-        ),
-        lambda out: (
-            np.asarray(out[0]),
-            np.asarray(out[1]),
-            [np.asarray(c) for c in out[2]],
-        ),
-    )
-    with _timed(acct, "unpack_s") as sp:
-        keep = np.nonzero(vmask)[0]
-        if len(keep) != n:
-            raise RuntimeError(
-                f"bucket shuffle lost rows: sent {n}, received {len(keep)} "
-                f"(cap={cap}) — host/device hash divergence?"
-            )
-        out_bucket = bucket[keep]
-        out_cols = [c[keep] for c in cols]
-        # shard s's post-exchange slice is rows [s*D*cap, (s+1)*D*cap) of
-        # the flat output; its compacted extent is the valid count per slice
-        per_shard = vmask.reshape(D, D * cap).sum(axis=1)
-        offsets = np.concatenate(
-            [np.zeros(1, dtype=np.int64), np.cumsum(per_shard, dtype=np.int64)]
-        )
-        sp.set("bytes", _nbytes(out_cols))
-    _publish_stats(
-        STRATEGY_FLAT,
-        D,
-        cap,
-        counts,
-        acct,
-        wire_bytes=_off_chip_rows(counts) * row_bytes,
-        # every slot carries its payloads, its bucket id and its validity
-        slot_bytes=D * D * cap * (row_bytes + 4 + 1),
-    )
-    return out_bucket, out_cols, offsets
-
-
-def _flat_cap(
-    bucket_host: np.ndarray, valid: np.ndarray, D: int
-) -> Tuple[int, np.ndarray]:
-    """(cap, counts) for the flat program: the power-of-two-padded MAX
-    count of VALID rows any shard sends to any peer, never larger than a
-    shard's slice."""
-    from hyperspace_tpu.ops import pad_len
-
-    n_local = len(bucket_host) // D
-    counts = _peer_counts(bucket_host % D, valid, n_local, D)
-    max_count = max(int(counts.max()), 1)
-    return min(pad_len(max_count), n_local), counts
-
-
-def _exchange_cap(
-    key_reps: np.ndarray,
-    valid: np.ndarray,
-    num_buckets: int,
-    D: int,
-    seed: int,
-    chunk: int = 1 << 18,
-) -> int:
-    """Back-compat capacity probe (tests): per-(shard, peer) exchange
-    capacity of the flat strategy for an already-padded input, also
-    publishing the skew telemetry snapshot."""
-    ids = _host_bucket_ids(key_reps, num_buckets, seed, chunk)
-    cap, counts = _flat_cap(ids, valid, D)
-    _publish_stats(STRATEGY_FLAT, D, cap, counts, {})
-    return cap
 
 
 # ---------------------------------------------------------------------------
@@ -642,7 +447,7 @@ def _host_exchange(mesh, key_reps, payloads, num_buckets, seed):
     with _timed(acct, "plan_s") as sp:
         bucket_ids = _host_bucket_ids(key_reps, num_buckets, seed)
         n_local = -(-n // D) if n else 1
-        counts = _peer_counts(bucket_ids % D, None, n_local, D)
+        counts = _peer_counts(bucket_ids % D, n_local, D)
         cap = int(counts.max()) if counts.size else 0
         _plan_attrs(sp, STRATEGY_HOST, D, cap, counts)
     with _timed(acct, "pack_s") as sp:
@@ -805,9 +610,8 @@ def _compact_exchange(mesh, key_reps, payloads, num_buckets, seed):
     at most ``D * num_buckets`` contiguous runs a payload (attr
     ``runs``) from the received slots straight into canonical order.
     Rows travel grouped by bucket inside a slot; the ``all_to_all`` does
-    not look inside one. Compared to ``flat`` this drops the second hash
-    pass, both device argsorts, the bucket/validity planes from the
-    wire, and the pow2 cap blowup; the exchanged bytes are exactly
+    not look inside one: no device hash or argsort, no bucket or
+    validity plane on the wire; the exchanged bytes are exactly
     ``D*D*cap`` slots per payload."""
     D = mesh.devices.size
     acct: Dict = {}
@@ -1048,8 +852,8 @@ def _twostage_exchange(mesh, key_reps, payloads, num_buckets, seed, hosts):
     ``max(count[src_host → (src_host+r) % H host, lane])`` — the
     per-(shard, peer) count matrix (the skew telemetry) IS the slot
     sizing, so a hot destination host inflates only the rounds that
-    target it. Row volume over DCN is unchanged vs flat; message count
-    per host drops to one buffer per peer host and no row pays a second
+    target it. Row volume over DCN is that of one all_to_all; message
+    count per host drops to one buffer per peer host and no row pays a second
     device hash or argsort.
 
     On a REAL multi-process job the per-process variant runs instead
@@ -1071,7 +875,7 @@ def _twostage_exchange(mesh, key_reps, payloads, num_buckets, seed, hosts):
         bucket_ids = _host_bucket_ids(key_reps, num_buckets, seed)
         owner = bucket_ids % D
         n_local = -(-n // D) if n else 1
-        counts = _peer_counts(owner, None, n_local, D)
+        counts = _peer_counts(owner, n_local, D)
         src_dev = (np.arange(n, dtype=np.int64) // n_local).astype(np.int64)
         src_h = src_dev // L
         dst_h = owner // L
@@ -1145,18 +949,16 @@ def _twostage_exchange(mesh, key_reps, payloads, num_buckets, seed, hosts):
 # ---------------------------------------------------------------------------
 
 
-def resolve_strategy(strategy: str, mesh, n_rows: int) -> str:
+def resolve_strategy(strategy: str, mesh) -> str:
     """Map the configured strategy (``hyperspace.build.exchange.
-    strategy``) to a concrete one. ``auto``:
+    strategy``) to a concrete one. ``auto`` is a function of the
+    platform alone:
 
     * multi-process job → ``twostage`` (the DCN leg is the bottleneck;
       docs/MULTIHOST.md);
     * CPU mesh → ``host`` (the simulation must never pay ICI-emulation
       costs);
-    * single-host accelerator → ``compact`` when the calibration probe
-      measured it beating ``flat`` at this row count
-      (``exchange_compact_min_rows``), else ``flat`` (the baseline and
-      TPU default).
+    * single-host accelerator → ``compact``.
     """
     s = (strategy or STRATEGY_AUTO).strip().lower()
     if s != STRATEGY_AUTO and s not in STRATEGIES:
@@ -1178,12 +980,7 @@ def resolve_strategy(strategy: str, mesh, n_rows: int) -> str:
         return s
     if mesh.devices.flat[0].platform == "cpu":
         return STRATEGY_HOST
-    from hyperspace_tpu.native import calibrate
-
-    t = calibrate.thresholds().exchange_compact_min_rows
-    if t and n_rows >= t:
-        return STRATEGY_COMPACT
-    return STRATEGY_FLAT
+    return STRATEGY_COMPACT
 
 
 def bucket_shuffle(
@@ -1223,12 +1020,8 @@ def bucket_shuffle(
         p.view(f"i{p.dtype.itemsize}") if p.dtype.kind == "f" else p
         for p in payloads
     ]
-    name = resolve_strategy(strategy, mesh, key_reps.shape[1])
-    if name == STRATEGY_FLAT:
-        bucket, cols, offsets = _flat_exchange(
-            mesh, key_reps, payloads, num_buckets, seed
-        )
-    elif name == STRATEGY_HOST:
+    name = resolve_strategy(strategy, mesh)
+    if name == STRATEGY_HOST:
         bucket, cols, offsets = _host_exchange(
             mesh, key_reps, payloads, num_buckets, seed
         )

@@ -20,7 +20,7 @@ throughput fields.
 Usage:  python scripts/bench_mesh.py [n_devices]     (default 8)
 Env:    HS_MESH_ROWS (default 64_000_000), HS_MESH_BUCKETS (default 8),
         HS_MESH_SIZES (default "1,<n_devices>"),
-        HS_MESH_STRATEGIES (default "auto" — e.g. "auto,flat,compact,
+        HS_MESH_STRATEGIES (default "auto" — e.g. "auto,compact,host,
         twostage" for a per-strategy A/B artifact)
 """
 

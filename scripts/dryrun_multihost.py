@@ -59,7 +59,6 @@ WITNESS_COORDINATOR_SITES = (
 #: single-controller device programs a multi-process job must never
 #: route through (resolve_strategy coerces to twostage)
 WITNESS_SINGLE_HOST_SITES = (
-    "hyperspace_tpu.parallel.shuffle._flat_program",
     "hyperspace_tpu.parallel.shuffle._compact_program",
 )
 

@@ -3,8 +3,9 @@ the differential matrix.
 
 The contract: every strategy (``host`` pure-RAM reorder, ``compact``
 host-packed exact-extent all_to_all, ``twostage`` DCN/ICI decomposition
-with per-peer round caps) produces BIT-IDENTICAL output to the ``flat``
-padded all_to_all baseline — same bucket ids, same payload rows in the
+with per-peer round caps) produces BIT-IDENTICAL output — the rows in
+the numpy lexsort by (owner, bucket, row) that ``_reference`` computes
+with nothing of the exchange: same bucket ids, same payload rows in the
 same order, same ``with_shard_offsets`` extents — across mesh sizes,
 payload types (ints, strings via dictionary codes, validity masks,
 floats with NaNs), skews (uniform and one hot bucket) and the
@@ -15,6 +16,7 @@ the parquet bytes of whole builds, including streaming waves.
 import hashlib
 import logging
 import os
+import types
 
 import numpy as np
 import pyarrow as pa
@@ -62,19 +64,33 @@ def _strategies_for(D):
     return out
 
 
+def _reference(keys, payloads, nb, D):
+    """What ``bucket_shuffle(..., with_shard_offsets=True)`` must return,
+    by a numpy lexsort: rows by (owner = bucket % D, bucket, row)."""
+    from hyperspace_tpu.ops.hash import bucket_ids_host
+
+    ids = bucket_ids_host(keys, nb, 42)
+    perm = np.lexsort((np.arange(len(ids)), ids, ids % D))
+    offsets = np.concatenate([[0], np.cumsum(np.bincount(ids % D, minlength=D))])
+    return ids[perm], [p[perm] for p in payloads], offsets
+
+
+def _fake_mesh(platform):
+    """As much of a mesh as ``resolve_strategy`` reads."""
+    dev = types.SimpleNamespace(platform=platform)
+    return types.SimpleNamespace(devices=np.array([dev, dev], dtype=object))
+
+
 class TestStrategyDifferential:
     @pytest.mark.parametrize("D", [1, 2, 8])
     @pytest.mark.parametrize("skew", ["uniform", "hot"])
-    def test_bit_identical_to_flat(self, D, skew):
+    def test_bit_identical_to_reference(self, D, skew):
         mesh = _mesh(D)
         rng = np.random.default_rng(D * 31 + len(skew))
         n, nb = 3001, 16
         keys = _keys(rng, n, skew)
         payloads = _payload_matrix(rng, n)
-        ref = sh.bucket_shuffle(
-            mesh, keys, payloads, nb, with_shard_offsets=True,
-            strategy=sh.STRATEGY_FLAT,
-        )
+        ref = _reference(keys, payloads, nb, D)
         for strat in _strategies_for(D):
             got = sh.bucket_shuffle(
                 mesh, keys, payloads, nb, with_shard_offsets=True,
@@ -96,10 +112,7 @@ class TestStrategyDifferential:
         n, nb = 999, 3  # owners only 0..2 of 8 shards
         keys = rng.integers(0, 50, (1, n)).astype(np.int64)
         payloads = [np.arange(n, dtype=np.int64)]
-        ref = sh.bucket_shuffle(
-            mesh, keys, payloads, nb, with_shard_offsets=True,
-            strategy=sh.STRATEGY_FLAT,
-        )
+        ref = _reference(keys, payloads, nb, 8)
         assert (np.diff(ref[2])[nb:] == 0).all()
         for strat in _strategies_for(8):
             got = sh.bucket_shuffle(
@@ -118,10 +131,7 @@ class TestStrategyDifferential:
         n, nb = 2048, 16
         keys = rng.integers(0, 200, (1, n)).astype(np.int64)
         payloads = [keys[0], rng.normal(size=n)]
-        ref = sh.bucket_shuffle(
-            mesh, keys, payloads, nb, with_shard_offsets=True,
-            strategy=sh.STRATEGY_FLAT,
-        )
+        ref = _reference(keys, payloads, nb, 8)
         got = sh.bucket_shuffle(
             mesh, keys, payloads, nb, with_shard_offsets=True,
             strategy=sh.STRATEGY_TWOSTAGE, twostage_hosts=hosts,
@@ -133,7 +143,7 @@ class TestStrategyDifferential:
         assert sh.last_shuffle_stats["hosts"] == float(hosts)
 
     @pytest.mark.parametrize(
-        "strategy", [sh.STRATEGY_FLAT, sh.STRATEGY_COMPACT, sh.STRATEGY_TWOSTAGE]
+        "strategy", [sh.STRATEGY_COMPACT, sh.STRATEGY_TWOSTAGE]
     )
     def test_floats_cross_the_device_as_integers(self, strategy, monkeypatch):
         """No float dtype reaches a device exchange program, and float
@@ -141,7 +151,7 @@ class TestStrategyDifferential:
         mangles when it is handed them as float64 (it holds no IEEE
         double: 1e300 -> inf, -0.0 -> 0.0, a NaN's payload bits lost)."""
         seen = []
-        for prog in ("_flat_program", "_compact_program", "_twostage_program"):
+        for prog in ("_compact_program", "_twostage_program"):
             real = getattr(sh, prog)
 
             def spy(*args, _real=real, **kw):
@@ -176,7 +186,7 @@ class TestStrategyDifferential:
 
     def test_canonical_order_is_flat_order(self):
         """The host-side permutation equals the naive (owner, bucket,
-        row) lexsort — the invariant every non-flat strategy rides."""
+        row) lexsort — the invariant every strategy rides."""
         rng = np.random.default_rng(11)
         n, nb, D = 5000, 13, 8
         ids = rng.integers(0, nb, n).astype(np.int32)
@@ -187,17 +197,33 @@ class TestStrategyDifferential:
             np.diff(offs), np.bincount(ids % D, minlength=D)
         )
 
-    def test_resolve(self):
-        mesh = _mesh(8)
-        # CPU mesh: auto must pick the host-side exchange
-        assert sh.resolve_strategy("auto", mesh, 10**6) == sh.STRATEGY_HOST
-        assert sh.resolve_strategy("flat", mesh, 10) == sh.STRATEGY_FLAT
-        assert (
-            sh.resolve_strategy("TwoStage", mesh, 10)
-            == sh.STRATEGY_TWOSTAGE
-        )
-        with pytest.raises(ValueError, match="unknown exchange strategy"):
-            sh.resolve_strategy("bogus", mesh, 10)
+    @pytest.mark.parametrize(
+        "configured, platform, processes, expect",
+        [
+            # auto: a function of the process count and the platform only
+            ("auto", "cpu", 1, sh.STRATEGY_HOST),
+            ("auto", "tpu", 1, sh.STRATEGY_COMPACT),
+            ("auto", "cpu", 2, sh.STRATEGY_TWOSTAGE),
+            ("auto", "tpu", 2, sh.STRATEGY_TWOSTAGE),
+            # forced: taken as given, but a multi-process job has one way
+            ("TwoStage", "cpu", 1, sh.STRATEGY_TWOSTAGE),
+            ("host", "tpu", 1, sh.STRATEGY_HOST),
+            ("compact", "cpu", 2, sh.STRATEGY_TWOSTAGE),
+            ("bogus", "cpu", 1, ValueError),
+            ("flat", "tpu", 1, ValueError),
+        ],
+    )
+    def test_resolve(self, configured, platform, processes, expect, monkeypatch):
+        monkeypatch.setattr(jax, "process_count", lambda: processes)
+        mesh = _fake_mesh(platform)
+        if expect is ValueError:
+            with pytest.raises(ValueError, match="unknown exchange strategy"):
+                sh.resolve_strategy(configured, mesh)
+        else:
+            assert sh.resolve_strategy(configured, mesh) == expect
+
+    def test_resolve_auto_on_the_cpu_mesh(self):
+        assert sh.resolve_strategy("auto", _mesh(8)) == sh.STRATEGY_HOST
 
 
 # ---------------------------------------------------------------------------
@@ -215,6 +241,10 @@ def _layout_keys(shape, D, rng):
         return np.full((1, 2048), 7, dtype=np.int64)
     if shape == "tiny":  # n < D * buckets: most runs are empty
         return rng.integers(0, 10**6, (1, D * _LAYOUT_BUCKETS - 5)).astype(np.int64)
+    if shape == "one_row_a_shard":  # n = D: the least ``_hash_shuffle`` sends
+        return rng.integers(0, 10**6, (1, D)).astype(np.int64)
+    if shape == "under_a_row_a_slot":  # n < D * D: some slots stay empty
+        return rng.integers(0, 10**6, (1, D * D - 1)).astype(np.int64)
     n = 2003 if shape == "ragged" else 2048  # 2003: no multiple of D
     keys = rng.integers(0, 10**6, (1, 4 * n)).astype(np.int64)
     if shape == "empty_owner":  # owner 1 receives no row
@@ -226,7 +256,11 @@ def _layout_keys(shape, D, rng):
 class TestCompactOnePartition:
     @pytest.mark.parametrize("D", [2, 4, 8])
     @pytest.mark.parametrize(
-        "shape", ["uniform", "hot", "empty_owner", "ragged", "tiny"]
+        "shape",
+        [
+            "uniform", "hot", "empty_owner", "ragged", "tiny",
+            "one_row_a_shard", "under_a_row_a_slot",
+        ],
     )
     def test_pack_layout(self, D, shape, monkeypatch):
         """Every send slot holds exactly the rows of its (source, owner)
@@ -282,11 +316,11 @@ class TestCompactOnePartition:
 
     @pytest.mark.parametrize("D", [2, 4, 8])
     @pytest.mark.parametrize("gather", ["native", "numpy"])
-    def test_mixed_widths_equal_flat_and_host(self, D, gather, monkeypatch):
+    def test_mixed_widths_equal_host_and_reference(self, D, gather, monkeypatch):
         """int64, float64 (crossing as int64), int32 codes and uint8
-        validity under two key columns: ``compact`` equals ``flat`` and
-        ``host`` element for element whichever gather packs it, and the
-        account says which did."""
+        validity under two key columns: ``compact`` equals ``host`` and
+        the numpy lexsort element for element whichever gather packs it,
+        and the account says which did."""
         from hyperspace_tpu import native
         from hyperspace_tpu.io import columnar
 
@@ -315,10 +349,14 @@ class TestCompactOnePartition:
             mesh, keys, payloads, nb, with_shard_offsets=True,
             strategy=sh.STRATEGY_COMPACT,
         )
-        for other in (sh.STRATEGY_FLAT, sh.STRATEGY_HOST):
-            ref = sh.bucket_shuffle(
-                mesh, keys, payloads, nb, with_shard_offsets=True, strategy=other
-            )
+        refs = {
+            sh.STRATEGY_HOST: sh.bucket_shuffle(
+                mesh, keys, payloads, nb, with_shard_offsets=True,
+                strategy=sh.STRATEGY_HOST,
+            ),
+            "lexsort": _reference(keys, payloads, nb, D),
+        }
+        for other, ref in refs.items():
             np.testing.assert_array_equal(got[0], ref[0], err_msg=other)
             np.testing.assert_array_equal(got[2], ref[2], err_msg=other)
             for a, b in zip(got[1], ref[1]):
@@ -416,10 +454,10 @@ def _assert_identical_files(files_a, files_b, tag):
 
 class TestBuildDifferential:
     def test_in_memory_builds_bit_identical(self, mesh8, mixed_parquet):
-        ref = _build(mesh8, mixed_parquet, "exflat", "flat")
+        ref = _build(mesh8, mixed_parquet, "exhost", "host")
         from hyperspace_tpu.indexes.covering_build import last_build_telemetry
 
-        for strat in ("auto", "host", "compact", "twostage"):
+        for strat in ("auto", "compact", "twostage"):
             files = _build(
                 mesh8, mixed_parquet, f"ex{strat}", strat, hosts=2
             )
@@ -437,10 +475,10 @@ class TestBuildDifferential:
             [os.path.join(mixed_parquet, first)], "parquet"
         )[0]
         budget = int(per_file * 1.5)  # several waves
-        ref = _build(mesh8, mixed_parquet, "stflat", "flat", budget=budget)
+        ref = _build(mesh8, mixed_parquet, "sthost", "host", budget=budget)
         from hyperspace_tpu.indexes.covering_build import last_build_telemetry
 
-        for strat in ("host", "compact", "twostage"):
+        for strat in ("compact", "twostage"):
             files = _build(
                 mesh8, mixed_parquet, f"st{strat}", strat,
                 budget=budget, hosts=2,

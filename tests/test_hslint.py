@@ -153,7 +153,7 @@ class TestPackageClean:
             if f.primitives
         }
         assert bearing >= {
-            "hyperspace_tpu.parallel.shuffle._flat_program",
+            "hyperspace_tpu.parallel.shuffle._compact_program",
             "hyperspace_tpu.parallel.shuffle._twostage_program",
             "hyperspace_tpu.parallel.shuffle._twostage_exchange_mp",
             "hyperspace_tpu.indexes.covering_build._global_written",

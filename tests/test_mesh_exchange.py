@@ -117,7 +117,7 @@ def test_one_chip_build_records_no_exchange(one_chip):
     assert "shuffle_strategy" not in telemetry
 
 
-@pytest.mark.parametrize("strategy", ["flat", "compact", "host", "twostage"])
+@pytest.mark.parametrize("strategy", ["compact", "host", "twostage"])
 def test_mesh_build_against_the_reference(strategy, table, one_chip, tmp_path):
     items_dir, cols = table
     rows = len(cols["l_orderkey"])
@@ -150,9 +150,7 @@ def test_mesh_build_against_the_reference(strategy, table, one_chip, tmp_path):
         assert _sha(path) == _sha(ref_files[bucket]), (strategy, bucket)
 
     # -- peer counts against the reference's matrix --------------------------
-    # flat pads the table to a power of two before it cuts it into blocks
-    block = (1 << (rows - 1).bit_length()) // CHIPS if strategy == "flat" else 0
-    matrix = reference_mesh.peer_matrix(cols["l_orderkey"], BUCKETS, CHIPS, block)
+    matrix = reference_mesh.peer_matrix(cols["l_orderkey"], BUCKETS, CHIPS)
     assert matrix.sum() == rows
     assert telemetry["shuffle_max_peer_count"] == matrix.max()
     assert telemetry["shuffle_mean_peer_count"] == round(float(matrix.mean()), 1)
@@ -192,8 +190,8 @@ def test_mesh_build_against_the_reference(strategy, table, one_chip, tmp_path):
     if device_leg:
         assert counters["exchange_wire_bytes"] == wire > 0
         assert counters["exchange_slot_bytes"] >= wire
-        if strategy != "flat":      # packed on the host: what goes up is the slots
-            assert counters["exchange_h2d_bytes"] == counters["exchange_slot_bytes"]
+        # packed on the host: what goes up is the slots
+        assert counters["exchange_h2d_bytes"] == counters["exchange_slot_bytes"]
         assert counters["exchange_d2h_bytes"] >= rows * ROW_BYTES
         by_name = {s.name: s for s in legs}
         assert by_name["h2d"].attrs["bytes"] == counters["exchange_h2d_bytes"]

@@ -1,13 +1,16 @@
 """Partition-first build pipeline — differential tests.
 
-The partition-then-sort pipeline (``hyperspace.index.build.partitionFirst``,
-default on) must produce output BIT-IDENTICAL to the legacy global
-lexsort by (bucket, keys...): same stable tie order, same lineage
-values, same parquet bytes per bucket file (modulo nothing — the
-encoding decision is shared), on both the in-memory and the
-streaming/spill paths, with and without the native kernels.
+The partition-then-sort pipeline must produce output BIT-IDENTICAL to
+the stable global lexsort by (bucket, keys...): same stable tie order,
+same lineage values, same parquet bytes per bucket file (modulo nothing
+— the encoding decision is shared), on both the in-memory and the
+streaming/spill paths, with and without the native kernels. The
+expected files are written here, from ``sort_permutation`` +
+``pio.write_bucket_files`` (``_reference_tail``), with nothing of the
+build's partition, exchange or per-bucket sorts.
 """
 
+import contextlib
 import hashlib
 import os
 
@@ -18,7 +21,10 @@ import pytest
 
 from hyperspace_tpu import constants as C
 from hyperspace_tpu.hyperspace import Hyperspace
+from hyperspace_tpu.indexes import covering_build
 from hyperspace_tpu.indexes.covering import CoveringIndexConfig
+from hyperspace_tpu.io import parquet as pio
+from hyperspace_tpu.ops.hash import bucket_ids_np
 from hyperspace_tpu.ops.sort import (
     partition_by_bucket,
     partitioned_sort_permutation,
@@ -59,8 +65,39 @@ def _sha(path):
         return hashlib.sha256(f.read()).hexdigest()
 
 
-def _build(session, hs, src, name, partition_first, budget=0, lineage=False):
-    session.conf.set(C.INDEX_BUILD_PARTITION_FIRST, partition_first)
+def _reference_bucketize(ctx, batch, indexed_cols, num_buckets):
+    """(bucket ids, batch) by ONE stable lexsort over (bucket, keys...):
+    no exchange, no partition, whatever the session's mesh."""
+    reps = batch.key_reps(indexed_cols)
+    buckets = bucket_ids_np(reps, num_buckets)
+    perm = sort_permutation(reps, buckets)
+    return buckets[perm], batch.take(perm)
+
+
+def _reference_tail(ctx, batch, indexed_cols, num_buckets, file_idx_offset, use_dict):
+    """The files an in-memory build must write."""
+    buckets, batch = _reference_bucketize(ctx, batch, indexed_cols, num_buckets)
+    return pio.write_bucket_files(
+        ctx.index_data_path, buckets, batch, num_buckets, file_idx_offset,
+        use_dictionary=use_dict,
+    )
+
+
+@pytest.fixture
+def reference_build(monkeypatch):
+    """Run the block's builds with the test's own tail in the program's
+    place: the in-memory tail, and the streaming waves' bucketize."""
+    @contextlib.contextmanager
+    def swapped():
+        with monkeypatch.context() as mp:
+            mp.setattr(covering_build, "_write_bucketed_pipelined", _reference_tail)
+            mp.setattr(covering_build, "bucketize", _reference_bucketize)
+            yield
+
+    return swapped
+
+
+def _build(session, hs, src, name, budget=0, lineage=False):
     session.conf.set(C.INDEX_BUILD_MEMORY_BUDGET, budget)
     session.conf.set(C.INDEX_LINEAGE_ENABLED, lineage)
     df = session.read.parquet(src)
@@ -80,22 +117,30 @@ def _assert_identical_files(files_a, files_b):
 
 
 class TestDifferentialBuild:
-    def test_in_memory_bit_identical(self, session, hs, tied_parquet):
-        legacy = _build(session, hs, tied_parquet, "leg", False)
-        pfirst = _build(session, hs, tied_parquet, "pf", True)
-        _assert_identical_files(legacy, pfirst)
+    def test_in_memory_bit_identical(
+        self, session, hs, tied_parquet, reference_build
+    ):
+        with reference_build():
+            expected = _build(session, hs, tied_parquet, "ref")
+        pfirst = _build(session, hs, tied_parquet, "pf")
+        _assert_identical_files(expected, pfirst)
 
-    def test_lineage_bit_identical(self, session, hs, tied_parquet):
+    def test_lineage_bit_identical(
+        self, session, hs, tied_parquet, reference_build
+    ):
         """Lineage attaches a per-file constant column whose within-tie
         order is exactly what stability protects."""
-        legacy = _build(session, hs, tied_parquet, "legl", False, lineage=True)
-        pfirst = _build(session, hs, tied_parquet, "pfl", True, lineage=True)
-        _assert_identical_files(legacy, pfirst)
+        with reference_build():
+            expected = _build(session, hs, tied_parquet, "refl", lineage=True)
+        pfirst = _build(session, hs, tied_parquet, "pfl", lineage=True)
+        _assert_identical_files(expected, pfirst)
         # lineage survives: every file id of the source is present
         t = pa.concat_tables([pq.read_table(f) for f in pfirst])
         assert len(set(t.column(C.DATA_FILE_NAME_ID).to_pylist())) == 4
 
-    def test_streaming_spill_bit_identical(self, session, hs, tied_parquet):
+    def test_streaming_spill_bit_identical(
+        self, session, hs, tied_parquet, reference_build
+    ):
         """Budget-constrained builds go through the wave/spill/merge loop;
         its per-wave bucketize must partition-first to the same layout."""
         from hyperspace_tpu.indexes.covering_build import (
@@ -107,30 +152,32 @@ class TestDifferentialBuild:
             "parquet",
         )
         budget = int(per_file * 2.5)
-        legacy = _build(session, hs, tied_parquet, "legs", False, budget=budget)
-        pfirst = _build(session, hs, tied_parquet, "pfs", True, budget=budget)
-        _assert_identical_files(legacy, pfirst)
+        with reference_build():
+            expected = _build(session, hs, tied_parquet, "refs", budget=budget)
+        pfirst = _build(session, hs, tied_parquet, "pfs", budget=budget)
+        _assert_identical_files(expected, pfirst)
 
     def test_numpy_leg_bit_identical(self, session, hs, tied_parquet, monkeypatch):
         """HS_NATIVE=0: the pure-numpy twins must reproduce the same
         bytes as the native kernels."""
         from hyperspace_tpu import native
 
-        native_files = _build(session, hs, tied_parquet, "natv", True)
+        native_files = _build(session, hs, tied_parquet, "natv")
         monkeypatch.setenv("HS_NATIVE", "0")
         monkeypatch.setattr(native, "_lib", None)
         monkeypatch.setattr(native, "_load_failed", False)
-        numpy_files = _build(session, hs, tied_parquet, "nump", True)
+        numpy_files = _build(session, hs, tied_parquet, "nump")
         _assert_identical_files(native_files, numpy_files)
 
-    def test_refresh_incremental_bit_identical(self, session, hs, tied_parquet):
+    def test_refresh_incremental_bit_identical(
+        self, session, hs, tied_parquet, reference_build
+    ):
         """The refresh data plane (append + delete compensation) rides
-        the same writers; both paths must land the same new version."""
+        the same writers; it must land the new version the reference
+        tail writes."""
 
-        def run(name, partition_first):
-            files = _build(
-                session, hs, tied_parquet, name, partition_first, lineage=True
-            )
+        def run(name):
+            files = _build(session, hs, tied_parquet, name, lineage=True)
             rng = np.random.default_rng(5)
             extra = pa.table(
                 {
@@ -148,11 +195,12 @@ class TestDifferentialBuild:
             entry = session.index_manager.get_index_log_entry(name)
             return sorted(entry.content.files), files
 
-        legacy, _ = run("rleg", False)
-        pfirst, _ = run("rpf", True)
+        with reference_build():
+            expected, _ = run("rref")
+        pfirst, _ = run("rpf")
         # refresh MERGE appends new files next to the v0 ones; compare
         # only the refreshed version's files (same basenames both legs)
-        _assert_identical_files(legacy, pfirst)
+        _assert_identical_files(expected, pfirst)
 
 
 class TestPartitionedSortPermutation:

@@ -1,14 +1,15 @@
-"""Sharded build/serve tail (``hyperspace.build.shardedTail.enabled``) —
-differential tests on the simulated 8-device CPU mesh.
+"""Sharded build/serve tail — differential tests on the simulated
+8-device CPU mesh.
 
-The contract: with the flag on, each mesh shard runs the post-exchange
-build tail (partition-first sort + bucketed parquet write) and the serve
-tail (prepare + merge-join) over only the buckets it owns
+The contract: on a mesh of more than one device each shard runs the
+post-exchange build tail (partition-first sort + bucketed parquet write)
+and the serve tail (prepare + merge-join) over only the buckets it owns
 (``bucket % D``), concurrently with the other shards — and every output
-is BIT-IDENTICAL to the single-tail path (flag off): same parquet bytes
-per bucket file, same joined rows in the same order. A bucket lives
-wholly inside one shard, so the per-bucket stable sort/merge cannot
-observe the sharding; these tests make that argument mechanical.
+is BIT-IDENTICAL to what a 1-device session (no exchange, the single
+tail) builds and serves: same parquet bytes per bucket file, same joined
+rows in the same order. A bucket lives wholly inside one shard, so the
+per-bucket stable sort/merge cannot observe the sharding; these tests
+make that argument mechanical.
 """
 
 import hashlib
@@ -27,6 +28,12 @@ from hyperspace_tpu.indexes.covering import CoveringIndexConfig
 @pytest.fixture
 def mesh8(session_factory):
     return session_factory(8)
+
+
+@pytest.fixture
+def mesh1(session_factory):
+    """The single tail: one device, over the same index system path."""
+    return session_factory(1)
 
 
 @pytest.fixture
@@ -69,8 +76,7 @@ def _assert_identical_files(files_a, files_b):
         assert _sha(fa) == _sha(fb), f"parquet bytes differ: {fa} vs {fb}"
 
 
-def _build(session, src, name, sharded, budget=0, lineage=False):
-    session.conf.set(C.BUILD_SHARDED_TAIL_ENABLED, sharded)
+def _build(session, src, name, budget=0, lineage=False):
     session.conf.set(C.INDEX_BUILD_MEMORY_BUDGET, budget)
     session.conf.set(C.INDEX_LINEAGE_ENABLED, lineage)
     hs = Hyperspace(session)
@@ -81,20 +87,21 @@ def _build(session, src, name, sharded, budget=0, lineage=False):
 
 
 class TestShardedBuildDifferential:
-    def test_in_memory_bit_identical(self, mesh8, mixed_parquet):
-        on = _build(mesh8, mixed_parquet, "shon", True)
-        off = _build(mesh8, mixed_parquet, "shoff", False)
-        _assert_identical_files(on, off)
-        # the sharded tail actually ran per shard
+    def test_in_memory_bit_identical(self, mesh8, mesh1, mixed_parquet):
         from hyperspace_tpu.indexes.covering_build import (
             last_build_breakdown,
         )
 
-        on2 = _build(mesh8, mixed_parquet, "shon2", True)
+        on = _build(mesh8, mixed_parquet, "shon")
+        # the sharded tail actually ran per shard
         assert last_build_breakdown.get("tail_shards", 0) > 1
+        off = _build(mesh1, mixed_parquet, "shoff")
+        assert "tail_shards" not in last_build_breakdown
+        _assert_identical_files(on, off)
+        on2 = _build(mesh8, mixed_parquet, "shon2")
         _assert_identical_files(on, on2)
 
-    def test_streaming_waves_bit_identical(self, mesh8, mixed_parquet):
+    def test_streaming_waves_bit_identical(self, mesh8, mesh1, mixed_parquet):
         """Budget-capped builds wave/spill/merge; the per-wave sharded
         sort and the per-shard merge fan-out must land the same bytes."""
         from hyperspace_tpu.indexes.covering_build import (
@@ -106,14 +113,14 @@ class TestShardedBuildDifferential:
             [os.path.join(mixed_parquet, first)], "parquet"
         )[0]
         budget = int(per_file * 2.5)
-        on = _build(mesh8, mixed_parquet, "ston", True, budget=budget)
-        off = _build(mesh8, mixed_parquet, "stoff", False, budget=budget)
+        on = _build(mesh8, mixed_parquet, "ston", budget=budget)
+        off = _build(mesh1, mixed_parquet, "stoff", budget=budget)
         _assert_identical_files(on, off)
 
-    def test_refresh_incremental_bit_identical(self, mesh8, mixed_parquet):
-        def run(name, sharded):
-            _build(mesh8, mixed_parquet, name, sharded, lineage=True)
-            hs = Hyperspace(mesh8)
+    def test_refresh_incremental_bit_identical(self, mesh8, mesh1, mixed_parquet):
+        def run(session, name):
+            _build(session, mixed_parquet, name, lineage=True)
+            hs = Hyperspace(session)
             rng = np.random.default_rng(5)
             extra = pa.table(
                 {
@@ -128,21 +135,21 @@ class TestShardedBuildDifferential:
                 mixed_parquet, f"extra-{name}.parquet"
             )
             pq.write_table(extra, extra_path)
-            mesh8.index_manager.clear_cache()
+            session.index_manager.clear_cache()
             hs.refresh_index(name, C.REFRESH_MODE_INCREMENTAL)
             os.remove(extra_path)  # identical source for the next leg
-            mesh8.index_manager.clear_cache()
-            entry = mesh8.index_manager.get_index_log_entry(name)
+            session.index_manager.clear_cache()
+            entry = session.index_manager.get_index_log_entry(name)
             return sorted(entry.content.files)
 
-        on = run("rfon", True)
-        off = run("rfoff", False)
+        on = run(mesh8, "rfon")
+        off = run(mesh1, "rfoff")
         _assert_identical_files(on, off)
 
     def test_cross_mesh_serve(self, session_factory, mixed_parquet):
         """An index built by the sharded tail serves identically from a
         single-device session (layout is mesh-independent)."""
-        _build(session_factory(8), mixed_parquet, "xms", True)
+        _build(session_factory(8), mixed_parquet, "xms")
         server = session_factory(1)
         df = server.read.parquet(mixed_parquet)
         q = lambda d: d.filter(d["k"] == 2).select("k", "s", "v")
@@ -199,14 +206,18 @@ class TestShardedServeDifferential:
     def _q(f, d):
         return f.join(d, on=f["k"] == d["j"]).select("k", "p", "w")
 
-    def test_join_bit_identical(self, mesh8, join_data):
-        f, d = self._indexed(mesh8, *join_data)
-        mesh8.enable_hyperspace()
+    def _served(self, session, fact, dim):
+        """The join, index-served by ``session`` from the indexes on the
+        shared system path."""
+        f, d = session.read.parquet(fact), session.read.parquet(dim)
+        session.enable_hyperspace()
         assert self._q(f, d).explain().count("Hyperspace(Type: CI") == 2
-        mesh8.conf.set(C.BUILD_SHARDED_TAIL_ENABLED, True)
-        on = self._q(f, d).collect()
-        mesh8.conf.set(C.BUILD_SHARDED_TAIL_ENABLED, False)
-        off = self._q(f, d).collect()
+        return self._q(f, d).collect()
+
+    def test_join_bit_identical(self, mesh8, mesh1, join_data):
+        f, d = self._indexed(mesh8, *join_data)
+        on = self._served(mesh8, *join_data)
+        off = self._served(mesh1, *join_data)
         # bit-identical: same rows in the same order, not just same set
         assert on.equals(off)
         mesh8.disable_hyperspace()
@@ -217,7 +228,7 @@ class TestShardedServeDifferential:
         assert key(on).equals(key(base))
         assert on.num_rows > 0
 
-    def test_hybrid_delta_bit_identical(self, mesh8, join_data):
+    def test_hybrid_delta_bit_identical(self, mesh8, mesh1, join_data):
         fact, dim = join_data
         f, d = self._indexed(mesh8, fact, dim)
         pq.write_table(
@@ -230,15 +241,12 @@ class TestShardedServeDifferential:
             ),
             os.path.join(fact, "extra.parquet"),
         )
-        mesh8.conf.set(C.INDEX_HYBRID_SCAN_ENABLED, True)
-        mesh8.index_manager.clear_cache()
+        for session in (mesh8, mesh1):
+            session.conf.set(C.INDEX_HYBRID_SCAN_ENABLED, True)
+            session.index_manager.clear_cache()
         f2 = mesh8.read.parquet(fact)
-        mesh8.enable_hyperspace()
-        assert self._q(f2, d).explain().count("Hyperspace(Type: CI") == 2
-        mesh8.conf.set(C.BUILD_SHARDED_TAIL_ENABLED, True)
-        on = self._q(f2, d).collect()
-        mesh8.conf.set(C.BUILD_SHARDED_TAIL_ENABLED, False)
-        off = self._q(f2, d).collect()
+        on = self._served(mesh8, fact, dim)
+        off = self._served(mesh1, fact, dim)
         assert on.equals(off)
         mesh8.disable_hyperspace()
         base = self._q(f2, d).collect()
@@ -246,6 +254,71 @@ class TestShardedServeDifferential:
             [(c, "ascending") for c in t.column_names]
         )
         assert key(on).equals(key(base))
+
+
+class TestTailChoice:
+    @pytest.mark.parametrize(
+        "devices, keys, sharded",
+        [
+            (1, "spread", False),  # no exchange ran: no shard offsets
+            (8, "one_bucket", False),  # an exchange, ONE occupied shard
+            (8, "spread", True),  # more than one shard holds rows
+        ],
+    )
+    def test_sharded_iff_more_than_one_occupied_shard(
+        self, devices, keys, sharded, session_factory, tmp_path, monkeypatch
+    ):
+        """``bucketize`` and ``write_bucketed`` take the sharded tail
+        exactly when the exchange returned offsets with more than one
+        occupied shard: nothing else is asked."""
+        from hyperspace_tpu.indexes import covering_build
+        from hyperspace_tpu.indexes.context import IndexerContext
+        from hyperspace_tpu.io.columnar import ColumnarBatch
+        from hyperspace_tpu.metadata.entry import FileIdTracker
+        from hyperspace_tpu.ops import sort as sort_ops
+
+        n = 4000
+        rng = np.random.default_rng(devices + len(keys))
+        k = np.full(n, 7) if keys == "one_bucket" else rng.integers(0, 500, n)
+        batch = ColumnarBatch.from_arrow(
+            pa.table({"k": pa.array(k, type=pa.int64()), "v": pa.array(rng.normal(size=n))})
+        )
+        ctx = IndexerContext(
+            session_factory(devices), FileIdTracker(), str(tmp_path / "v__=0")
+        )
+        seen = {"offsets": [], "sharded_sort": 0, "sharded_write": 0}
+        real_shuffle = covering_build._hash_shuffle
+
+        def shuffle(*args):
+            out = real_shuffle(*args)
+            seen["offsets"].append(out[3])
+            return out
+
+        def counting(key, real):
+            def call(*args, **kw):
+                seen[key] += 1
+                return real(*args, **kw)
+
+            return call
+
+        monkeypatch.setattr(covering_build, "_hash_shuffle", shuffle)
+        monkeypatch.setattr(
+            sort_ops, "sharded_sort_permutation",
+            counting("sharded_sort", sort_ops.sharded_sort_permutation),
+        )
+        monkeypatch.setattr(
+            covering_build, "_write_bucketed_sharded",
+            counting("sharded_write", covering_build._write_bucketed_sharded),
+        )
+        buckets, out = covering_build.bucketize(ctx, batch, ["k"], 8)
+        written = covering_build.write_bucketed(ctx, batch, ["k"], 8)
+        assert out.num_rows == n and len(buckets) == n
+        assert sum(pq.read_metadata(f).num_rows for f in written) == n
+        for offs in seen["offsets"]:
+            occupied = 0 if offs is None else np.count_nonzero(np.diff(offs))
+            assert (offs is None) == (devices == 1)
+            assert (occupied > 1) == sharded
+        assert seen["sharded_sort"] == seen["sharded_write"] == int(sharded)
 
 
 class TestShardedSortPermutation:
@@ -305,7 +378,7 @@ class TestSkewTelemetry:
         pq.write_table(t, d / "p0.parquet")
         pq.write_table(t, d / "p1.parquet")
         with caplog.at_level(logging.WARNING, "hyperspace_tpu.shuffle"):
-            _build(mesh8, str(d), "skidx", True)
+            _build(mesh8, str(d), "skidx")
         from hyperspace_tpu.indexes.covering_build import (
             last_build_telemetry,
         )
@@ -321,7 +394,7 @@ class TestSkewTelemetry:
         with caplog.at_level(logging.WARNING, "hyperspace_tpu.shuffle"):
             # 5 keys over 8 buckets is mildly skewed but telemetry must
             # exist either way
-            _build(mesh8, mixed_parquet, "balidx", True)
+            _build(mesh8, mixed_parquet, "balidx")
         from hyperspace_tpu.indexes.covering_build import (
             last_build_telemetry,
         )

@@ -162,11 +162,11 @@ class TestShuffle:
         n, nb = 1003, 16  # deliberately not divisible by 8
         keys = rng.integers(0, 50, (1, n)).astype(np.int64)
         payload = rng.integers(0, 10**9, n).astype(np.int64)
-        # pin the flat strategy: this test exercises the device
+        # pin the compact strategy: this test exercises the device
         # all_to_all itself (auto resolves a CPU mesh to the host-side
         # exchange; tests/test_exchange_strategies.py covers the matrix)
         buckets, (keys_out, payload_out) = bucket_shuffle(
-            mesh, keys, [keys[0], payload], nb, strategy="flat"
+            mesh, keys, [keys[0], payload], nb, strategy="compact"
         )
         # No rows lost or duplicated.
         assert len(buckets) == n
@@ -189,7 +189,7 @@ class TestShuffle:
         keys = np.arange(n, dtype=np.int64)[None, :]
         payload = np.arange(n, dtype=np.int64) * 1000
         _, (k_out, p_out) = bucket_shuffle(
-            mesh, keys, [keys[0], payload], 4, strategy="flat"
+            mesh, keys, [keys[0], payload], 4, strategy="compact"
         )
         np.testing.assert_array_equal(k_out * 1000, p_out)
 
@@ -215,46 +215,3 @@ def test_bucket_ids_host_device_bit_exact():
     assert np.array_equal(host, dev)
 
 
-def test_shuffle_cap_bounds_memory_and_preserves_rows():
-    """The exchange buffer is sized to real traffic: skewed destinations
-    still deliver every row, balanced data gets a cap near n_local/D (not
-    n_local), and padding rows never inflate the cap."""
-    import numpy as np
-
-    from hyperspace_tpu.parallel.mesh import default_mesh
-    from hyperspace_tpu.parallel.shuffle import _exchange_cap, bucket_shuffle
-
-    mesh = default_mesh()
-    D = mesh.devices.size
-    rng = np.random.default_rng(3)
-    n = 4096
-    n_local = n // D
-    valid = np.ones(n, dtype=bool)
-
-    # skew: every row to one destination -> cap == n_local
-    reps = np.zeros((1, n), dtype=np.int64)
-    assert _exchange_cap(reps, valid, D * 4, D, 42) == n_local
-    payload = np.arange(n, dtype=np.int64)
-    buckets, cols = bucket_shuffle(
-        mesh, reps, [reps[0], payload], D * 4, strategy="flat"
-    )
-    assert len(buckets) == n
-    assert sorted(cols[1].tolist()) == list(range(n))
-
-    # balanced: cap well below n_local (~n_local/D padded to pow2)
-    reps = rng.integers(-(2**60), 2**60, size=(1, n), dtype=np.int64)
-    cap = _exchange_cap(reps, valid, D * 4, D, 42)
-    assert cap < n_local // 2, cap
-    buckets, cols = bucket_shuffle(
-        mesh, reps, [reps[0], payload], D * 4, strategy="flat"
-    )
-    assert len(buckets) == n
-    assert sorted(cols[1].tolist()) == list(range(n))
-
-    # padding rows (invalid) do not count toward the cap
-    valid_half = valid.copy()
-    valid_half[n // 2 :] = False
-    reps_pad = reps.copy()
-    reps_pad[:, n // 2 :] = 0  # pads all hash to one dest — must not matter
-    cap_pad = _exchange_cap(reps_pad, valid_half, D * 4, D, 42)
-    assert cap_pad < n_local // 2, cap_pad
