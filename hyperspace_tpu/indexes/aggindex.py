@@ -521,7 +521,7 @@ def _map_files(fn, files: List[str]):
         return [fn(f) for f in files], 1
     from concurrent.futures import ThreadPoolExecutor
 
-    workers = min(16, native._cores(), len(files))
+    workers = min(native.core_budget(), len(files))
     with ThreadPoolExecutor(
         max_workers=workers, thread_name_prefix="hs-aggcapture"
     ) as pool:

@@ -883,8 +883,11 @@ def _write_bucketed_pipelined(
        bucket instead of the whole table, which is what collapsed the
        64M-row global lexsort (BASELINE.md: TLB-bound gathers over
        512MB);
-    3. bucket *i*'s parquet write runs on a writer thread while bucket
-       *i+1* is still sorting.
+    3. bucket *i*'s parquet write is handed to a pool of writers
+       (``_bucket_writers``: the core budget, at most one a non-empty
+       bucket, fewer when a memory budget is set and that many of the
+       largest buckets would not fit it) while bucket *i+1* is still
+       sorting; no two writers ever share a file.
 
     The composed permutation equals the stable lexsort by (bucket,
     keys...) (``ops/sort.sort_permutation``), so each file holds what
@@ -898,7 +901,13 @@ def _write_bucketed_pipelined(
     records only the drain after the last sort — the overlapped portion
     of the writes hides inside the sort stage, which is the point of
     the pipeline — while its ``sum_s``/``max_s`` count every file's
-    seconds on the writer thread, overlapped or not.
+    seconds on its writer's thread, overlapped or not, and ``writers``
+    is the pool's size: ``sum_s / (writers * the stage's seconds)`` is
+    the pool's occupancy, and ``sum_s`` against what the same files
+    take on one writer is what the writers cost each other.
+
+    The first writer that raises surfaces from here; files not yet
+    started are then cancelled, not written after the build has failed.
 
     Datasets beyond the memory budget never reach here; they stream
     through ``_write_bucketed_streaming``'s wave/spill loop, whose
@@ -925,20 +934,27 @@ def _write_bucketed_pipelined(
         if written is not None:
             return written
     sort_s: List[float] = []
-    with ThreadPoolExecutor(max_workers=1) as writer:
-        futures = []
+    futures = []
+    with contextlib.ExitStack() as cleanup:
         with stage("sort"):
             with _obs_trace.span("partition"):
                 order, offsets = partition_by_bucket(buckets, num_buckets)
                 planes = _order_words_np(reps.astype(np.int64, copy=False))
             with _obs_trace.span("to_arrow"):
                 table = batch.to_arrow()
+            writers = _bucket_writers(ctx, table, offsets)
+            pool = ThreadPoolExecutor(
+                max_workers=writers, thread_name_prefix="hs-bucketwrite"
+            )
+            # on the way out after a failure, the files no writer has
+            # started are dropped, not written
+            cleanup.callback(pool.shutdown, cancel_futures=True)
             with _obs_trace.span("bucket_sorts") as sorts_sp:
                 for b, final_idx in bucket_key_sort_runs(
                     planes, order, offsets, seconds_out=sort_s
                 ):
                     futures.append(
-                        writer.submit(
+                        pool.submit(
                             _timed_write_bucket_file,
                             ctx.index_data_path,
                             b,
@@ -949,14 +965,34 @@ def _write_bucketed_pipelined(
                         )
                     )
                 _repeat_attrs(sorts_sp, sort_s, "buckets")
-        with stage("write") as write_sp:
+        with stage("write", writers=writers) as write_sp:
+            # in submission order: ascending bucket id
             done = [f.result() for f in futures]
+            pool.shutdown()  # the writers' way out is the drain's too
             written = [path for path, _s in done]
-            # every file's seconds on the writer thread, those that ran
-            # under the sort stage included
+            # every file's seconds on its writer's thread, those that
+            # ran under the sort stage included
             _repeat_attrs(write_sp, [sec for _p, sec in done], "buckets")
             _count_written(write_sp, written)
     return written
+
+
+def _bucket_writers(ctx, table, offsets: np.ndarray) -> int:
+    """Writers of the pipelined tail's pool: the core budget, at most one
+    a non-empty bucket. A running writer holds one gathered bucket
+    (``table.take``), so with few, large buckets the pool can approach a
+    second copy of the table: under a build memory budget only as many
+    writers as the LARGEST bucket's bytes fit it, the rule the streaming
+    merge has for its concurrent merges."""
+    from hyperspace_tpu import native
+
+    counts = np.diff(offsets)
+    writers = min(native.core_budget(), int(np.count_nonzero(counts)))
+    budget = ctx.session.conf.build_memory_budget
+    if budget and table.num_rows:
+        biggest = int(counts.max()) * table.nbytes // table.num_rows
+        writers = min(writers, int(budget // max(biggest, 1)))
+    return max(1, writers)
 
 
 class _ForeignBuckets(Exception):

@@ -459,12 +459,22 @@ def _cores() -> int:
         return os.cpu_count() or 1
 
 
+def core_budget() -> int:
+    """Threads one stage of a build's tail may keep busy at once: the
+    cores this process may run on, at most 16 — THE budget the per-bucket
+    sorts (``ops/sort._sort_pool_plan``), the shard tails
+    (``ops/sort.shard_tail_plan``), the bucket-file writers
+    (``indexes/covering_build``) and the aggregate capture
+    (``indexes/aggindex._map_files``) each split or take."""
+    return min(_cores(), 16)
+
+
 def _n_threads(n: int) -> int:
     """Thread count scaled to the input: one thread per ~64k rows, capped
     by cores and 16. Just-above-threshold inputs (32k rows) would
     otherwise pay 15 thread spawn/joins per byte pass for ~2k-row chunks
     — more overhead than the whole numpy sort."""
-    return max(1, min(_cores(), 16, n >> 16))
+    return max(1, min(core_budget(), n >> 16))
 
 
 def lexsort_u32(

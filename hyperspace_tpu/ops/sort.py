@@ -209,7 +209,7 @@ def _sort_pool_plan(n_buckets: int) -> tuple[int, int]:
     across concurrent per-bucket sorts."""
     from hyperspace_tpu import native
 
-    budget = max(1, min(native._cores(), 16))
+    budget = native.core_budget()
     workers = max(1, min(budget, n_buckets))
     return workers, max(1, budget // workers)
 
@@ -306,7 +306,7 @@ def shard_tail_plan(shard_offsets: np.ndarray) -> tuple[list, int]:
         for s in range(len(shard_offsets) - 1)
         if shard_offsets[s + 1] > shard_offsets[s]
     ]
-    budget = max(1, min(native._cores(), 16))
+    budget = native.core_budget()
     return shards, max(1, budget // max(len(shards), 1))
 
 

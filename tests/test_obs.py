@@ -655,6 +655,24 @@ class TestActionAccount:
             assert sp.attrs["max_s"] <= sp.attrs["sum_s"] + 1e-9
         assert by_name["write"].attrs["files"] == root.attrs["index_files"]
 
+    def test_write_span_names_its_writers(
+        self, session_factory, tmp_path, monkeypatch
+    ):
+        """Still ONE ``write`` span, whatever pool the files went
+        through: the pool's size is an attr beside the files' seconds
+        summed over its threads."""
+        from hyperspace_tpu import native
+
+        monkeypatch.setattr(native, "core_budget", lambda: 5)
+        _build(session_factory, tmp_path, warm=False, buckets=200)
+        root = trace.finished("action.CreateAction")[-1]
+        (write,) = [sp for sp in root.spans if sp.name == "write"]
+        assert write.parent_id == root.span_id
+        assert write.attrs["writers"] == 5 <= write.attrs["buckets"]
+        assert write.attrs["buckets"] == write.attrs["files"]
+        assert 0.0 < write.attrs["max_s"] <= write.attrs["sum_s"] + 1e-9
+        assert not [sp for sp in root.spans if sp.parent_id == write.span_id]
+
     def test_self_seconds_on_a_hand_built_tree(self):
         """Overlapping children count once; a summed span has no
         interval and is left out of every union and self time."""

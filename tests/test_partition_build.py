@@ -37,19 +37,16 @@ def hs(session):
     return Hyperspace(session)
 
 
-@pytest.fixture
-def tied_parquet(tmp_path):
-    """4 files whose keys collide heavily (3 distinct values per column)
-    — long tie runs across files, the stability torture case — plus a
-    string column and a float payload."""
+def _tied_files(d, distinct_keys):
     rng = np.random.default_rng(21)
-    d = tmp_path / "tied"
     d.mkdir()
     for i in range(4):
         n = 3000
         t = pa.table(
             {
-                "k": pa.array(rng.integers(0, 3, n), type=pa.int64()),
+                "k": pa.array(
+                    rng.integers(0, distinct_keys, n), type=pa.int64()
+                ),
                 "s": pa.array(
                     [["aa", "bb", "cc"][v] for v in rng.integers(0, 3, n)]
                 ),
@@ -58,6 +55,21 @@ def tied_parquet(tmp_path):
         )
         pq.write_table(t, d / f"part-{i}.parquet")
     return str(d)
+
+
+@pytest.fixture
+def tied_parquet(tmp_path):
+    """4 files whose keys collide heavily (3 distinct values per column)
+    — long tie runs across files, the stability torture case — plus a
+    string column and a float payload."""
+    return _tied_files(tmp_path / "tied", 3)
+
+
+@pytest.fixture
+def spread_parquet(tmp_path):
+    """The same, over 60 keys: ties as long as 200 rows, and a file in
+    every one of a session's buckets."""
+    return _tied_files(tmp_path / "spread", 60)
 
 
 def _sha(path):
@@ -264,3 +276,181 @@ class TestPartitionByBucket:
         without = partition_by_bucket(bids, 8)
         np.testing.assert_array_equal(with_native[0], without[0])
         np.testing.assert_array_equal(with_native[1], without[1])
+
+
+class _Sized:
+    """What ``_bucket_writers`` reads of an arrow table."""
+
+    def __init__(self, num_rows, bytes_per_row):
+        self.num_rows, self.nbytes = num_rows, num_rows * bytes_per_row
+
+
+def _ctx_with_budget(budget):
+    from types import SimpleNamespace as NS
+
+    return NS(session=NS(conf=NS(build_memory_budget=budget)))
+
+
+class TestBucketWriters:
+    """The pipelined tail's bucket files go through a pool of writers
+    sized from what the build can see — cores, non-empty buckets, the
+    memory budget — and come out as from one writer: same names, same
+    order, same bytes."""
+
+    @pytest.mark.parametrize(
+        "cores,rows,buckets,budget,want",
+        [
+            (13, 16_000_000, 200, 0, 13),   # the benchmark's one-chip host
+            (30, 16_000_000, 200, 0, 16),   # the core budget's cap
+            (8, 16_000_000, 3, 0, 3),       # never more than files
+            (13, 16_000, 200, 0, 13),       # a refresh's few-KB files too
+            (13, 0, 200, 0, 1),             # no rows: no file, one idle writer
+            # 4 buckets of 4M rows x 28 B = 112 MB each
+            (13, 16_000_000, 4, 250_000_000, 2),
+            (13, 16_000_000, 4, 223_999_999, 1),  # two do not fit
+            (13, 16_000_000, 4, 1, 1),
+        ],
+    )
+    def test_pool_size_rule(
+        self, monkeypatch, cores, rows, buckets, budget, want
+    ):
+        from hyperspace_tpu import native
+
+        monkeypatch.setattr(native, "_cores", lambda: cores)
+        offsets = np.linspace(0, rows, buckets + 1).astype(np.int64)
+        got = covering_build._bucket_writers(
+            _ctx_with_budget(budget), _Sized(rows, 28), offsets
+        )
+        assert got == want
+
+    def test_empty_buckets_take_no_writer(self, monkeypatch):
+        from hyperspace_tpu import native
+
+        monkeypatch.setattr(native, "_cores", lambda: 8)
+        offsets = np.array([0, 0, 500_000, 500_000, 1_000_000], np.int64)
+        assert covering_build._bucket_writers(
+            _ctx_with_budget(0), _Sized(1_000_000, 28), offsets
+        ) == 2
+
+    @pytest.fixture
+    def spied_tail(self, monkeypatch):
+        """The list ``_write_bucketed_pipelined`` returned, as returned."""
+        returned = []
+        real = covering_build._write_bucketed_pipelined
+
+        def spy(*a, **k):
+            out = real(*a, **k)
+            returned.append(list(out))
+            return out
+
+        monkeypatch.setattr(covering_build, "_write_bucketed_pipelined", spy)
+        return returned
+
+    @pytest.mark.parametrize("writers", [1, 8])
+    @pytest.mark.parametrize("source", ["tied_parquet", "spread_parquet"])
+    def test_files_do_not_depend_on_the_pool(
+        self, session_factory, request, reference_build, spied_tail,
+        monkeypatch, source, writers,
+    ):
+        from hyperspace_tpu import native
+        from hyperspace_tpu.obs import trace
+
+        src = request.getfixturevalue(source)
+        session = session_factory(1)
+        session.conf.set(C.INDEX_NUM_BUCKETS, 24)
+        hs = Hyperspace(session)
+        with reference_build():
+            expected = _build(session, hs, src, f"ref{writers}")
+        assert spied_tail == []  # the reference took the tail's place
+        monkeypatch.setattr(native, "core_budget", lambda: writers)
+        got = _build(session, hs, src, f"pool{writers}")
+        _assert_identical_files(expected, got)
+        assert len(got) == (3 if source == "tied_parquet" else 24)
+        # as returned: one file a bucket, in ascending bucket id
+        (returned,) = spied_tail
+        ids = [pio.bucket_id_of_file(f) for f in returned]
+        assert ids == sorted(set(ids)) and sorted(returned) == got
+        root = trace.finished("action.CreateAction")[-1]
+        (write,) = [sp for sp in root.spans if sp.name == "write"]
+        assert write.attrs["writers"] == min(writers, len(got))
+
+    def test_memory_budget_narrows_the_pool(
+        self, session_factory, tmp_path, monkeypatch
+    ):
+        """Few, large buckets: a writer holds one gathered bucket, so
+        under a budget that two of the largest do not fit there is one
+        writer; with no budget, one a non-empty bucket."""
+        from hyperspace_tpu import native
+        from hyperspace_tpu.obs import trace
+
+        rng = np.random.default_rng(8)
+        n = 20_000
+        d = tmp_path / "skewed"
+        d.mkdir()
+        k = np.where(rng.random(n) < 0.7, 1, rng.integers(2, 5, n))
+        pq.write_table(
+            pa.table({"k": pa.array(k, pa.int64()),
+                      "s": pa.array(["x"] * n),
+                      "v": pa.array(rng.normal(size=n))}),
+            d / "part-0.parquet",
+        )
+        monkeypatch.setattr(native, "core_budget", lambda: 8)
+        session = session_factory(1)
+        hs = Hyperspace(session)
+
+        def writers_of(name, budget):
+            files = _build(session, hs, str(d), name, budget=budget)
+            root = trace.finished("action.CreateAction")[-1]
+            # the whole table fit the budget: no wave, no spill
+            assert "waves" not in covering_build.last_build_telemetry
+            (write,) = [sp for sp in root.spans if sp.name == "write"]
+            return write.attrs["writers"], len(files)
+
+        budget = covering_build.estimated_materialized_bytes(
+            [str(d / "part-0.parquet")], "parquet"
+        )
+        assert writers_of("tight", budget) == (1, 4)
+        assert writers_of("free", 0) == (4, 4)
+
+    def test_crash_at_the_second_file_fails_the_build(
+        self, session_factory, spread_parquet, monkeypatch
+    ):
+        """The first writer that raises surfaces from ``create_index``;
+        the file it died on is never written, and the recovery path
+        rolls the stranded create back."""
+        import time
+
+        from hyperspace_tpu import native
+        from hyperspace_tpu.constants import States
+        from hyperspace_tpu.metadata import recovery
+        from hyperspace_tpu.testing import faults
+        from hyperspace_tpu.testing.faults import SimulatedCrash
+
+        session = session_factory(1)
+        session.conf.set(C.RECOVERY_LEASE_MS, 40)
+        session.conf.set(C.RECOVERY_ORPHAN_GRACE_MS, 0)
+        hs = Hyperspace(session)
+        monkeypatch.setattr(native, "core_budget", lambda: 4)
+        faults.reset()
+        faults.set_crash("mid_data_write", "raise;at=2")
+        try:
+            with pytest.raises(SimulatedCrash):
+                _build(session, hs, spread_parquet, "dies")
+            assert faults.stats() == {"crash.mid_data_write": 1}
+        finally:
+            faults.reset()
+        log_mgr, _ = session.index_manager._managers("dies")
+        assert log_mgr.get_latest_log().state == States.CREATING
+        written = [
+            f for _d, _s, fs in os.walk(log_mgr.index_path) for f in fs
+            if pio.bucket_id_of_file(f) is not None
+        ]
+        assert 1 <= len(written) < session.conf.num_buckets
+        time.sleep(0.1)  # past the dead writer's lease
+        assert hs.recover("dies")["rolled_back"]
+        assert log_mgr.get_latest_log().state == States.DOESNOTEXIST
+        assert recovery.find_orphans(log_mgr.index_path) == []
+        # the name is free again, and the build completes
+        session.index_manager.clear_cache()
+        files = _build(session, hs, spread_parquet, "dies")
+        assert len(files) == session.conf.num_buckets
