@@ -21,9 +21,10 @@ are whole), identifiers 8-byte integers, dates date32. The table is cut
 into ``n_files`` parquet files of equal row counts (dbgen's ``-C``). The
 same seed gives the same bytes.
 
-The four columns the index holds are also returned as numpy arrays: they
-are the input of the plain reference (``reference.py``), which never
-sees anything the program wrote.
+The columns a cell's index holds are also returned as numpy arrays, by
+name (``cols``; any of the eleven numeric and date columns): they are
+the input of the plain reference (``reference.py``), which never sees
+anything the program wrote.
 """
 
 from __future__ import annotations
@@ -42,6 +43,8 @@ LINEITEM_COLS = (
     "l_shipdate", "l_commitdate", "l_receiptdate", "l_shipinstruct",
     "l_shipmode", "l_comment",
 )
+# the columns that are numbers or dates: what a reference can be handed
+NUMPY_COLS = LINEITEM_COLS[:8] + LINEITEM_COLS[10:13]
 ITEMS_PER_ORDER = 4          # the mean of 1..7
 
 _EPOCH = np.datetime64("1970-01-01")
@@ -98,9 +101,13 @@ def _choice(values: tuple, picks: np.ndarray) -> pa.Array:
         pa.array(picks, type=pa.int8()), pa.array(values)).cast(pa.string())
 
 
-def gen_lineitem(tmp: str, n_orders: int, n_files: int, seed: int):
-    """Write LINEITEM under ``tmp`` -> (directory, the index's four
-    columns as numpy, dates as int32 days since the epoch)."""
+def gen_lineitem(tmp: str, n_orders: int, n_files: int, seed: int, cols=ITEM_COLS):
+    """Write LINEITEM under ``tmp`` -> (directory, the columns named by
+    ``cols`` as numpy, dates as int32 days since the epoch). What is
+    written does not depend on ``cols``."""
+    unknown = [c for c in cols if c not in NUMPY_COLS]
+    if unknown:
+        raise ValueError(f"no numpy form of LINEITEM column(s) {unknown}: one of {NUMPY_COLS}")
     rng = np.random.default_rng(seed)
     items_dir = os.path.join(tmp, "lineitem")
     os.makedirs(items_dir)
@@ -157,6 +164,7 @@ def gen_lineitem(tmp: str, n_orders: int, n_files: int, seed: int):
 
     with ThreadPoolExecutor(max_workers=min(n_files, 8)) as pool_:
         list(pool_.map(write, range(n_files)))
-    cols = {"l_orderkey": l_orderkey, "l_shipdate": l_shipdate,
-            "l_quantity": l_quantity, "l_extendedprice": l_extendedprice}
-    return items_dir, cols
+    made = dict(zip(NUMPY_COLS, (
+        l_orderkey, l_partkey, l_suppkey, l_linenumber, l_quantity, l_extendedprice,
+        l_discount, l_tax, l_shipdate, l_commitdate, l_receiptdate)))
+    return items_dir, {c: made[c] for c in cols}

@@ -12,6 +12,7 @@ bucket files are opened, both against the plain reference.
 from __future__ import annotations
 
 import os
+import resource
 import time
 import traceback
 
@@ -41,6 +42,15 @@ def _cpu_lost_s() -> tuple:
         return 0.0, 0.0
 
 
+def _process_cost() -> tuple:
+    """(user seconds, system seconds, major page faults) of this process so
+    far: printed per build beside the above. A stalled build that burnt no
+    more CPU than its neighbours waited; one with major faults read from
+    the disk what the page cache had held."""
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    return ru.ru_utime, ru.ru_stime, ru.ru_majflt
+
+
 def _drop(ctx) -> None:
     name = ctx.config["index"]["name"]
     ctx.hs.delete_index(name)
@@ -66,6 +76,7 @@ def window(ctx, seconds: float) -> dict:
     while True:
         t = time.perf_counter()
         steal0, iowait0 = _cpu_lost_s()
+        user0, sys0, majflt0 = _process_cost()
         try:
             with ctx.span("bench.create_index"):
                 ctx.hs.create_index(items, cfg)
@@ -74,12 +85,15 @@ def window(ctx, seconds: float) -> dict:
             failed += 1
             break
         end = time.perf_counter() - t0
+        steal1, iowait1 = _cpu_lost_s()
+        user1, sys1, majflt1 = _process_cost()
         ops.append({
             "kind": "build", "wall_s": time.perf_counter() - t,
             "breakdown": dict(covering_build.last_build_breakdown),
             "telemetry": dict(covering_build.last_build_telemetry),
-            "cpu_steal_s": _cpu_lost_s()[0] - steal0,
-            "cpu_iowait_s": _cpu_lost_s()[1] - iowait0,
+            "cpu_steal_s": steal1 - steal0, "cpu_iowait_s": iowait1 - iowait0,
+            "cpu_user_s": user1 - user0, "cpu_sys_s": sys1 - sys0,
+            "major_faults": majflt1 - majflt0,
         })
         if end >= seconds:
             break
@@ -97,6 +111,9 @@ def window(ctx, seconds: float) -> dict:
                      "drop_s": [round(o["drop_s"], 3) for o in ops if "drop_s" in o],
                      "cpu_steal_s": [round(o["cpu_steal_s"], 2) for o in ops],
                      "cpu_iowait_s": [round(o["cpu_iowait_s"], 2) for o in ops],
+                     "cpu_user_s": [round(o["cpu_user_s"], 2) for o in ops],
+                     "cpu_sys_s": [round(o["cpu_sys_s"], 2) for o in ops],
+                     "major_faults": [o["major_faults"] for o in ops],
                      "per_build_stage_s": [
                          {k: round(v, 3) for k, v in o["breakdown"].items()} for o in ops],
                      "stage_s": mean_stages(ops)},

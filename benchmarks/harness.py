@@ -3,12 +3,16 @@
 Everything that belongs to one configuration, one traffic mix or one
 per-layer metric is a file found by the name ``BENCHMARK.json`` gives:
 
-    configs/<config>.json          the deployment as it is run
+    configs/<config>.json          the deployment as it is run; ``index.kind``
+                                   names the index (``INDEX_KINDS``), ``indexed``
+                                   + ``included`` the reference's columns
     traffic/<traffic>.json         ``driver`` and its parameters
     drivers/<driver>.py            the one general generator of a kind of traffic
     layer_metrics/<metric>.json    ``reader`` and its argument (unit, layer,
                                    moves and cells are BENCHMARK.json's alone)
     readers/<reader>.py            from spans, counters or the trace to a number
+    tests/faults/<driver>.py       how that driver's timed path is broken, and
+                                   which compared numbers must and may see it
 
 A later PR adds files and edits none.
 """
@@ -70,6 +74,22 @@ def metrics_of(manifest: dict, group: str, workload: str) -> list:
         m for m in manifest[group]
         if "workloads" not in m or workload in m["workloads"]
     ]
+
+
+# index.kind of a configuration -> (module, config class, the abbreviation
+# ``explain()`` prints for it); each class takes (name, indexed, included)
+INDEX_KINDS = {
+    "covering": ("hyperspace_tpu.indexes.covering", "CoveringIndexConfig", "CI"),
+    "zorder": ("hyperspace_tpu.indexes.zorder", "ZOrderCoveringIndexConfig", "ZOCI"),
+}
+
+
+def index_kind(config: dict) -> tuple:
+    kind = config["index"]["kind"]
+    if kind not in INDEX_KINDS:
+        raise SystemExit(f"bench: config {config['name']!r} states index.kind {kind!r}; "
+                         f"the harness builds {sorted(INDEX_KINDS)}")
+    return INDEX_KINDS[kind]
 
 
 def load_driver(name: str):
@@ -138,10 +158,13 @@ class Ctx:
         return self.session.read.parquet(self.items_dir)
 
     def index_config(self):
-        from hyperspace_tpu.indexes.covering import CoveringIndexConfig
-
+        module, cls, _abbr = index_kind(self.config)
         ix = self.config["index"]
-        return CoveringIndexConfig(ix["name"], ix["indexed"], ix["included"])
+        return getattr(importlib.import_module(module), cls)(
+            ix["name"], ix["indexed"], ix["included"])
+
+    def index_abbr(self) -> str:
+        return index_kind(self.config)[2]
 
 
 def device_info() -> dict:
@@ -170,6 +193,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     cell = find_cell(manifest, workload)
     config = config_of(manifest, cell)
     traffic = traffic_of(cell)
+    index_kind(config)      # an unknown kind is refused before anything is made
     rehearsal = rehearsal_rows > 0
 
     device = device_info()
@@ -217,7 +241,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         log(f"set-up: native + probe {time.time() - t0:.1f}s; thresholds {thresholds}")
         t0 = time.time()
         ctx.items_dir, ctx.items_cols = datagen.gen_lineitem(
-            tmp, n_orders, int(config["files_per_table"]), seed)
+            tmp, n_orders, int(config["files_per_table"]), seed,
+            cols=config["index"]["indexed"] + config["index"]["included"])
         log(f"set-up: data {time.time() - t0:.1f}s")
 
         session = HyperspaceSession()
@@ -225,8 +250,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         session.conf.set(C.INDEX_SYSTEM_PATH, ctx.index_root)
         for key, value in config.get("conf", {}).items():
             session.conf.set(key, value)
-        stated = config["index"]["num_buckets"]
-        if session.conf.num_buckets != stated:
+        stated = config["index"].get("num_buckets")     # a kind without buckets states none
+        if stated is not None and session.conf.num_buckets != stated:
             raise RuntimeError(f"config states {stated} buckets, the session "
                                f"runs {session.conf.num_buckets}")
         ctx.session, ctx.hs = session, Hyperspace(session)

@@ -25,36 +25,37 @@ from concurrent.futures import ThreadPoolExecutor
 import numpy as np
 
 import reference
-from datagen import ITEM_COLS
 
 _VERSION_DIR = re.compile(r"v__=(\d+)$")
 _BUCKET_FILE = re.compile(r"bucket_(\d+)\.parquet$")
 
 
-def served(df, index_name: str) -> bool:
-    """``explain()`` names the covering index ``index_name``."""
+def served(df, index_name: str, abbr: str = "CI") -> bool:
+    """``explain()`` names the index ``index_name`` of the kind that
+    prints as ``abbr`` (``CI`` covering, ``ZOCI`` z-order covering)."""
     plan = df.explain()
-    return "Hyperspace(Type: CI" in plan and f"Name: {index_name}" in plan
+    return f"Hyperspace(Type: {abbr}," in plan and f"Name: {index_name}" in plan
 
 
-def point_query(items, key: int):
-    """``lineitem WHERE l_orderkey = k`` selecting the index's four
-    columns: the float payload has to come back bit for bit."""
-    return items.filter(items["l_orderkey"] == key).select(*ITEM_COLS)
+def point_query(items, key_col: str, key: int, cols):
+    """``lineitem WHERE <key_col> = k`` selecting the index's columns:
+    a float payload has to come back bit for bit."""
+    return items.filter(items[key_col] == key).select(*cols)
 
 
 def point_answers_wrong(index: reference.KeyIndex, keys, answers, transform=None) -> int:
     """Point answers (pyarrow tables, in the order of ``keys``) that differ
-    from the reference. ``transform`` puts a control's answers in the
-    program's place."""
+    from the reference, over all of the reference's columns.
+    ``transform`` puts a control's answers in the program's place."""
     import pyarrow as pa
 
-    want_cols, want_counts = reference.ref_point(index, keys, ITEM_COLS)
+    cols = list(index.cols)
+    want_cols, want_counts = reference.ref_point(index, keys, cols)
     want = reference.segment_digests(want_cols, want_counts)
     if transform is not None:
         got = reference.segment_digests(transform(want_cols), want_counts)
     else:
-        tables = [t.select(list(ITEM_COLS)) for t in answers]
+        tables = [t.select(cols) for t in answers]
         counts = np.array([t.num_rows for t in tables], dtype=np.int64)
         got = reference.segment_digests(
             reference.table_cols(pa.concat_tables(tables)), counts)
@@ -62,9 +63,12 @@ def point_answers_wrong(index: reference.KeyIndex, keys, answers, transform=None
 
 
 def readback(ctx, want_cols: dict, point_keys, transform=None) -> dict:
-    """-> the numbers compared, each with its limit."""
-    index_name = ctx.config["index"]["name"]
-    index = reference.KeyIndex(want_cols, "l_orderkey")
+    """-> the numbers compared, each with its limit. ``want_cols`` are
+    the reference's columns (the configuration's indexed + included);
+    the key of the lookups is the first indexed column."""
+    index_name, abbr = ctx.config["index"]["name"], ctx.index_abbr()
+    key_col, cols = ctx.config["index"]["indexed"][0], list(want_cols)
+    index = reference.KeyIndex(want_cols, key_col)
     want = reference.digest(want_cols)
     keys = [int(k) for k in point_keys]
     answers, unserved = None, 0
@@ -73,13 +77,13 @@ def readback(ctx, want_cols: dict, point_keys, transform=None) -> dict:
     else:
         ctx.session.enable_hyperspace()
         items = ctx.read_items()
-        every = items.filter(items["l_orderkey"] >= 0).select(*ITEM_COLS)
-        unserved = int(not served(every, index_name))
+        every = items.filter(items[key_col] >= 0).select(*cols)
+        unserved = int(not served(every, index_name, abbr))
         got = reference.digest(reference.table_cols(every.collect()))
         answers = []
         for k in keys:
-            q = point_query(items, k)
-            unserved += int(not served(q, index_name))
+            q = point_query(items, key_col, k, cols)
+            unserved += int(not served(q, index_name, abbr))
             answers.append(q.collect())
     return {
         "readback_rows_gap": {"value": abs(got[0] - want[0]), "limit": 0},

@@ -9,8 +9,7 @@ driver names it>} and one of
                                   ``summed`` have no interval and are left out)
   {"unattributed_share": true} -> 100 x (sum of the operations' wall time -
                                   sum of the union of each root's direct
-                                  children) / sum of the wall time, in %:
-                                  the denominator of ``build_unnamed_share``
+                                  children) / sum of the wall time, in %
   {"counter": name}            -> mean per operation of that root attribute
 
 The last N roots of that name are read, N = the window's operations of

@@ -17,7 +17,6 @@ import roofline_exchange
 import trace_reduce
 
 MODULES = {
-    "flat": "jit__flat_program",
     "compact": "jit__compact_program",
     "twostage": "jit__twostage_program",
 }

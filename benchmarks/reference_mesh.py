@@ -4,9 +4,7 @@ many rows each chip has to send to each other chip.
 A build over ``chips`` chips cuts the table, in row order, into ``chips``
 equal contiguous blocks (the last one shorter when the rows do not
 divide), one a chip; bucket ``b`` is exchanged to, sorted by and written
-from chip ``b mod chips``. An exchange that pads the table first (the
-``flat`` strategy: to a power of two) cuts the padded length, so its
-blocks are longer and its last chips hold fewer rows: ``block`` says so.
+from chip ``b mod chips``.
 Everything here follows from ``reference.bucket_of`` alone; nothing of
 ``hyperspace_tpu`` is imported.
 """
@@ -23,18 +21,18 @@ def owner_of(bucket, chips: int):
     return np.asarray(bucket) % chips
 
 
-def source_of(n_rows: int, chips: int, block: int = 0) -> np.ndarray:
+def source_of(n_rows: int, chips: int) -> np.ndarray:
     """The chip that holds each row before the exchange: contiguous
-    blocks of ``block`` rows, ceil(n_rows / chips) unless given."""
-    block = block or -(-n_rows // chips) or 1
+    blocks of ceil(n_rows / chips) rows."""
+    block = -(-n_rows // chips) or 1
     return np.arange(n_rows, dtype=np.int64) // block
 
 
-def peer_matrix(keys, num_buckets: int, chips: int, block: int = 0) -> np.ndarray:
+def peer_matrix(keys, num_buckets: int, chips: int) -> np.ndarray:
     """[chips, chips] rows that source chip i holds and chip j owns."""
     keys = np.asarray(keys)
     owner = owner_of(reference.bucket_of(keys, num_buckets), chips)
-    flat = source_of(len(keys), chips, block) * chips + owner
+    flat = source_of(len(keys), chips) * chips + owner
     return np.bincount(flat, minlength=chips * chips).reshape(chips, chips)
 
 

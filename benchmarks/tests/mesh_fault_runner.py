@@ -10,9 +10,9 @@ build calls the exchange (``parallel/shuffle.bucket_shuffle``):
   none                nothing broken
 
 With ``--cpu-rehearsal --rows N`` among the arguments it runs here at a
-tiny size, and forces the ``compact`` strategy through the session's
-conf (``auto`` on a CPU mesh is ``host``, which crosses no chip);
-without them, on the chips at the cell's own size, under ``auto``.
+tiny size, and has ``shuffle.resolve_strategy`` answer ``compact``
+(``auto`` on a CPU mesh is ``host``, which crosses no chip); without
+them, on the chips at the cell's own size, under ``auto``.
 """
 
 import os
@@ -65,15 +65,9 @@ def _swap_peers(chips, buckets, cols, offsets, source):
 
 def plant(fault: str, force_compact: bool) -> None:
     if force_compact:
-        from hyperspace_tpu import constants as C
-        from hyperspace_tpu import session as session_mod
+        from hyperspace_tpu.parallel import shuffle
 
-        class CompactSession(session_mod.HyperspaceSession):
-            def __init__(self, *args, **kw):
-                super().__init__(*args, **kw)
-                self.conf.set(C.BUILD_EXCHANGE_STRATEGY, "compact")
-
-        session_mod.HyperspaceSession = CompactSession
+        shuffle.resolve_strategy = lambda strategy, mesh: shuffle.STRATEGY_COMPACT
     if fault == "peer_rows_dropped":
         _exchange_then(_drop_peer_rows)
     elif fault == "peers_swapped":

@@ -127,11 +127,15 @@ def test_traced_rehearsal_reports_the_build_account():
     line = json.loads(p.stdout.strip().splitlines()[-1])
     assert line["correct"] is True
     got = line["metrics"]
-    for name in ("build_sidecar_s", "build_log_s", "build_unattributed_share"):
+    for name in ("build_sidecar_s", "build_log_s", "build_unattributed_share",
+                 "build_scan_s", "build_partition_s", "hash_key_reps_s"):
         assert name in got, sorted(got)
-    assert got["build_sidecar_s"]["value"] > 0 and got["build_log_s"]["value"] > 0
-    assert 0 <= got["build_unattributed_share"]["value"] < got["build_unnamed_share"]["value"]
-    # 12,000 rows hash on the host: no device round trip to read
+        assert got[name]["value"] > 0, name
+    assert got["build_unattributed_share"]["value"] < 10 and "build_unnamed_share" not in got
+    # 12,000 rows hash on the host: no device round trip to read, no words to split
     assert "hash_transfer_s" not in got and "hash_d2h_bytes.build" not in got
+    assert "hash_split_words_s" not in got
+    gaps = dict(line["breakdown"]["idle_gaps"])
+    assert {"hs.scan", "hs.sidecar_capture"} <= set(gaps), sorted(gaps)
     assert "bench: spans: action.CreateAction" in p.stderr
     assert any("host_hash" in ln for ln in p.stderr.splitlines() if "bench: spans:" in ln)

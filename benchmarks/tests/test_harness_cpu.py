@@ -14,38 +14,20 @@ import pytest
 
 from conftest import BENCH, HERE, ROOT
 
-with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
-    MANIFEST = json.load(_f)
-WORKLOADS = MANIFEST["workloads"]
-CELLS = [c["name"] for c in WORKLOADS]
+import faults
+import harness
+
+MANIFEST = harness.load_manifest(ROOT)
+CELLS = [c["name"] for c in MANIFEST["workloads"]]
 ROWS = "12000"
-
-# which faults a cell's traffic can have: an answer altered where it is
-# produced, a row in the wrong bucket file, a bucket file left unsorted
-FAULTS = {
-    "build_loop": ["altered_value", "misbucketed", "unsorted"],
-}
-
-
-# the numbers that may see each fault, and no other may
-CAUGHT_BY = {
-    "altered_value": {"readback_digest_differs", "point_answers_wrong"},
-    "misbucketed": {"misbucketed_rows"},
-    "unsorted": {"unsorted_bucket_files"},
-}
 
 
 def _driver(cell: str) -> str:
-    traffic = next(c["traffic"] for c in WORKLOADS if c["name"] == cell)
-    with open(os.path.join(BENCH, "traffic", traffic + ".json")) as f:
-        return json.load(f)["driver"]
+    return harness.traffic_of(harness.find_cell(MANIFEST, cell))["driver"]
 
 
-def _faults():
-    out = []
-    for c in WORKLOADS:
-        out += [(c["name"], f) for f in FAULTS[_driver(c["name"])]]
-    return out
+# every cell under every fault its driver declares (faults/<driver>.py)
+FAULTS = {cell: faults.of(_driver(cell)) for cell in CELLS}
 
 
 def _run(cell: str, fault: str = None, trace: int = 0, seed: int = 2**31 + 11, controls: int = 0):
@@ -84,12 +66,20 @@ def test_end_to_end_line_has_exactly_the_cells_metrics():
     assert all(m["value"] > 0 for m in line["metrics"].values())
 
 
-@pytest.mark.parametrize("cell,fault", _faults())
+@pytest.mark.parametrize("cell", CELLS)
+def test_the_cells_driver_declares_a_fault(cell):
+    assert FAULTS[cell], (
+        f"driver {_driver(cell)!r} of cell {cell!r} declares no fault "
+        f"(benchmarks/tests/faults/{_driver(cell)}.py): a cell whose check nothing can fail decides nothing")
+
+
+@pytest.mark.parametrize("cell,fault", [(c, f) for c in CELLS for f in FAULTS[c]])
 def test_a_broken_program_comes_out_not_correct(cell, fault):
     line, err = _run(cell, fault=fault)
     assert line["correct"] is False, (line["checks"], err[-1500:])
     failed = {k for k, c in line["checks"].items() if c["value"] > c["limit"]}
-    assert failed and failed <= CAUGHT_BY[fault], failed
+    _plant, must, may = FAULTS[cell][fault]
+    assert must <= failed <= must | may, failed
 
 
 def test_the_runner_without_a_fault_is_correct():

@@ -30,6 +30,13 @@ def test_same_seed_same_bytes_and_every_seed_the_same_shapes(tables, tmp_path):
     assert tuple(on_disk.column_names) == datagen.LINEITEM_COLS
     four = reference.table_cols(on_disk.select(list(datagen.ITEM_COLS)))
     assert reference.digest(four) == reference.digest(items)
+    # asked for by name, each numeric or date column is the one the file holds
+    _d, eleven = datagen.gen_lineitem(str(tmp_path / "c"), N_ORDERS, 4, 2**31 + 7, cols=datagen.NUMPY_COLS)
+    assert tuple(eleven) == datagen.NUMPY_COLS
+    for name in datagen.NUMPY_COLS:
+        assert np.array_equal(reference.table_cols(on_disk.select([name]))[name], eleven[name]), name
+    with pytest.raises(ValueError, match="l_comment"):
+        datagen.gen_lineitem(str(tmp_path / "d"), N_ORDERS, 4, 1, cols=["l_comment"])
     _d, other = datagen.gen_lineitem(str(tmp_path / "b"), N_ORDERS, 4, 12345)
     assert len(other["l_orderkey"]) == len(items["l_orderkey"]) == 4 * N_ORDERS
     assert not np.array_equal(other["l_orderkey"], items["l_orderkey"])

@@ -19,7 +19,6 @@ def test_interval_arithmetic():
     u = tr.union([[5, 7], [0, 2], [1, 3], [7, 8], [9, 9]])
     assert u == [[0, 3], [5, 8]] and tr.total(u) == 6
     assert tr.complement(u, 0, 10) == [[3, 5], [8, 10]]
-    assert tr.overlap(u, [[2, 6], [7, 20]]) == 3
 
 
 def test_busy_is_a_union_not_a_sum(trace):
@@ -40,9 +39,34 @@ def test_top_ops_and_gap_attribution(trace):
     assert ops["custom-call:X64SplitHigh"] == pytest.approx(100e-9)
     gaps = dict(tr.idle_gaps(trace, 10000))
     # busiest device idle: 10000 - 1100 = 8900 ns; create_index covers
-    # [0,6000)+[7000,9000) of which busy 1000+100
-    assert gaps["bench.create_index"] == pytest.approx((8000 - 1100) * 1e-9)
-    assert gaps["bench.delete_vacuum"] == pytest.approx(1000e-9)
+    # [0,6000)+[7000,9000) of which busy 1000+100; another thread's
+    # bench.serve started later and takes its idle [500,1000) from it
     assert gaps["bench.serve"] == pytest.approx((1000 - 500) * 1e-9)
+    assert gaps["bench.create_index"] == pytest.approx((8000 - 1100 - 500) * 1e-9)
+    assert gaps["bench.delete_vacuum"] == pytest.approx(1000e-9)
     assert gaps[tr.NO_SPAN] == pytest.approx(1000e-9)
+    assert sum(gaps.values()) == pytest.approx(8900e-9)
     assert len(tr.describe(trace)) == 5
+
+
+def test_a_gap_goes_to_the_innermost_span_of_the_program():
+    with open(os.path.join(HERE, "data", "nested_trace.json")) as f:
+        trace = json.load(f)
+    # the device runs [3000,3100) and [7000,7050): idle 10000 - 150 = 9850 ns
+    assert tr.busy_seconds(trace) == pytest.approx([150e-9])
+    gaps = {k: round(v * 1e9) for k, v in tr.idle_gaps(trace, 10000)}
+    assert gaps == {
+        "bench.create_index": 300,       # what no stage covers: [0,200) + [6100,6200)
+        "hs.action.CreateAction": 400,   # the root's own: [200,500) + [6000,6100)
+        "hs.scan": 2000,                 # [500,2500)
+        "hs.hash_shuffle": 800,          # [2500,3500) less hs.kernel's [2950,3150)
+        "hs.kernel": 100,                # its 200 less the 100 in which the device ran
+        "hs.sidecar_capture": 2100,      # [3500,6000) less a later span of another thread
+        "hs.write": 400,                 # [4000,4400) on a writer's thread
+        "bench.delete_vacuum": 700,      # [6200,7200) less hs.vacuum and 50 busy
+        "hs.vacuum": 250,
+        tr.NO_SPAN: 2800,                # [7200,10000)
+    }
+    assert sum(gaps.values()) == 9850
+    top = tr.idle_gaps(trace, 10000, n=3)
+    assert [k for k, _v in top] == [tr.NO_SPAN, "hs.sidecar_capture", "hs.scan"]

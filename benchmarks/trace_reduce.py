@@ -11,7 +11,8 @@ checked on a small recorded one (``tests/data/small_trace.json``):
 ``load_xplane`` makes that form from the ``.xplane.pb`` the JAX profiler
 writes, keeping only what is read here: the device planes' lines and the
 host events whose name starts with ``bench.`` (the benchmark's own
-``TraceAnnotation`` spans).
+``TraceAnnotation`` spans) or ``hs.`` (the program's: every span of its
+action trace enters ``hs.<name>``).
 """
 
 from __future__ import annotations
@@ -22,7 +23,7 @@ import os
 DEVICE_PLANE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-SPAN_PREFIX = "bench."
+SPAN_PREFIXES = ("bench.", "hs.")
 NO_SPAN = "_no_bench_span_"
 
 
@@ -41,7 +42,7 @@ def load_xplane(trace_dir: str) -> dict:
             events = [
                 [e.name, float(e.start_ns), float(e.duration_ns)]
                 for e in line.events
-                if device or e.name.startswith(SPAN_PREFIX)
+                if device or e.name.startswith(SPAN_PREFIXES)
             ]
             if events:
                 lines.append({"name": line.name, "events": events})
@@ -80,21 +81,6 @@ def union(intervals) -> list:
 
 def total(intervals) -> float:
     return sum(e - s for s, e in intervals)
-
-
-def overlap(a, b) -> float:
-    """Length of the intersection of two merged interval lists."""
-    i = j = 0
-    acc = 0.0
-    while i < len(a) and j < len(b):
-        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
-        if hi > lo:
-            acc += hi - lo
-        if a[i][1] < b[j][1]:
-            i += 1
-        else:
-            j += 1
-    return acc
 
 
 def complement(intervals, lo: float, hi: float) -> list:
@@ -164,29 +150,41 @@ def top_device_ops(trace: dict, n: int = 10) -> list:
     return [[k, v] for k, v in sorted(acc.items(), key=lambda kv: -kv[1])[:n]]
 
 
-def host_spans(trace: dict) -> dict:
-    """{span name: merged intervals} of the benchmark's own host spans."""
+def host_events(trace: dict) -> list:
+    """[[name, start, end]] of the host spans kept (``SPAN_PREFIXES``)."""
+    return [
+        [name, s, s + d]
+        for plane in trace["planes"] if not plane["name"].startswith(DEVICE_PLANE_PREFIX)
+        for line in plane["lines"]
+        for name, s, d in line["events"] if name.startswith(SPAN_PREFIXES)
+    ]
+
+
+def innermost_cover(gaps: list, events: list) -> dict:
+    """{span name: length} of the merged intervals ``gaps``, each piece
+    given to the one span that covers it and started last (the innermost
+    of nested spans; of two threads' spans, the later); what no span
+    covers goes to ``NO_SPAN``. The parts add up to the gaps' length."""
     acc = {}
-    for plane in trace["planes"]:
-        if plane["name"].startswith(DEVICE_PLANE_PREFIX):
-            continue
-        for line in plane["lines"]:
-            for name, s, d in line["events"]:
-                if name.startswith(SPAN_PREFIX):
-                    acc.setdefault(name, []).append([s, s + d])
-    return {k: union(v) for k, v in acc.items()}
+    for lo, hi in gaps:
+        inside = [e for e in events if e[1] < hi and e[2] > lo]
+        cuts = sorted({lo, hi} | {min(max(t, lo), hi) for e in inside for t in e[1:]})
+        for a, b in zip(cuts, cuts[1:]):
+            over = [e for e in inside if e[1] <= a and e[2] >= b]
+            name = max(over, key=lambda e: e[1])[0] if over else NO_SPAN
+            acc[name] = acc.get(name, 0.0) + (b - a)
+    return acc
 
 
 def idle_gaps(trace: dict, window_ns: float, n: int = 10) -> list:
     """[[what the host was doing, idle seconds]]: the busiest device's idle
-    time inside [0, window_ns), split by the benchmark span that covers
-    it. Spans of several threads overlap, so the parts can add up to more
-    than the idle time; what no span covers is ``_no_bench_span_``."""
+    time inside [0, window_ns), each piece given to the innermost host
+    span that covers it: a stage of the program (``hs.scan``) before the
+    benchmark's span around the whole call (``bench.create_index``), which
+    keeps what no stage covers."""
     planes = device_planes(trace)
     busy = max((busy_intervals(p) for p in planes), key=total, default=[])
     gaps = complement(busy, 0.0, window_ns)
-    spans = host_spans(trace)
-    out = [[name, overlap(gaps, iv) / 1e9] for name, iv in spans.items()]
-    covered = union([x for iv in spans.values() for x in iv])
-    out.append([NO_SPAN, (total(gaps) - overlap(gaps, covered)) / 1e9])
-    return sorted(out, key=lambda kv: -kv[1])[:n]
+    parts = innermost_cover(gaps, host_events(trace))
+    parts.setdefault(NO_SPAN, 0.0)
+    return [[k, v / 1e9] for k, v in sorted(parts.items(), key=lambda kv: -kv[1])[:n]]
