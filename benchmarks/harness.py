@@ -11,6 +11,10 @@ per-layer metric is a file found by the name ``BENCHMARK.json`` gives:
     layer_metrics/<metric>.json    ``reader`` and its argument (unit, layer,
                                    moves and cells are BENCHMARK.json's alone)
     readers/<reader>.py            from spans, counters or the trace to a number
+    roofline*.py                   a kernel's operations and bytes from shapes and
+                                   the configuration; a roofline metric's ``arg``
+                                   names module and function (``bytes_from``,
+                                   ``bytes_fn``), ``peaks.json`` the chip's rates
     tests/faults/<driver>.py       how that driver's timed path is broken, and
                                    which compared numbers must and may see it
 

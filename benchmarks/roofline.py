@@ -21,10 +21,13 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def bucket_hash_bytes(rows: int) -> int:
+def bucket_hash_bytes(rows: int, config: dict) -> int:
     """The bucket hash reads each row's 8-byte key (two 32-bit words) and
     writes its 4-byte bucket id. The murmur mix is a few dozen integer
-    operations a row, far under the chip's rate: memory bounds it."""
+    operations a row, far under the chip's rate: memory bounds it.
+
+    Every bytes function takes (rows, configuration); this one needs no
+    more than the rows, since any key is hashed as one 8-byte rep."""
     return rows * (8 + 4)
 
 

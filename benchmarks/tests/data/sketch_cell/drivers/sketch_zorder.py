@@ -1,4 +1,4 @@
-"""Traffic ``zorder_build``: build the configuration's z-order covering
+"""Traffic ``sketch-zorder``: build the configuration's z-order covering
 index over and over (the minimal driver of the additions-only proof,
 ``tests/test_added_cell.py``; not a cell of the benchmark).
 

@@ -1,4 +1,4 @@
-"""The fault of a z-order build (driver ``zorder_build``): an answer
+"""The fault of a z-order build (driver ``sketch_zorder``): an answer
 altered where it is produced."""
 
 from faults import Fault

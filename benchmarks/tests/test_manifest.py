@@ -1,5 +1,6 @@
 """BENCHMARK.json and the files it names."""
 
+import importlib
 import json
 import os
 import re
@@ -90,9 +91,24 @@ def test_layer_metric(metric):
     spec = load(BENCH, "layer_metrics", metric["name"] + ".json")
     assert set(spec) <= {"reader", "arg"}
     assert os.path.exists(os.path.join(BENCH, "readers", spec["reader"] + ".py"))
+    arg = spec.get("arg", {})
+    if "bytes_fn" in arg:   # a roofline's bytes function is found by name
+        module = importlib.import_module(arg.get("bytes_from", "roofline"))
+        assert os.path.dirname(module.__file__) == BENCH and callable(getattr(module, arg["bytes_fn"]))
     moved = E2E[metric["moves"]]
     for w in metric["workloads"]:   # each cell reports the metric this one moves
         assert w in cells_of(moved), (metric["name"], w)
+
+
+def test_nothing_of_the_tree_is_named_as_the_sketch_is():
+    # ``sketch*`` is the test data of test_added_cell.py (data/sketch_cell/),
+    # which lays it over a copy of this tree: a real cell takes other names
+    names = [c["name"] for c in CELLS] + [c["traffic"] for c in CELLS]
+    names += [c["name"] for c in MANIFEST["configs"]]
+    names += [m["name"] for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]]
+    for d in ("configs", "traffic", "drivers", "readers", "layer_metrics", os.path.join("tests", "faults"), ""):
+        names += os.listdir(os.path.join(BENCH, d))
+    assert [n for n in names if n.startswith(("sketch", "roofline_sketch"))] == []
 
 
 def test_files_under_paths_are_named_plainly():

@@ -214,8 +214,8 @@ def test_structure_of_bucket_files(tmp_path):
 
 
 def test_hash_roofline_bytes_and_peaks():
-    assert roofline.bucket_hash_bytes(16_000_000) == 192_000_000
-    least = roofline.least_seconds(roofline.bucket_hash_bytes(16_000_000), "TPU v5 lite")
+    assert roofline.bucket_hash_bytes(16_000_000, {}) == 192_000_000
+    least = roofline.least_seconds(roofline.bucket_hash_bytes(16_000_000, {}), "TPU v5 lite")
     assert least == pytest.approx(192e6 / 819e9)
     with pytest.raises(KeyError):
         roofline.peaks("TPU v9")

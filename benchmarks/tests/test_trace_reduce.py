@@ -6,7 +6,8 @@ import os
 import pytest
 
 import trace_reduce as tr
-from conftest import HERE
+from conftest import BENCH, HERE
+from readers import trace_module
 
 
 @pytest.fixture(scope="module")
@@ -30,6 +31,20 @@ def test_module_time_by_name_up_to_the_run_id(trace):
     seconds, runs = tr.module_seconds(trace, "jit__bucket_ids_words")
     assert (seconds, runs) == (pytest.approx(1000e-9), 2)
     assert tr.module_seconds(trace, "jit_never_ran") == (None, 0)
+
+
+def test_the_hash_metrics_as_their_data_files_read_them(trace):
+    # the module ran 1000 ns for two builds of 16 rows: 2 x 16 x 12 B at 819 GB/s
+    record = {"trace": trace, "ops": [{"kind": "build"}] * 2, "rows": 16, "config": {},
+              "device": {"kind": "TPU v5 lite"}}
+
+    def read(metric, **other):
+        with open(os.path.join(BENCH, "layer_metrics", metric + ".json")) as f:
+            return trace_module.read(dict(record, **other), json.load(f)["arg"])
+
+    assert read("hash_kernel_s") == pytest.approx(500e-9)
+    assert read("hash_roofline") == pytest.approx(100 * (2 * 16 * 12 / 819e9) / 1000e-9)
+    assert read("hash_kernel_s", ops=[]) is None and read("hash_roofline", trace=None) is None
 
 
 def test_top_ops_and_gap_attribution(trace):
