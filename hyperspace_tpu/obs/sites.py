@@ -247,7 +247,9 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
         "exchange and its h2d / kernel / d2h children: the device leg "
         "of every strategy that has one goes through this one function, "
         "so a 1.5 ms all_to_all inside seconds of host-seen exchange is "
-        "told from its transfers",
+        "told from its transfers; d2h is _fetch_shards (every output's "
+        "copy_to_host_async before any read, then the shards in place) "
+        "and carries shards / started / assembled_bytes beside bytes",
     ),
     "hyperspace_tpu.parallel.shuffle._timed": (
         "span",

@@ -224,13 +224,53 @@ def _off_chip_rows(counts: np.ndarray) -> int:
     return int(counts.sum() - np.trace(counts))
 
 
-def _device_leg(acct: Dict, put: Callable, run: Callable, fetch: Callable):
+def _fetch_shards(out) -> Tuple[List[List[np.ndarray]], Dict[str, int]]:
+    """The outputs of an exchange program back on the host, shard by
+    shard: per output, its addressable shards in index order, each the
+    flat view of the host buffer the runtime copied it into.
+
+    Every output's ``copy_to_host_async()`` is called before any shard
+    is read, so all payloads x devices copies are in flight at once;
+    ``np.asarray`` of a whole sharded output would start that output's
+    copies only, wait, and copy every shard a second time into a fresh
+    array of the full shape — the unpacks read inside one shard at a
+    time and never need it. Any dtype an exchange carries comes back as
+    it went (8-byte values and key reps, 4-byte codes, validity planes).
+
+    Also the fetch's account, for the ``d2h`` span: ``shards`` (buffers
+    read in place), ``started`` (copies started before the first read)
+    and ``assembled_bytes`` (bytes copied a second time on the host:
+    none — nothing here concatenates or allocates)."""
+    started = 0
+    for o in out:
+        o.copy_to_host_async()
+        started += len(o.addressable_shards)
+    host = [
+        [
+            np.asarray(shard.data).reshape(-1)
+            for shard in sorted(
+                o.addressable_shards,
+                key=lambda shard: tuple(sl.start or 0 for sl in shard.index),
+            )
+        ]
+        for o in out
+    ]
+    account = {
+        "shards": sum(len(shards) for shards in host),
+        "started": started,
+        "assembled_bytes": 0,
+    }
+    return host, account
+
+
+def _device_leg(acct: Dict, put: Callable, run: Callable):
     """The device leg of an exchange, as the host sees it — the
     ``exchange`` span and its three children: ``h2d`` (operands placed
     shard by shard, to ``block_until_ready``), ``kernel`` (the program's
     launch under ``mesh_dispatch_lock`` to ``block_until_ready`` of its
-    outputs) and ``d2h`` (``fetch``: the outputs back as numpy). The
-    bytes of both transfers go on their spans and into the account."""
+    outputs) and ``d2h`` (:func:`_fetch_shards`: every output's shards
+    as flat host arrays, with the fetch's account as attrs). The bytes
+    of both transfers go on their spans and into the account."""
     with _timed(acct, "exchange_s"):
         with _obs_trace.span("h2d") as sp:
             operands = jax.block_until_ready(put())
@@ -240,8 +280,10 @@ def _device_leg(acct: Dict, put: Callable, run: Callable, fetch: Callable):
                 out = run(operands)
             out = jax.block_until_ready(out)
         with _obs_trace.span("d2h") as sp:
-            host = fetch(out)
+            host, account = _fetch_shards(out)
             _transferred(sp, acct, "d2h_bytes", host)
+            for key, value in account.items():
+                sp.set(key, value)
     return host
 
 
@@ -559,34 +601,42 @@ def _compact_pack(
 
 
 def _compact_unpack(
-    plan: _CompactPlan, flats: Sequence[np.ndarray]
+    plan: _CompactPlan, received: Sequence[Sequence[np.ndarray]]
 ) -> Tuple[np.ndarray, List[np.ndarray], np.ndarray, int]:
     """Received slots -> canonical order by contiguous run copies:
     ``(bucket ids, payload columns, [D+1] shard extents, runs)``.
+    ``received`` holds, per payload, owner ``o``'s flat ``[D*cap]``
+    shard at index ``o`` (:func:`_fetch_shards`).
 
     The rows of the bucket of rank ``r`` (owner ``o``) that source ``s``
-    sent sit contiguously in the received ``[D*D*cap]`` buffer at
-    ``(o*D + s)*cap + (rows of s's lower-ranked buckets owned by o)``;
-    canonical order is, for each rank ascending (owner-major, bucket
-    ascending), for each source ascending, that run — within a bucket
-    source-major with original order inside a source, i.e. ascending
-    original row index. No permutation, no index array, no gather."""
+    sent sit contiguously in shard ``o`` at ``s*cap + (rows of s's
+    lower-ranked buckets owned by o)`` — every run lies inside one
+    shard; canonical order is, for each rank ascending (owner-major,
+    bucket ascending), for each source ascending, that run — within a
+    bucket source-major with original order inside a source, i.e.
+    ascending original row index. No permutation, no index array, no
+    gather."""
     D = plan.counts.shape[0]
     R = len(plan.ranked)
     owner_of_rank = plan.ranked.astype(np.int64) % D
     src = np.arange(D, dtype=np.int64)[:, None]
     within_slot = plan.starts[:, :R] - plan.starts[src, plan.owner_lo[owner_of_rank]]
-    lo = ((owner_of_rank * D + src) * plan.cap + within_slot).T.ravel()
+    lo = (src * plan.cap + within_slot).T.ravel()
     run_len = np.diff(plan.starts, axis=1)
     hi = lo + run_len.T.ravel()
-    runs = [(a, b) for a, b in zip(lo.tolist(), hi.tolist()) if b > a]
+    owner = np.repeat(owner_of_rank, D)
+    runs = [
+        (o, a, b)
+        for o, a, b in zip(owner.tolist(), lo.tolist(), hi.tolist())
+        if b > a
+    ]
 
-    def unpack(flat: np.ndarray) -> np.ndarray:
+    def unpack(shards: Sequence[np.ndarray]) -> np.ndarray:
         if not runs:
-            return np.zeros(0, dtype=flat.dtype)
-        return np.concatenate([flat[a:b] for a, b in runs])
+            return np.zeros(0, dtype=shards[0].dtype)
+        return np.concatenate([shards[o][a:b] for o, a, b in runs])
 
-    out_cols = _per_payload(unpack, flats, len(plan.order))
+    out_cols = _per_payload(unpack, received, len(plan.order))
     out_bucket = np.repeat(plan.ranked, run_len.sum(axis=0))
     shard_offsets = np.concatenate(
         [np.zeros(1, dtype=np.int64), np.cumsum(plan.counts.sum(axis=0))]
@@ -623,14 +673,13 @@ def _compact_exchange(mesh, key_reps, payloads, num_buckets, seed):
         sp.set("bytes", _nbytes(sends))
         sp.set("gathers_native", gathers_native)
         sp.set("gathers_numpy", len(sends) - gathers_native)
-    flats = _device_leg(
+    received = _device_leg(
         acct,
         lambda: tuple(put_sharded(mesh, s) for s in sends),
         lambda ops: _compact_program(mesh, ops),
-        lambda out: [np.asarray(o).reshape(-1) for o in out],
     )
     with _timed(acct, "unpack_s") as sp:
-        out_bucket, out_cols, shard_offsets, runs = _compact_unpack(plan, flats)
+        out_bucket, out_cols, shard_offsets, runs = _compact_unpack(plan, received)
         sp.set("bytes", _nbytes(out_cols))
         sp.set("runs", runs)
     row_bytes = sum(p.dtype.itemsize for p in payloads)
@@ -758,23 +807,11 @@ def _twostage_exchange_mp(mesh, key_reps, payloads, num_buckets, seed):
             sends.append(buf.reshape(1, L, B))
         sp.set("bytes", _nbytes(sends))
     hmesh = hierarchical_view(mesh, H)
-
-    def fetch(out):
-        local = []
-        for arr in out:
-            shards = sorted(arr.addressable_shards, key=lambda s: s.index)
-            local.append(
-                np.concatenate(
-                    [np.asarray(s.data).reshape(-1) for s in shards]
-                ).reshape(L, B)
-            )
-        return local
-
+    # per payload, this process's L lanes: each a flat [B] shard
     local = _device_leg(
         acct,
         lambda: tuple(_process_local_operand(hmesh, s) for s in sends),
         lambda ops: _twostage_program(hmesh, ops, caps),
-        fetch,
     )
     with _timed(acct, "unpack_s") as sp:
         recv_ids, recv_cols = local[0], local[1:]
@@ -791,9 +828,9 @@ def _twostage_exchange_mp(mesh, key_reps, payloads, num_buckets, seed):
                 r = (pid - src_h) % H
                 cnt = int(hl_all[src_h, pid, l])
                 lo = int(offs[r])
-                ids_parts.append(recv_ids[l, lo : lo + cnt])
+                ids_parts.append(recv_ids[l][lo : lo + cnt])
                 for i, c in enumerate(recv_cols):
-                    col_parts[i].append(c[l, lo : lo + cnt])
+                    col_parts[i].append(c[l][lo : lo + cnt])
             ids_l = np.concatenate(ids_parts)
             order = np.argsort(ids_l, kind="stable")
             out_bucket_parts.append(ids_l[order])
@@ -808,7 +845,7 @@ def _twostage_exchange_mp(mesh, key_reps, payloads, num_buckets, seed):
         out_cols = [
             np.concatenate(parts)
             if parts
-            else np.zeros(0, dtype=c.dtype)
+            else np.zeros(0, dtype=c[0].dtype)
             for parts, c in zip(out_col_parts, recv_cols)
         ]
         shard_offsets = np.concatenate(
@@ -902,7 +939,7 @@ def _twostage_exchange(mesh, key_reps, payloads, num_buckets, seed, hosts):
         # sender of a row is device (src_h, lane): the host already moved
         # it to its destination lane's buffer (the RAM ici leg)
         send_pos = (src_h * L + lane) * B + offs[rnd] + rank
-        recv_pos = (dst_h * L + lane) * B + offs[rnd] + rank
+        recv_pos = offs[rnd] + rank  # inside the owner's [B] shard
         sends = []
         for p in payloads:
             buf = np.zeros(D * B, dtype=p.dtype)
@@ -910,18 +947,27 @@ def _twostage_exchange(mesh, key_reps, payloads, num_buckets, seed, hosts):
             sends.append(buf.reshape(H, L, B))
         sp.set("bytes", _nbytes(sends))
     hmesh = hierarchical_view(mesh, H)
-    flats = _device_leg(
+    received = _device_leg(
         acct,
         lambda: tuple(
             put_sharded(hmesh, s, P(DCN_AXIS, ICI_AXIS)) for s in sends
         ),
         lambda ops: _twostage_program(hmesh, ops, caps),
-        lambda out: [np.asarray(o).reshape(-1) for o in out],
     )
     with _timed(acct, "unpack_s") as sp:
+        # canonical order is owner-major and device (dst_h, lane) IS the
+        # owner: owner o's rows are one gather inside shard o
         out_perm, shard_offsets = canonical_order(bucket_ids, num_buckets, D)
         gather_idx = recv_pos[out_perm]
-        out_cols = _threaded_gather(flats, gather_idx)
+        extents = list(zip(shard_offsets[:-1].tolist(), shard_offsets[1:].tolist()))
+
+        def unpack(shards: Sequence[np.ndarray]) -> np.ndarray:
+            col = np.empty(n, dtype=shards[0].dtype)
+            for shard, (lo, hi) in zip(shards, extents):
+                np.take(shard, gather_idx[lo:hi], out=col[lo:hi])
+            return col
+
+        out_cols = _per_payload(unpack, received, n)
         out_bucket = bucket_ids[out_perm]
         sp.set("bytes", _nbytes(out_cols))
     row_bytes = sum(p.dtype.itemsize for p in payloads)

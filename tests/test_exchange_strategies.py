@@ -231,6 +231,10 @@ class TestStrategyDifferential:
 # ---------------------------------------------------------------------------
 
 _LAYOUT_BUCKETS = 12
+_LAYOUT_SHAPES = [
+    "uniform", "hot", "empty_owner", "ragged", "tiny",
+    "one_row_a_shard", "under_a_row_a_slot",
+]
 
 
 def _layout_keys(shape, D, rng):
@@ -255,13 +259,7 @@ def _layout_keys(shape, D, rng):
 
 class TestCompactOnePartition:
     @pytest.mark.parametrize("D", [2, 4, 8])
-    @pytest.mark.parametrize(
-        "shape",
-        [
-            "uniform", "hot", "empty_owner", "ragged", "tiny",
-            "one_row_a_shard", "under_a_row_a_slot",
-        ],
-    )
+    @pytest.mark.parametrize("shape", _LAYOUT_SHAPES)
     def test_pack_layout(self, D, shape, monkeypatch):
         """Every send slot holds exactly the rows of its (source, owner)
         pair, grouped by ascending bucket, in original order inside a
@@ -392,6 +390,159 @@ class TestCompactOnePartition:
         assert pack["gathers_numpy"] >= 2  # the codes and the validity
         assert 0 < unpack["runs"] <= D * nb
         assert by_name["exchange_plan"].attrs["strategy"] == "compact"
+
+
+# ---------------------------------------------------------------------------
+# the fetch: every copy started before any read, shards read in place
+# ---------------------------------------------------------------------------
+
+class _ShardData:
+    """Stands where a shard's single-device array stands: ``np.asarray``
+    of it hands out the buffer itself, and says so in the log."""
+
+    def __init__(self, log, tag, buf):
+        self._log, self._tag, self.buf = log, tag, buf
+
+    def __array__(self, dtype=None, copy=None):
+        self._log.append(("read",) + self._tag)
+        return self.buf
+
+
+class _Output:
+    """As much of a sharded ``jax.Array`` as the fetch touches: rows
+    split over ``D`` shards, handed out in no particular order."""
+
+    def __init__(self, log, k, full, D):
+        self._log, self._k, self.shape = log, k, full.shape
+        rows = full.shape[0] // D
+        self.addressable_shards = [
+            types.SimpleNamespace(
+                index=(slice(d * rows, (d + 1) * rows), slice(None)),
+                data=_ShardData(log, (k, d), full[d * rows : (d + 1) * rows]),
+            )
+            for d in reversed(range(D))
+        ]
+
+    def copy_to_host_async(self):
+        self._log.append(("start", self._k))
+
+    def __array__(self, dtype=None, copy=None):
+        raise AssertionError("a whole sharded output was converted")
+
+
+class TestFetchShards:
+    @pytest.mark.parametrize("D", [1, 2, 4])
+    @pytest.mark.parametrize("dtype", [np.int64, np.int32, np.uint8, bool])
+    def test_every_copy_starts_before_any_read_and_nothing_is_assembled(
+        self, D, dtype
+    ):
+        rng = np.random.default_rng(D)
+        log = []
+        fulls = [
+            rng.integers(0, 2, (D * D, 24)).astype(dtype),
+            rng.integers(-(2**60), 2**60, (D * D, 24)).astype(np.int64),
+            rng.integers(0, 100, (D * D, 24)).astype(dtype),
+        ]
+        out = [_Output(log, k, full, D) for k, full in enumerate(fulls)]
+        host, account = sh._fetch_shards(out)
+        # every output's copy is started, then every shard is read: in
+        # output order, in index order
+        assert log[: len(out)] == [("start", k) for k in range(len(out))]
+        assert log[len(out):] == [
+            ("read", k, d) for k in range(len(out)) for d in range(D)
+        ]
+        assert account == {
+            "shards": len(out) * D, "started": len(out) * D, "assembled_bytes": 0,
+        }
+        # the arrays handed on ARE the shards' buffers, flat: nothing of
+        # an output's full shape was allocated, no byte copied again
+        for full, shards in zip(fulls, host):
+            assert len(shards) == D
+            for d, got in enumerate(shards):
+                assert got.dtype == full.dtype and got.shape == (D * 24,)
+                assert np.shares_memory(got, full[d * D : (d + 1) * D])
+            np.testing.assert_array_equal(np.concatenate(shards), full.reshape(-1))
+
+    @pytest.mark.parametrize("D", [1, 2, 8])
+    def test_the_outputs_of_a_device_program(self, D):
+        """Real sharded outputs of mixed widths: per output ``D`` flat
+        shards that concatenate to the whole array."""
+        mesh = _mesh(D)
+        rng = np.random.default_rng(D)
+        sends = tuple(
+            rng.integers(0, 2**31, (D * D, 16)).astype(dt)
+            for dt in (np.int64, np.int32, np.uint8)
+        )
+        out = sh._compact_program(
+            mesh, tuple(sh.put_sharded(mesh, s) for s in sends)
+        )
+        host, account = sh._fetch_shards(out)
+        assert account == {"shards": 3 * D, "started": 3 * D, "assembled_bytes": 0}
+        for s, shards in zip(sends, host):
+            want = s.reshape(D, D, 16).transpose(1, 0, 2).reshape(-1)
+            assert [x.shape for x in shards] == [(D * 16,)] * D
+            assert all(x.dtype == s.dtype for x in shards)
+            np.testing.assert_array_equal(np.concatenate(shards), want)
+
+
+def _received(sends, D):
+    """What the ``all_to_all`` delivers, per payload: owner ``o``'s flat
+    ``[D*cap]`` shard — slot ``(s, o)`` of every source, source-major —
+    each its own read-only buffer, as the runtime hands them over."""
+    out = []
+    for buf in sends:
+        slots = buf.reshape(D, D, -1)
+        shards = [np.ascontiguousarray(slots[:, o]).reshape(-1) for o in range(D)]
+        for shard in shards:
+            shard.flags.writeable = False
+        out.append(shards)
+    return out
+
+
+def _unpacked_equals_the_lexsort(plan, sends, keys, payloads, nb, D):
+    """``_compact_unpack`` of the shards the sends arrive as, held to
+    ``_reference`` element for element; returns the runs it copied."""
+    ids, cols, offsets, runs = sh._compact_unpack(plan, _received(sends, D))
+    ref = _reference(keys, payloads, nb, D)
+    np.testing.assert_array_equal(ids, ref[0])
+    np.testing.assert_array_equal(offsets, ref[2])
+    for a, b in zip(cols, ref[1]):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(a, b)
+    return runs
+
+
+class TestCompactUnpackFromShards:
+    @pytest.mark.parametrize("D", [2, 4, 8])
+    @pytest.mark.parametrize("shape", _LAYOUT_SHAPES)
+    def test_equals_the_lexsort(self, D, shape):
+        rng = np.random.default_rng(D * 7 + len(shape))
+        keys = _layout_keys(shape, D, rng)
+        n, nb = keys.shape[1], _LAYOUT_BUCKETS
+        payloads = [
+            np.arange(1, n + 1, dtype=np.int64),
+            (np.arange(n) % 251 + 1).astype(np.int32),
+        ]
+        plan = sh._compact_plan(keys, nb, 42, D)
+        sends, _ = sh._compact_pack(plan, payloads)
+        runs = _unpacked_equals_the_lexsort(plan, sends, keys, payloads, nb, D)
+        assert runs <= D * nb
+
+    @pytest.mark.parametrize("D", [2, 4, 8])
+    def test_mixed_widths_equal_the_lexsort(self, D):
+        """8-byte values, 4-byte string codes and a validity plane under
+        two key columns, each unpacked from its own shards."""
+        rng = np.random.default_rng(D)
+        n, nb = 3001, 16
+        keys = rng.integers(0, 97, (2, n)).astype(np.int64)
+        payloads = [
+            rng.integers(-(2**60), 2**60, n).astype(np.int64),
+            rng.integers(0, 3, n).astype(np.int32),
+            rng.integers(0, 2, n).astype(bool),
+        ]
+        plan = sh._compact_plan(keys, nb, 42, D)
+        sends, _ = sh._compact_pack(plan, payloads)
+        _unpacked_equals_the_lexsort(plan, sends, keys, payloads, nb, D)
 
 
 # ---------------------------------------------------------------------------

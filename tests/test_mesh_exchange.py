@@ -45,6 +45,7 @@ SEED = 2**31 + 27
 # four columns as a ColumnarBatch holds them — int64, float64, and the
 # date32 widened to int64 (4 bytes more than the configuration's 28)
 ROW_BYTES = 8 + 32
+PAYLOADS = 5     # each its own plane on the wire: one output a payload
 EXCHANGE_SPANS = ("exchange_plan", "pack", "exchange", "unpack")
 DEVICE_SPANS = ("h2d", "kernel", "d2h")
 COUNTERS = ("exchange_h2d_bytes", "exchange_d2h_bytes", "exchange_wire_bytes",
@@ -196,6 +197,11 @@ def test_mesh_build_against_the_reference(strategy, table, one_chip, tmp_path):
         by_name = {s.name: s for s in legs}
         assert by_name["h2d"].attrs["bytes"] == counters["exchange_h2d_bytes"]
         assert by_name["d2h"].attrs["bytes"] == counters["exchange_d2h_bytes"]
+        # the fetch: every shard's copy started before the first read,
+        # every shard read where it landed, nothing copied a second time
+        fetch = by_name["d2h"].attrs
+        assert fetch["shards"] == fetch["started"] == CHIPS * PAYLOADS
+        assert fetch["assembled_bytes"] == 0
     else:
         assert [counters[k] for k in COUNTERS[:4]] == [0, 0, 0, 0]
     assert telemetry["shuffle_wire_bytes"] == counters["exchange_wire_bytes"]
