@@ -813,7 +813,7 @@ def write_bucketed(
     )
 
 
-def _count_written(sp, paths: List[str]) -> None:
+def count_written(sp, paths: List[str]) -> None:
     """Files and bytes a write stage produced: on its span, and summed
     onto the action's root."""
     n_bytes = _files_bytes(paths)
@@ -973,7 +973,7 @@ def _write_bucketed_pipelined(
             # every file's seconds on its writer's thread, those that
             # ran under the sort stage included
             _repeat_attrs(write_sp, [sec for _p, sec in done], "buckets")
-            _count_written(write_sp, written)
+            count_written(write_sp, written)
     return written
 
 
@@ -1093,7 +1093,7 @@ def _write_bucketed_sharded(
                 _repeat_attrs(
                     write_sp, [sec for _b, (_p, sec) in done], "buckets"
                 )
-                _count_written(write_sp, [path for _b, path in out])
+                count_written(write_sp, [path for _b, path in out])
         return out
 
     # the shard tails run on pool threads: hand them the action's span

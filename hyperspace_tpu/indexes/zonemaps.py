@@ -469,7 +469,9 @@ def _capture_files(dir_path: str, index, kind: str, sp) -> bool:
         }
     if kind == "ZOrderCoveringIndex":
         try:
-            _capture_zspans(doc, files, footers, list(index.indexed_columns))
+            _capture_zspans(
+                doc, files, footers, list(index.indexed_columns), sp
+            )
         # z capture is best-effort extra sharpness: any failure (exotic
         # dtype, memory pressure) must leave the min/max sidecar usable
         except Exception as exc:  # hslint: disable=HS402
@@ -508,11 +510,15 @@ def capture_safely(dir_path: str, index) -> None:
 _Z_BITS = 16
 
 
-def _capture_zspans(doc, files, footers, zcols: List[str]) -> None:
+def _capture_zspans(doc, files, footers, zcols: List[str], sp) -> None:
     """Per-row-group z-address spans for a z-order version dir, two
     passes bounded by the largest file: (1) fit a frozen range/dict
     encoder spec over the directory's data, (2) per file, compute planes
-    and record each row group's packed (z_lo, z_hi)."""
+    and record each row group's packed (z_lo, z_hi). The parts of its
+    seconds go on the capture's span ``sp`` as attrs — ``zspan_fit_s``
+    (pass 1), ``zspan_planes_s`` (pass 2's read + encode + interleave),
+    ``zspan_minmax_s`` (the per-row-group ``planes_z_minmax``) and
+    ``row_groups`` — never a span a row group."""
     from hyperspace_tpu.io import parquet as pio
     from hyperspace_tpu.io.columnar import ColumnarBatch
     from hyperspace_tpu.ops.zorder import (
@@ -528,6 +534,7 @@ def _capture_zspans(doc, files, footers, zcols: List[str]) -> None:
     # pass 1 (spec fit) reads per file and discards, pass 2 re-reads per
     # file: peak memory stays bounded by the largest file's indexed
     # columns, not the whole index
+    t_fit = time.perf_counter()
     for f in files:
         batch = ColumnarBatch.from_arrow(pio.read_table([f], zcols))
         for j, c in enumerate(zcols):
@@ -556,14 +563,20 @@ def _capture_zspans(doc, files, footers, zcols: List[str]) -> None:
                 )
             )
     encoder = ZOrderEncoder(_Z_BITS, specs)
+    sp.set("zspan_fit_s", round(time.perf_counter() - t_fit, 6))
     nplanes = None
+    planes_s = minmax_s = 0.0
+    row_groups = 0
     for f in files:
         fz = footers.get(f)
         entry = doc["files"].get(os.path.basename(f))
         if fz is None or entry is None:
             continue
+        t_planes = time.perf_counter()
         batch = ColumnarBatch.from_arrow(pio.read_table([f], zcols))
         planes = encoder.planes([batch.column(c) for c in zcols])
+        t_minmax = time.perf_counter()
+        planes_s += t_minmax - t_planes
         nplanes = planes.shape[0]
         spans = []
         pos = 0
@@ -574,6 +587,11 @@ def _capture_zspans(doc, files, footers, zcols: List[str]) -> None:
             )
             pos += rows
         entry["rg_zspans"] = spans
+        minmax_s += time.perf_counter() - t_minmax
+        row_groups += len(spans)
+    sp.set("zspan_planes_s", round(planes_s, 6))
+    sp.set("zspan_minmax_s", round(minmax_s, 6))
+    sp.set("row_groups", row_groups)
     doc["zorder"] = {
         "columns": list(zcols),
         "bits": _Z_BITS,

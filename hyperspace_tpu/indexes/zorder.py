@@ -190,16 +190,32 @@ class ZOrderCoveringIndex(Index):
         }
 
 
+# bits of the z-address a column gets (ops/zorder's layout)
+_Z_BITS = 16
+
+
 def _write_zordered(
     ctx, data, indexed_cols: List[str], target_bytes: int
 ) -> List[str]:
     """Global z-sort then split into ~equal files sized to hit the target
-    partition bytes. ``data`` is a ColumnarBatch or (for datasets beyond
-    the build memory budget) a lazy SourceScan streamed in two passes."""
+    partition bytes — bytes of the index's columns as they lie IN MEMORY
+    (``table.nbytes``), not of the source on disk as the key
+    ``targetSourceBytesPerPartition`` says (ROADMAP.md, Design: "z-order
+    file sizing"). ``data`` is a ColumnarBatch or (for datasets beyond
+    the build memory budget) a lazy SourceScan streamed in two passes.
+
+    Build stages under the action's root (``covering_build.stage``):
+    ``zorder_encode`` (order encodings + min/max), ``zorder_interleave``
+    (host words, both transfers and the kernel: ``ops/zorder``),
+    ``zorder_sort`` (``ops/sort.lexsort_perm``; ``h2d``/``kernel``/
+    ``d2h`` under it when it takes the device arm), ``take``,
+    ``to_arrow``, ``write``."""
     import os
 
+    from hyperspace_tpu.indexes import covering_build
     from hyperspace_tpu.indexes.covering_build import CompositeScan, SourceScan
-    from hyperspace_tpu.ops.zorder import z_order_permutation
+    from hyperspace_tpu.ops.sort import lexsort_perm
+    from hyperspace_tpu.ops.zorder import ZOrderEncoder
 
     os.makedirs(ctx.index_data_path, exist_ok=True)
     if isinstance(data, (SourceScan, CompositeScan)):
@@ -210,23 +226,49 @@ def _write_zordered(
     if batch.num_rows == 0:
         return []
     conf = ctx.session.conf
-    perm = z_order_permutation(
-        [batch.column(c) for c in indexed_cols],
-        quantile=conf.zorder_quantile_enabled,
-        relative_error=conf.zorder_quantile_relative_error,
-    )
-    table = batch.take(perm).to_arrow()
+    with covering_build.stage("zorder_encode"):
+        encoder, encs = ZOrderEncoder.fit(
+            [batch.column(c) for c in indexed_cols],
+            _Z_BITS,
+            conf.zorder_quantile_enabled,
+            conf.zorder_quantile_relative_error,
+        )
+    with covering_build.stage("zorder_interleave"):
+        planes = encoder.planes_from_encodings(encs)
+    with covering_build.stage("zorder_sort"):
+        perm = lexsort_perm(planes)
+    with covering_build.stage("take"):
+        batch = batch.take(perm)
+    with covering_build.stage("to_arrow"):
+        table = batch.to_arrow()
+    return _write_parts(ctx, table, target_bytes, 0)
+
+
+def _write_parts(ctx, table, target_bytes: int, first_idx: int) -> List[str]:
+    """One ``write`` stage (attrs ``files``, ``bytes``, ``rows``): a
+    z-sorted table cut into ~equal files of about ``target_bytes`` of
+    ``table.nbytes`` each, named from ``first_idx`` on."""
+    import os
+
+    from hyperspace_tpu.indexes import covering_build
+
     nbytes = max(table.nbytes, 1)
     num_parts = max(1, math.ceil(nbytes / target_bytes))
     rows_per_part = math.ceil(table.num_rows / num_parts)
     written = []
-    for i in range(num_parts):
-        chunk = table.slice(i * rows_per_part, rows_per_part)
-        if chunk.num_rows == 0:
-            continue
-        path = os.path.join(ctx.index_data_path, f"part-{i:05d}-zorder.parquet")
-        pio.write_table(path, chunk)
-        written.append(path)
+    with covering_build.stage("write") as sp:
+        for i in range(num_parts):
+            chunk = table.slice(i * rows_per_part, rows_per_part)
+            if chunk.num_rows == 0:
+                continue
+            path = os.path.join(
+                ctx.index_data_path,
+                f"part-{first_idx + i:05d}-zorder.parquet",
+            )
+            pio.write_table(path, chunk)
+            written.append(path)
+        covering_build.count_written(sp, written)
+        sp.set("rows", int(table.num_rows))
     return written
 
 
@@ -253,10 +295,16 @@ def _write_zordered_streaming(
        (ZOrderCoveringIndex.scala:139-153).
     3. **Merge**: per range in ascending order, re-encode + lexsort (a
        range holds ~1/64 of the data) and write size-targeted files.
+
+    The spill's and the merge's steps record the in-memory build's
+    stages where they run the same step (``zorder_interleave`` — here
+    with the re-encode inside it —, ``zorder_sort``, ``take``,
+    ``to_arrow``, ``write``): once a wave or a range, never a row group.
     """
     import os
     import shutil
 
+    from hyperspace_tpu.indexes import covering_build
     from hyperspace_tpu.indexes.covering_build import plan_waves
     from hyperspace_tpu.io.columnar import ColumnarBatch
     from hyperspace_tpu.ops.sort import lexsort_perm
@@ -315,7 +363,7 @@ def _write_zordered_streaming(
                     maxs[j] if maxs[j] is not None else np.uint64(0),
                 )
             )
-    encoder = ZOrderEncoder(16, specs)
+    encoder = ZOrderEncoder(_Z_BITS, specs)
 
     # pass 2: spill into contiguous z-ranges
     spill_root = os.path.join(
@@ -331,9 +379,10 @@ def _write_zordered_streaming(
             batch = scan.materialize(w)
             if batch.num_rows == 0:
                 continue
-            planes = encoder.planes(
-                [batch.column(c) for c in indexed_cols]
-            )
+            with covering_build.stage("zorder_interleave"):
+                planes = encoder.planes(
+                    [batch.column(c) for c in indexed_cols]
+                )
             pid = (planes[0] >> np.uint32(32 - _ZORDER_SPILL_BITS)).astype(
                 np.int32
             )
@@ -372,26 +421,21 @@ def _write_zordered_streaming(
         state = {"file_idx": 0}
 
         def write_sorted(table):
-            nbytes = max(table.nbytes, 1)
-            num_parts = max(1, math.ceil(nbytes / target_bytes))
-            rows_per_part = math.ceil(table.num_rows / num_parts)
-            for i in range(num_parts):
-                chunk = table.slice(i * rows_per_part, rows_per_part)
-                if chunk.num_rows == 0:
-                    continue
-                path = os.path.join(
-                    ctx.index_data_path,
-                    f"part-{state['file_idx']:05d}-zorder.parquet",
-                )
-                pio.write_table(path, chunk)
-                written.append(path)
-                state["file_idx"] += 1
+            paths = _write_parts(ctx, table, target_bytes, state["file_idx"])
+            written.extend(paths)
+            state["file_idx"] += len(paths)
 
         def sort_batch(batch):
-            perm = lexsort_perm(
-                encoder.planes([batch.column(c) for c in indexed_cols])
-            )
-            return batch.take(perm).to_arrow()
+            with covering_build.stage("zorder_interleave"):
+                planes = encoder.planes(
+                    [batch.column(c) for c in indexed_cols]
+                )
+            with covering_build.stage("zorder_sort"):
+                perm = lexsort_perm(planes)
+            with covering_build.stage("take"):
+                batch = batch.take(perm)
+            with covering_build.stage("to_arrow"):
+                return batch.to_arrow()
 
         def next_window(plane_idx, shift):
             """The split window after (plane_idx, shift): slide down the

@@ -109,6 +109,17 @@ BUILD_STAGES = (
     "pack",
     "exchange",
     "unpack",
+    # a z-order build's stages (indexes/zorder._write_zordered), with
+    # to_arrow and write as above: the order encodings + min/max, the
+    # z-address planes (words = the host's scaling, stack and padding,
+    # then h2d / kernel / d2h of ops/zorder), the global lexsort (h2d /
+    # kernel / d2h of ops/sort.lexsort_perm's device arm under it), the
+    # gather of the batch
+    "zorder_encode",
+    "zorder_interleave",
+    "words",
+    "zorder_sort",
+    "take",
 )
 
 #: advisor-side stage spans (advisor/: query-log mining and what-if
@@ -267,6 +278,34 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
         "kernel inside a 1.2 s stage is explained only by separating "
         "the host's word split and each transfer from the kernel wait",
     ),
+    "hyperspace_tpu.ops.zorder.ZOrderEncoder.planes_from_encodings": (
+        "span",
+        "words / h2d / kernel / d2h: the z-address planes are host word "
+        "scaling, two transfers and a device program; the 0.15 s kernel "
+        "inside seconds of zorder_interleave is told from them",
+    ),
+    "hyperspace_tpu.ops.sort.lexsort_perm": (
+        "span",
+        "h2d / kernel / d2h of the device arm only (the z-order build's "
+        "global sort); the host arms, which the per-bucket sorts take, "
+        "open nothing",
+    ),
+    "hyperspace_tpu.indexes.zorder._write_zordered": (
+        "span",
+        "zorder_encode / zorder_interleave / zorder_sort / take / "
+        "to_arrow: the z-order build's data plane, a third of the "
+        "build, had no span at all (PERF.md, PR 34)",
+    ),
+    "hyperspace_tpu.indexes.zorder._write_parts": (
+        "span",
+        "write of a z-sorted table's files, one after another: files / "
+        "bytes / rows as attrs, never a span a file",
+    ),
+    "hyperspace_tpu.indexes.zorder._write_zordered_streaming": (
+        "span",
+        "the same stage names where the streamed build's spill and "
+        "merge run the same steps, once a wave or a z-range",
+    ),
     "hyperspace_tpu.indexes.aggindex.capture_index_dir": (
         "span",
         "sidecar_capture (aggstate): build-tail I/O that re-reads every "
@@ -279,7 +318,9 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
     "hyperspace_tpu.indexes.zonemaps.capture_index_dir": (
         "span",
         "sidecar_capture (zonemap): the footer pass and its publish, "
-        "the same stage name with its own sidecar attr",
+        "the same stage name with its own sidecar attr; on a z-order "
+        "directory the z-span capture's parts are attrs (zspan_fit_s, "
+        "zspan_planes_s, zspan_minmax_s, row_groups)",
     ),
     "hyperspace_tpu.actions.base.Action.run": (
         "span",

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import hyperspace_tpu.ops  # noqa: F401  (enables x64)
+from hyperspace_tpu.obs import trace as _obs_trace
 
 _SIGN = np.uint32(0x80000000)
 
@@ -123,6 +124,10 @@ def lexsort_perm(
     ``n_threads`` caps the native kernel's thread count — the partitioned
     build runs many per-bucket sorts concurrently and hands each a slice
     of the core budget instead of letting every sort claim the machine.
+
+    Under a live trace the device arm is three spans — ``h2d``,
+    ``kernel`` (dispatch to ``block_until_ready``), ``d2h`` — with the
+    bytes each way counted on the root; the host arms open none.
     """
     from hyperspace_tpu.ops import pad_len
 
@@ -147,7 +152,14 @@ def lexsort_perm(
             np.uint32(0xFFFFFFFF),
         )
         planes = np.concatenate([planes, fill], axis=1)
-    perm = np.asarray(lexsort_indices(jnp.asarray(planes)))
+    with _obs_trace.span("h2d", bytes=int(planes.nbytes)):
+        on_device = jax.block_until_ready(jnp.asarray(planes))
+    with _obs_trace.span("kernel"):
+        order = jax.block_until_ready(lexsort_indices(on_device))
+    with _obs_trace.span("d2h", bytes=int(order.nbytes)):
+        perm = np.asarray(order)
+    _obs_trace.accumulate("h2d_bytes", int(planes.nbytes))
+    _obs_trace.accumulate("d2h_bytes", int(perm.nbytes))
     return perm[:n]
 
 

@@ -13,6 +13,33 @@ vectorized 32-bit device arithmetic:
    normalization, same shape);
 3. bit interleaving across columns into a multi-word z-address, ordered
    lexicographically word-major.
+
+**The layout** (min/max encoding, the default; one definition for this
+module, ``benchmarks/reference_zorder.py`` and ``docs/range-serve.md``).
+For ``k`` indexed columns, 16 bits a column:
+
+* ``enc`` = the value's order-preserving uint64: signed integers and
+  ``date32`` days offset by 2^63; float64 in IEEE total order (sign bit
+  set → all bits flipped, else the sign bit set);
+* ``word = trunc(min((enc − min) as float64 × ((2^16 − 1) ÷ (max − min)
+  as float64), 2^16 − 1))`` with min/max the column's own extremes in
+  encoding space, 0 where max = min;
+* address bit ``t`` (most significant first) = bit ``15 − t div k`` of
+  column ``t mod k``, first indexed column first: ``16 k`` bits, packed
+  into 32-bit planes from the top (the last plane's low bits are zero).
+
+A float column is scaled in *encoding* space, not value space: on a
+column of 0.00..0.10 the value 0.00 maps to word 0 and 0.01..0.10 to the
+top ~0.3% of the words, so such a column orders its rows only in the
+address's last bits. Rows of one address are in no stated order (the
+sort is stable over the input's row order, which nothing relies on).
+Upstream's ``ZOrderField`` instead keeps ``value − min`` at the bit
+length of ``max − min`` and interleaves through a bit-index map.
+
+Under a live trace ``planes_from_encodings`` is four spans — ``words``
+(the host's scaling, stack and padding), ``h2d``, ``kernel`` (dispatch
+to ``block_until_ready``), ``d2h`` — with the bytes each way counted on
+the root, as ``ops/hash.bucket_ids_np`` has them.
 """
 
 from __future__ import annotations
@@ -25,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import hyperspace_tpu.ops  # noqa: F401  (enables x64)
+from hyperspace_tpu.obs import trace as _obs_trace
 
 
 def order_u64_np(col) -> np.ndarray:
@@ -168,17 +196,26 @@ class ZOrderEncoder:
         from hyperspace_tpu.ops import pad_len
 
         n = len(encs[0]) if encs else 0
-        words = np.stack(
-            [self._words(e, s) for e, s in zip(encs, self.specs)]
-        ) if encs else np.zeros((0, 0), dtype=np.uint32)
-        n_pad = pad_len(max(n, 1))
-        if n_pad != n:
-            fill = np.full(
-                (words.shape[0], n_pad - n), np.uint32((1 << self.bits) - 1)
-            )
-            words = np.concatenate([words, fill], axis=1)
-        planes = np.asarray(_interleave(jnp.asarray(words), self.bits))
-        return planes[:, :n]
+        with _obs_trace.span("words"):
+            words = np.stack(
+                [self._words(e, s) for e, s in zip(encs, self.specs)]
+            ) if encs else np.zeros((0, 0), dtype=np.uint32)
+            n_pad = pad_len(max(n, 1))
+            if n_pad != n:
+                fill = np.full(
+                    (words.shape[0], n_pad - n),
+                    np.uint32((1 << self.bits) - 1),
+                )
+                words = np.concatenate([words, fill], axis=1)
+        with _obs_trace.span("h2d", bytes=int(words.nbytes)):
+            on_device = jax.block_until_ready(jnp.asarray(words))
+        with _obs_trace.span("kernel"):
+            planes = jax.block_until_ready(_interleave(on_device, self.bits))
+        with _obs_trace.span("d2h", bytes=int(planes.nbytes)):
+            out = np.asarray(planes)
+        _obs_trace.accumulate("h2d_bytes", int(words.nbytes))
+        _obs_trace.accumulate("d2h_bytes", int(out.nbytes))
+        return out[:, :n]
 
     def planes(self, columns: List) -> np.ndarray:
         return self.planes_from_encodings(
