@@ -420,10 +420,13 @@ def capture_index_dir(dir_path: str, index) -> bool:
     """Write the ``_zonemaps.json`` sidecar for one freshly-written index
     version directory. Covering-family indexes only (a data-skipping
     sketch table is itself metadata). Z-order indexes additionally get
-    per-row-group z-address spans under a frozen encoder spec fit on the
-    directory's own data (one extra read of the indexed columns, paid at
-    build time so the serve path never has to). Returns True when a
-    sidecar was written; failures only cost the lazy-backfill path."""
+    per-row-group z-address spans under a frozen encoder spec of the
+    directory's own data, paid at build time so the serve path never has
+    to: taken from the write that has just sorted these rows where
+    ``index`` carries its hand-off (``take_written_zspans``; an in-memory
+    z-order write), else fit and computed by reading the indexed columns
+    back (:func:`_capture_zspans`). Returns True when a sidecar was
+    written; failures only cost the lazy-backfill path."""
     kind = getattr(index, "kind", "")
     if kind not in ("CoveringIndex", "ZOrderCoveringIndex"):
         return False
@@ -438,6 +441,9 @@ def _capture_files(dir_path: str, index, kind: str, sp) -> bool:
     from hyperspace_tpu.indexes import covering_build
     from hyperspace_tpu.io import parquet as pio
 
+    # taken whatever follows: a hand-off outlives no capture
+    take_written = getattr(index, "take_written_zspans", None)
+    written = take_written() if take_written is not None else None
     try:
         files = pio.list_format_files(dir_path, "parquet")
     except (OSError, KeyError):
@@ -470,7 +476,7 @@ def _capture_files(dir_path: str, index, kind: str, sp) -> bool:
     if kind == "ZOrderCoveringIndex":
         try:
             _capture_zspans(
-                doc, files, footers, list(index.indexed_columns), sp
+                doc, files, footers, list(index.indexed_columns), sp, written
             )
         # z capture is best-effort extra sharpness: any failure (exotic
         # dtype, memory pressure) must leave the min/max sidecar usable
@@ -510,15 +516,90 @@ def capture_safely(dir_path: str, index) -> None:
 _Z_BITS = 16
 
 
-def _capture_zspans(doc, files, footers, zcols: List[str], sp) -> None:
-    """Per-row-group z-address spans for a z-order version dir, two
-    passes bounded by the largest file: (1) fit a frozen range/dict
-    encoder spec over the directory's data, (2) per file, compute planes
-    and record each row group's packed (z_lo, z_hi). The parts of its
-    seconds go on the capture's span ``sp`` as attrs — ``zspan_fit_s``
+def _capture_zspans(doc, files, footers, zcols: List[str], sp, written=None) -> None:
+    """Per-row-group z-address spans for a z-order version dir
+    (``doc["files"][<basename>]["rg_zspans"]``) and the frozen encoder
+    spec they were computed under (``doc["zorder"]``), from one of two
+    sources of the same numbers, chosen by what can be observed here:
+
+    * **the write** — ``written`` is what the in-memory z-order write
+      that sorted these rows handed over (``indexes/zorder.
+      WrittenZSpans``): its spec and each row group's first and last
+      address. Used when every spec is ``range`` or ``dict`` (a quantile
+      encoder is not what a sidecar stores or ``spec_word_bounds``
+      reads), the directory's files are exactly the files that write
+      reports, and each footer's ``rg_rows`` are the row groups it
+      assumed. Nothing is read.
+    * **the re-read** — everything else (no hand-off: the streamed
+      build, a directory this process did not write; a quantile encoder;
+      any mismatch): two passes bounded by the largest file, (1) fit a
+      frozen range/dict spec over the directory's data, (2) per file,
+      compute planes and each row group's packed (z_lo, z_hi).
+
+    The capture's span ``sp`` says which ran, as counts of row groups:
+    ``zspans_from_write`` and ``zspans_reread`` beside ``row_groups``.
+    The parts of the re-read's seconds are attrs too — ``zspan_fit_s``
     (pass 1), ``zspan_planes_s`` (pass 2's read + encode + interleave),
-    ``zspan_minmax_s`` (the per-row-group ``planes_z_minmax``) and
-    ``row_groups`` — never a span a row group."""
+    ``zspan_minmax_s`` (the per-row-group ``planes_z_minmax``) — set only
+    when it ran; never a span a row group."""
+    spans_of = _spans_from_write(written, files, footers)
+    from_write = spans_of is not None
+    if from_write:
+        bits, nplanes, specs = written.bits, written.nplanes, written.specs
+    else:
+        bits = _Z_BITS
+        nplanes, specs, spans_of = _spans_by_reread(files, footers, zcols, sp)
+    for f, spans in spans_of.items():
+        doc["files"][os.path.basename(f)]["rg_zspans"] = [
+            None if mm is None else [format(mm[0], "x"), format(mm[1], "x")]
+            for mm in spans
+        ]
+    row_groups = sum(len(spans) for spans in spans_of.values())
+    sp.set("row_groups", row_groups)
+    sp.set("zspans_from_write", row_groups if from_write else 0)
+    sp.set("zspans_reread", 0 if from_write else row_groups)
+    doc["zorder"] = {
+        "columns": list(zcols),
+        "bits": bits,
+        "nplanes": int(nplanes or 1),
+        "specs": [
+            ["dict", s[1]]
+            if s[0] == "dict"
+            else ["range", str(int(s[1])), str(int(s[2]))]
+            for s in specs
+        ],
+    }
+
+
+def _spans_from_write(written, files, footers) -> Optional[dict]:
+    """{file: (z_lo, z_hi) of each of its row groups} out of a write's
+    hand-off, or None where it cannot stand for the re-read: there is
+    none, a spec is not ``range`` / ``dict``, the directory's files are
+    not exactly the files that write reports, or a footer's row groups
+    are not the ones it assumed."""
+    if written is None or any(
+        s[0] not in ("range", "dict") for s in written.specs
+    ):
+        return None
+    by_path = {os.path.abspath(f): f for f in files}
+    if by_path.keys() != written.files.keys():
+        return None
+    spans_of = {}
+    for path, (rg_rows, spans) in written.files.items():
+        fz = footers.get(by_path[path])
+        if fz is None or list(fz["rg_rows"]) != rg_rows:
+            return None
+        spans_of[by_path[path]] = spans
+    return spans_of
+
+
+def _spans_by_reread(files, footers, zcols: List[str], sp):
+    """(nplanes, specs, {file: (z_lo, z_hi) or None of each of its row
+    groups}) by reading the indexed columns back, twice: pass 1 (spec
+    fit) reads per file and discards, pass 2 re-reads per file, so peak
+    memory stays bounded by the largest file's indexed columns, not the
+    whole index. Its seconds go on ``sp`` (``zspan_fit_s``,
+    ``zspan_planes_s``, ``zspan_minmax_s``)."""
     from hyperspace_tpu.io import parquet as pio
     from hyperspace_tpu.io.columnar import ColumnarBatch
     from hyperspace_tpu.ops.zorder import (
@@ -531,9 +612,6 @@ def _capture_zspans(doc, files, footers, zcols: List[str], sp) -> None:
     mins: List[Optional[int]] = [None] * k
     maxs: List[Optional[int]] = [None] * k
     dicts: List[Optional[set]] = [None] * k
-    # pass 1 (spec fit) reads per file and discards, pass 2 re-reads per
-    # file: peak memory stays bounded by the largest file's indexed
-    # columns, not the whole index
     t_fit = time.perf_counter()
     for f in files:
         batch = ColumnarBatch.from_arrow(pio.read_table([f], zcols))
@@ -566,11 +644,10 @@ def _capture_zspans(doc, files, footers, zcols: List[str], sp) -> None:
     sp.set("zspan_fit_s", round(time.perf_counter() - t_fit, 6))
     nplanes = None
     planes_s = minmax_s = 0.0
-    row_groups = 0
+    spans_of = {}
     for f in files:
         fz = footers.get(f)
-        entry = doc["files"].get(os.path.basename(f))
-        if fz is None or entry is None:
+        if fz is None:
             continue
         t_planes = time.perf_counter()
         batch = ColumnarBatch.from_arrow(pio.read_table([f], zcols))
@@ -581,28 +658,13 @@ def _capture_zspans(doc, files, footers, zcols: List[str], sp) -> None:
         spans = []
         pos = 0
         for rows in fz["rg_rows"]:
-            mm = planes_z_minmax(planes, pos, pos + rows)
-            spans.append(
-                None if mm is None else [format(mm[0], "x"), format(mm[1], "x")]
-            )
+            spans.append(planes_z_minmax(planes, pos, pos + rows))
             pos += rows
-        entry["rg_zspans"] = spans
+        spans_of[f] = spans
         minmax_s += time.perf_counter() - t_minmax
-        row_groups += len(spans)
     sp.set("zspan_planes_s", round(planes_s, 6))
     sp.set("zspan_minmax_s", round(minmax_s, 6))
-    sp.set("row_groups", row_groups)
-    doc["zorder"] = {
-        "columns": list(zcols),
-        "bits": _Z_BITS,
-        "nplanes": int(nplanes or 1),
-        "specs": [
-            ["dict", s[1]]
-            if s[0] == "dict"
-            else ["range", str(int(s[1])), str(int(s[2]))]
-            for s in specs
-        ],
-    }
+    return nplanes, specs, spans_of
 
 
 # ---------------------------------------------------------------------------

@@ -10,8 +10,10 @@ split into ~targetSourceBytesPerPartition files (the reference's
 
 from __future__ import annotations
 
+import dataclasses
 import math
-from typing import Dict, List, Optional, Tuple
+import os
+from typing import Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
@@ -41,6 +43,20 @@ class ZOrderCoveringIndex(Index):
         self.schema_json = schema_json
         self.target_bytes_per_partition = int(target_bytes_per_partition)
         self.properties: Dict[str, str] = dict(properties or {})
+        # what the last in-memory write into a version directory knows of
+        # its files' z-spans, until the zone-map capture of that
+        # directory takes it: of this object only, never of the index
+        # (not in to_dict, __eq__ or __hash__)
+        self._written_zspans: Optional[WrittenZSpans] = None
+
+    def take_written_zspans(self) -> Optional["WrittenZSpans"]:
+        """Hand over, once, what the last write / optimize / refresh of
+        THIS object learned of the files it wrote (None after a streamed
+        or an empty write, and once taken): the zone-map capture that
+        follows in the same action (``zonemaps.capture_safely(dir,
+        index)``) is its one reader."""
+        written, self._written_zspans = self._written_zspans, None
+        return written
 
     def __eq__(self, other):
         return (
@@ -95,13 +111,13 @@ class ZOrderCoveringIndex(Index):
     def write(self, ctx, index_data: ColumnarBatch) -> None:
         """Z-sort + size-targeted split write
         (ZOrderCoveringIndex.write:97-154)."""
-        _write_zordered(
+        self._written_zspans = _write_zordered(
             ctx, index_data, self._indexed_columns, self.target_bytes_per_partition
         )
 
     def optimize(self, ctx, files_to_optimize: List[str]) -> None:
         batch = ColumnarBatch.from_arrow(pio.read_table(files_to_optimize, None))
-        _write_zordered(
+        self._written_zspans = _write_zordered(
             ctx, batch, self._indexed_columns, self.target_bytes_per_partition
         )
 
@@ -150,7 +166,7 @@ class ZOrderCoveringIndex(Index):
             mode = UpdateMode.MERGE
         if scans:
             combined = scans[0] if len(scans) == 1 else CompositeScan(tuple(scans))
-            _write_zordered(
+            self._written_zspans = _write_zordered(
                 ctx,
                 lazy_or_materialized(ctx, combined),
                 self._indexed_columns,
@@ -194,9 +210,27 @@ class ZOrderCoveringIndex(Index):
 _Z_BITS = 16
 
 
+@dataclasses.dataclass(frozen=True)
+class WrittenZSpans:
+    """What an in-memory z-order write knows of the files it has just
+    written, kept for the zone-map capture that follows
+    (``zonemaps._capture_zspans``) so that it need not read them back:
+    the frozen encoder spec, and for every file the rows of each row
+    group as the write cut them with the packed z-address of the row
+    group's first and last row. The rows were written in sorted order, so
+    those two ARE the row group's least and greatest address. A few
+    hundred integers: the planes themselves are not kept."""
+
+    bits: int
+    nplanes: int
+    specs: list  # ZOrderEncoder.specs, one a column
+    # absolute path -> (rows of each row group, (z_lo, z_hi) of each)
+    files: Dict[str, Tuple[List[int], List[Tuple[int, int]]]]
+
+
 def _write_zordered(
     ctx, data, indexed_cols: List[str], target_bytes: int
-) -> List[str]:
+) -> Optional[WrittenZSpans]:
     """Global z-sort then split into ~equal files sized to hit the target
     partition bytes — bytes of the index's columns as they lie IN MEMORY
     (``table.nbytes``), not of the source on disk as the key
@@ -204,14 +238,17 @@ def _write_zordered(
     file sizing"). ``data`` is a ColumnarBatch or (for datasets beyond
     the build memory budget) a lazy SourceScan streamed in two passes.
 
+    Returns what the zone-map capture can use in place of a second read
+    (:class:`WrittenZSpans`) where one sort ordered every row written —
+    the in-memory path; None from the streamed build and an empty batch,
+    whose directories the capture reads back.
+
     Build stages under the action's root (``covering_build.stage``):
     ``zorder_encode`` (order encodings + min/max), ``zorder_interleave``
     (host words, both transfers and the kernel: ``ops/zorder``),
     ``zorder_sort`` (``ops/sort.lexsort_perm``; ``h2d``/``kernel``/
     ``d2h`` under it when it takes the device arm), ``take``,
     ``to_arrow``, ``write``."""
-    import os
-
     from hyperspace_tpu.indexes import covering_build
     from hyperspace_tpu.indexes.covering_build import CompositeScan, SourceScan
     from hyperspace_tpu.ops.sort import lexsort_perm
@@ -219,12 +256,11 @@ def _write_zordered(
 
     os.makedirs(ctx.index_data_path, exist_ok=True)
     if isinstance(data, (SourceScan, CompositeScan)):
-        return _write_zordered_streaming(
-            ctx, data, indexed_cols, target_bytes
-        )
+        _write_zordered_streaming(ctx, data, indexed_cols, target_bytes)
+        return None
     batch = data
     if batch.num_rows == 0:
-        return []
+        return None
     conf = ctx.session.conf
     with covering_build.stage("zorder_encode"):
         encoder, encs = ZOrderEncoder.fit(
@@ -241,35 +277,64 @@ def _write_zordered(
         batch = batch.take(perm)
     with covering_build.stage("to_arrow"):
         table = batch.to_arrow()
-    return _write_parts(ctx, table, target_bytes, 0)
+    written = _write_parts(ctx, table, target_bytes, 0)
+    return _written_zspans(
+        encoder, planes, perm, written, _part_cuts(table, target_bytes)
+    )
+
+
+def _part_cuts(table, target_bytes: int) -> Iterator[Tuple[int, int, int]]:
+    """(part number, first row, rows) of each file a z-sorted table is
+    cut into: ~equal files of about ``target_bytes`` of ``table.nbytes``
+    each, the empty ones left out."""
+    num_parts = max(1, math.ceil(max(table.nbytes, 1) / target_bytes))
+    rows_per_part = math.ceil(table.num_rows / num_parts)
+    for i in range(num_parts):
+        start = i * rows_per_part
+        rows = min(rows_per_part, table.num_rows - start)
+        if rows > 0:
+            yield i, start, rows
 
 
 def _write_parts(ctx, table, target_bytes: int, first_idx: int) -> List[str]:
     """One ``write`` stage (attrs ``files``, ``bytes``, ``rows``): a
-    z-sorted table cut into ~equal files of about ``target_bytes`` of
-    ``table.nbytes`` each, named from ``first_idx`` on."""
-    import os
-
+    z-sorted table cut into files (:func:`_part_cuts`), named from
+    ``first_idx`` on."""
     from hyperspace_tpu.indexes import covering_build
 
-    nbytes = max(table.nbytes, 1)
-    num_parts = max(1, math.ceil(nbytes / target_bytes))
-    rows_per_part = math.ceil(table.num_rows / num_parts)
     written = []
     with covering_build.stage("write") as sp:
-        for i in range(num_parts):
-            chunk = table.slice(i * rows_per_part, rows_per_part)
-            if chunk.num_rows == 0:
-                continue
+        for i, start, rows in _part_cuts(table, target_bytes):
             path = os.path.join(
                 ctx.index_data_path,
                 f"part-{first_idx + i:05d}-zorder.parquet",
             )
-            pio.write_table(path, chunk)
+            pio.write_table(path, table.slice(start, rows))
             written.append(path)
         covering_build.count_written(sp, written)
         sp.set("rows", int(table.num_rows))
     return written
+
+
+def _written_zspans(encoder, planes, perm, written: List[str], cuts) -> WrittenZSpans:
+    """The hand-off of one sorted write: for each file (``written`` beside
+    its cut of the sorted order) the row groups ``pio.write_table`` cuts
+    (``pio.INDEX_ROW_GROUP_SIZE`` rows, the last one shorter) and, from
+    the planes through the permutation, the packed address of each row
+    group's first and last row — two look-ups a row group."""
+    from hyperspace_tpu.ops.zorder import planes_z_at
+
+    group = pio.INDEX_ROW_GROUP_SIZE
+    files = {}
+    for path, (_i, start, rows) in zip(written, cuts):
+        firsts = np.arange(start, start + rows, group)
+        lasts = np.minimum(firsts + group, start + rows) - 1
+        z = planes_z_at(planes, perm[np.concatenate([firsts, lasts])])
+        files[os.path.abspath(path)] = (
+            (lasts - firsts + 1).tolist(),
+            list(zip(z[: len(firsts)], z[len(firsts) :])),
+        )
+    return WrittenZSpans(encoder.bits, int(planes.shape[0]), encoder.specs, files)
 
 
 # range-partition count for the streamed z-order spill: top bits of the
@@ -301,7 +366,6 @@ def _write_zordered_streaming(
     with the re-encode inside it —, ``zorder_sort``, ``take``,
     ``to_arrow``, ``write``): once a wave or a range, never a row group.
     """
-    import os
     import shutil
 
     from hyperspace_tpu.indexes import covering_build

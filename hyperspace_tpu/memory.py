@@ -288,11 +288,13 @@ ALLOC_SITES: Dict[str, Tuple[str, str, str]] = {
         "reads one source file per iteration and keeps only its O(1) "
         "sketch row; peak residency is the largest single file",
     ),
-    "hyperspace_tpu.indexes.zonemaps._capture_zspans": (
+    "hyperspace_tpu.indexes.zonemaps._spans_by_reread": (
         "build",
         "chunk-bounded",
-        "two per-file passes that read one file at a time and retain "
-        "only per-file span cells; bounded by the largest single file",
+        "the z-span capture where no in-memory write handed its spans "
+        "over (streamed build, quantile encoder, a mismatch): two "
+        "per-file passes that read one file at a time and retain only "
+        "per-file span cells; bounded by the largest single file",
     ),
     # -- maintenance plane: optimize / refresh subsets -----------------------
     "hyperspace_tpu.indexes.covering_build.rewrite_files": (

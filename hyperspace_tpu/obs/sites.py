@@ -319,8 +319,10 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
         "span",
         "sidecar_capture (zonemap): the footer pass and its publish, "
         "the same stage name with its own sidecar attr; on a z-order "
-        "directory the z-span capture's parts are attrs (zspan_fit_s, "
-        "zspan_planes_s, zspan_minmax_s, row_groups)",
+        "directory row_groups with zspans_from_write / zspans_reread "
+        "(row groups by where their span came from) and, where the "
+        "re-read ran, its parts (zspan_fit_s, zspan_planes_s, "
+        "zspan_minmax_s) are attrs",
     ),
     "hyperspace_tpu.actions.base.Action.run": (
         "span",

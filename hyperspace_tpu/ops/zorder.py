@@ -355,6 +355,23 @@ def z_box_ranges(word_lo, word_hi, bits: int, max_ranges: int = 64):
     return merged
 
 
+def _pack_z(words) -> int:
+    """One row's planes, most-significant first, as one python int: the
+    PACKED (32 bits a plane) z-space every captured span is stored in."""
+    z = 0
+    for w in words:
+        z = (z << 32) | int(w)
+    return z
+
+
+def planes_z_at(planes: np.ndarray, rows) -> List[int]:
+    """Packed z-addresses (as :func:`planes_z_minmax` packs them) of the
+    given row positions of ``planes`` — a gather of ``len(rows)``
+    columns, whatever the planes' length."""
+    picked = planes[:, np.asarray(rows, dtype=np.int64)]
+    return [_pack_z(picked[:, i]) for i in range(picked.shape[1])]
+
+
 def planes_z_minmax(planes: np.ndarray, start: int, end: int):
     """(z_lo, z_hi) python ints of rows [start, end) of ``planes``
     ([nplanes, n] uint32, most-significant plane first), in PACKED
@@ -366,17 +383,10 @@ def planes_z_minmax(planes: np.ndarray, start: int, end: int):
     n = sub.shape[1]
     if n == 0:
         return None
-
-    def pack(col) -> int:
-        z = 0
-        for w in col:
-            z = (z << 32) | int(w)
-        return z
-
     if sub.shape[0] == 1:
         return int(sub[0].min()), int(sub[0].max())
     order = np.lexsort(sub[::-1])
-    return pack(sub[:, order[0]]), pack(sub[:, order[-1]])
+    return _pack_z(sub[:, order[0]]), _pack_z(sub[:, order[-1]])
 
 
 def pack_box_ranges(ranges, bits: int, k: int, nplanes: int):

@@ -11,7 +11,9 @@ answer served as ``ZOCI``, and the spans and counters the build records.
 
 import datetime
 import glob
+import json
 import os
+import shutil
 import sys
 
 import numpy as np
@@ -31,9 +33,9 @@ import reference_zorder as rz  # noqa: E402
 
 from hyperspace_tpu import constants as C  # noqa: E402
 from hyperspace_tpu.hyperspace import Hyperspace  # noqa: E402
-from hyperspace_tpu.indexes import covering_build  # noqa: E402
+from hyperspace_tpu.indexes import covering_build, zonemaps  # noqa: E402
 from hyperspace_tpu.indexes.covering import CoveringIndexConfig  # noqa: E402
-from hyperspace_tpu.indexes.zorder import ZOrderCoveringIndexConfig  # noqa: E402
+from hyperspace_tpu.indexes.zorder import ZOrderCoveringIndex, ZOrderCoveringIndexConfig  # noqa: E402
 from hyperspace_tpu.io.columnar import Column  # noqa: E402
 from hyperspace_tpu.obs import trace  # noqa: E402
 from hyperspace_tpu.ops import sort as sort_ops  # noqa: E402
@@ -204,8 +206,8 @@ def _inside(child, parent):
     return parent.start_ns <= child.start_ns <= child.end_ns <= parent.end_ns
 
 
-def test_a_zorder_create_names_its_stages_once_each(built):
-    indexed, _dir, cols, _session_, files, root = built
+def test_a_zorder_create_names_its_stages_once_each(built, tmp_path):
+    indexed, items_dir, cols, _session_, files, root = built
     top = _children(root, root)
     stages = [_one(top, name) for name in Z_STAGES]
     # one after another, each with an interval inside the root's
@@ -228,20 +230,110 @@ def test_a_zorder_create_names_its_stages_once_each(built):
     assert write.attrs["rows"] == rows == root.attrs["rows"]
     assert write.attrs["bytes"] == root.attrs["index_bytes"] == sum(map(os.path.getsize, files))
     assert root.attrs["h2d_bytes"] > 0 and root.attrs["d2h_bytes"] > 0
-    # the stages and the breakdown are one measurement (the last build's)
-    assert root.children_union_s() >= 0.9 * root.duration_s
+    # the named stages cover the build. The limit is a share of the wall
+    # clock of a 50 ms build, and a thread that loses its core between
+    # two stages for 5 ms is unnamed time too; a stage that has no name
+    # is unnamed in every build. So under load the best of three builds
+    # is held to the limit
+    covered = [root.children_union_s() / root.duration_s]
+    while covered[-1] < 0.9 and len(covered) < 3:
+        again = _build(str(tmp_path / f"again{len(covered)}"), items_dir, indexed)[2]
+        covered.append(again.children_union_s() / again.duration_s)
+    assert max(covered) >= 0.9, covered
 
 
-def test_the_zspan_capture_says_where_its_seconds_went(built):
+def _zonemap_capture(root):
+    return _one([s for s in root.spans if s.attrs.get("sidecar") == "zonemap"], "sidecar_capture")
+
+
+def test_the_zspan_capture_says_where_its_spans_came_from(built):
+    """A plain create sorts every row it writes in one piece, so the
+    capture takes each row group's span from the write and reads nothing
+    back: no second interleave, no seconds of a re-read."""
     _indexed, _dir, cols, _session_, _files, root = built
-    zonemap = _one([s for s in root.spans if s.attrs.get("sidecar") == "zonemap"], "sidecar_capture")
+    zonemap = _zonemap_capture(root)
     attrs = zonemap.attrs
-    for key in ("zspan_fit_s", "zspan_planes_s", "zspan_minmax_s"):
-        assert attrs[key] >= 0.0, key
-    assert attrs["zspan_fit_s"] + attrs["zspan_planes_s"] + attrs["zspan_minmax_s"] <= attrs["read_s"]
     assert attrs["row_groups"] == -(-len(cols["l_shipdate"]) // 65536)
-    # the capture interleaves the file's rows a second time
-    assert [s.name for s in _children(root, zonemap)] == ["words", "h2d", "kernel", "d2h"]
+    assert attrs["zspans_from_write"] == attrs["row_groups"] and attrs["zspans_reread"] == 0
+    assert not {"zspan_fit_s", "zspan_planes_s", "zspan_minmax_s"} & set(attrs)
+    assert _children(root, zonemap) == []
+    # jit__interleave is dispatched once a build: under zorder_interleave
+    for name in ("words", "h2d", "kernel", "d2h"):
+        assert _one(root.spans, name).parent_id == _one(root.spans, "zorder_interleave").span_id
+
+
+def _zspans(sidecar_dir):
+    """What the z-span capture wrote into a version directory's sidecar:
+    ({file: its row groups' spans}, the ``zorder`` block)."""
+    with open(os.path.join(sidecar_dir, zonemaps.SIDECAR_NAME), encoding="utf-8") as f:
+        doc = json.load(f)
+    return {name: entry["rg_zspans"] for name, entry in doc["files"].items()}, doc["zorder"]
+
+
+def recaptured(version_dir, indexed, tmp_path):
+    """The z-spans of a copy of ``version_dir`` captured by an index
+    object that wrote nothing: the two-pass re-read."""
+    copy = str(tmp_path / "recaptured" / os.path.basename(version_dir))
+    shutil.copytree(version_dir, copy)
+    os.remove(os.path.join(copy, zonemaps.SIDECAR_NAME))
+    assert zonemaps.capture_index_dir(copy, ZOrderCoveringIndex(indexed, [], "", 1 << 30))
+    return _zspans(copy)
+
+
+def test_the_sidecar_from_the_write_equals_the_re_reads(built, tmp_path):
+    indexed, _dir, cols, _session_, files, _root = built
+    version_dir = os.path.dirname(files[0])
+    spans, block = _zspans(version_dir)
+    assert (spans, block) == recaptured(version_dir, indexed, tmp_path)
+    assert block["columns"] == indexed and block["bits"] == 16
+    assert block["nplanes"] == -(-len(indexed) * 16 // 32)
+    assert [s[0] for s in block["specs"]] == ["range"] * len(indexed)
+    # and they are the reference's: the least and greatest address of the rows
+    mins, maxs = rz.min_max(cols, indexed)
+    z = rz.z_address(cols, indexed, mins, maxs) << np.uint64(32 * block["nplanes"] - 16 * len(indexed))
+    assert spans == {os.path.basename(files[0]): [[format(int(z.min()), "x"), format(int(z.max()), "x")]]}
+
+
+@pytest.fixture(scope="module")
+def several_row_groups(tmp_path_factory):
+    """400,000 rows: one file of seven row groups, the last one short."""
+    tmp = str(tmp_path_factory.mktemp("z_groups"))
+    items_dir, cols = datagen.gen_lineitem(tmp, 100_000, 4, 3500000011, cols=COLS)
+    session, files, root = _build(tmp + "/index", items_dir, Q6_COLS)
+    return items_dir, cols, session, files, root
+
+
+@pytest.mark.parametrize("year,hundredths,quantity", [(1993, 3, 24), (1995, 8, 25), (1997, 5, 24)])
+def test_a_range_answer_pruned_by_the_writes_spans_equals_the_references(
+        several_row_groups, year, hundredths, quantity):
+    items_dir, cols, session, files, root = several_row_groups
+    groups = pq.read_metadata(files[0]).num_row_groups
+    attrs = _zonemap_capture(root).attrs
+    assert len(files) == 1 and groups == 7
+    assert attrs["zspans_from_write"] == groups and attrs["zspans_reread"] == 0
+    session.enable_hyperspace()
+    query, params = _q6(session, items_dir, year, (hundredths - 1) / 100.0,
+                        (hundredths + 1) / 100.0, quantity)
+    assert "Hyperspace(Type: ZOCI," in query.explain()
+    mask = rz.range_rows(cols, *params)
+    assert mask.sum() > 0
+    got = reference.table_cols(query.collect())
+    assert reference.digest(got) == reference.digest({c: v[mask] for c, v in cols.items()})
+    read = zonemaps.last_prune_stats
+    assert read["zonemap_files_sidecar"] == 1 and read["row_groups_total"] == groups
+    assert 0 < read["row_groups_kept"] < groups, read
+
+
+def test_each_row_groups_span_is_the_references_least_and_greatest_address(several_row_groups):
+    _dir, cols, _session_, files, _root = several_row_groups
+    spans, block = _zspans(os.path.dirname(files[0]))
+    mins, maxs = rz.min_max(cols, Q6_COLS)
+    written = reference.table_cols(pq.read_table(files[0], columns=Q6_COLS))
+    z = rz.z_address(written, Q6_COLS, mins, maxs) << np.uint64(32 * block["nplanes"] - 16 * len(Q6_COLS))
+    want = [[format(int(z[at:at + 65536].min()), "x"), format(int(z[at:at + 65536].max()), "x")]
+            for at in range(0, len(z), 65536)]
+    assert spans == {os.path.basename(files[0]): want}
+    assert len(want) == 7 and len(z) % 65536 != 0
 
 
 def test_the_breakdown_is_the_same_measurement(table, tmp_path):
