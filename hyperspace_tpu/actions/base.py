@@ -168,7 +168,9 @@ class Action(abc.ABC):
         ``scan``, ``hash_shuffle``, ``dict_probe``, ``sort``, ``write``,
         ``sidecar_capture``). ``op()`` itself has no span: what the
         root's children leave uncovered IS the unnamed time
-        (``root.duration_s - root.children_union_s()``)."""
+        (``root.duration_s - root.children_union_s()``). The root's
+        counter ``cpu_s`` is the process's CPU seconds over the action
+        (``covering_build.stage`` puts the same on every stage)."""
         # configure, not just set_enabled: action-only processes (build
         # workers with no frontend) must still honor the trace bounds
         obs_trace.configure(self.session.conf)
@@ -178,6 +180,7 @@ class Action(abc.ABC):
         root = obs_trace.root(
             f"action.{type(self).__name__}", always=True, index=str(index_name)
         )
+        cpu0 = time.process_time_ns()
         with obs_trace.activate(root):
             try:
                 self._run_protocol()
@@ -186,6 +189,11 @@ class Action(abc.ABC):
                 root.set("status", "failed")
                 raise
             finally:
+                # the process's CPU seconds over the action, all threads:
+                # beside the root's seconds, the cores the action kept busy
+                root.set(
+                    "cpu_s", round((time.process_time_ns() - cpu0) / 1e9, 6)
+                )
                 root.finish()
 
     def _run_protocol(self) -> None:

@@ -16,9 +16,11 @@ in ``OBS_SITES`` (``obs/sites.py``) with a one-line justification.
   ``covering_build.stage`` — ``stage(...)`` inside that module) or
   registers metrics (``registry.counter`` /
   ``gauge`` / ``labeled_counter`` / ``stage_timer`` /
-  ``register_view`` / ``register_weak_view``) whose outermost
-  enclosing function (or module, for import-time registration) has no
-  ``OBS_SITES`` entry:
+  ``register_view`` / ``register_weak_view``) or reaches the span live
+  in its caller's context to put attrs on it (``trace.current()``: a
+  pass that says what its caller's stage's seconds went to) whose
+  outermost enclosing function (or module, for import-time
+  registration) has no ``OBS_SITES`` entry:
   undeclared instrumentation. Propagation shims (``trace.carry`` /
   ``activate``) and point events (``trace.event``) are exempt — they
   create no spans.
@@ -60,11 +62,13 @@ RULES = {
 #: candidate homes of the OBS_SITES literal, first hit wins
 REGISTRY_FILES = ("obs/sites.py", "sites.py")
 
-KINDS = ("span", "metric", "view")
+KINDS = ("span", "metric", "view", "attr")
 
 #: span-creating trace primitives (module alias must look like a trace
-#: module) and metric-registering registry primitives
+#: module), the accessor through which a pass puts attrs on a span it
+#: did not open, and metric-registering registry primitives
 TRACE_PRIMS = frozenset({"root", "span", "stage"})
+ATTR_PRIMS = frozenset({"current"})
 METRIC_PRIMS = frozenset(
     {
         "counter",
@@ -206,7 +210,7 @@ def _is_obs_call(node: ast.Call, rel: str = "") -> Optional[str]:
     if base is None:
         return None
     last = base.rsplit(".", 1)[-1]
-    if f.attr in TRACE_PRIMS and last in _TRACE_BASES:
+    if f.attr in (TRACE_PRIMS | ATTR_PRIMS) and last in _TRACE_BASES:
         return f.attr
     if f.attr == "stage" and last in _BUILD_HOOK_BASES:
         return f.attr

@@ -254,6 +254,13 @@ SHARED_STATE: Dict[str, Tuple[str, str, str]] = {
         "per-trace span cap republished whole by configure(); a stale "
         "read caps one trace at the previous bound",
     ),
+    "hyperspace_tpu.obs.trace._compile_listening": (
+        "hyperspace_tpu.obs.trace._rec_lock",
+        "guarded",
+        "the compile listeners' once-a-process latch: read and set "
+        "under the record lock by every root(), so two first roots "
+        "cannot both register",
+    ),
     "hyperspace_tpu.obs.trace._finished": (
         "hyperspace_tpu.obs.trace._rec_lock",
         "guarded",

@@ -130,7 +130,7 @@ class _OverCap(Exception):
 
 
 @contextlib.contextmanager
-def _outside(turn: Optional[threading.Lock]):
+def _outside(turn: Optional[threading.Lock], stats: dict, key: str):
     """Put ``turn`` down for a call that runs outside the interpreter
     lock (a parquet read, a kernel sweep), and take it up again after.
 
@@ -139,15 +139,31 @@ def _outside(turn: Optional[threading.Lock]):
     another at every small call, the Python part of a file cost 3.5
     times the CPU it costs alone (the chip's 13-core host, PERF.md §6
     PR 26) and the pool lost to a pool of two. Taking turns, only the
-    calls in here overlap — which is all that can."""
-    if turn is None:
-        yield
-        return
-    turn.release()
+    calls in here overlap — which is all that can.
+
+    This is also where a task's seconds are told apart: the call's own
+    go to ``stats[key]`` (``read_s`` or ``sweep_s``), and what it then
+    waits to have the turn back to ``stats["turn_wait_s"]``
+    (:func:`_take`). Two clock reads a call."""
+    t0 = _time.perf_counter()
+    if turn is not None:
+        turn.release()
     try:
         yield
     finally:
+        stats[key] += _time.perf_counter() - t0
+        if turn is not None:
+            _take(turn, stats)
+
+
+def _take(turn: threading.Lock, stats: dict) -> None:
+    """Take the turn; the seconds it was another task's go to
+    ``stats["turn_wait_s"]`` — none, and no clock read, where it was
+    free, so a capture of one task reads exactly 0."""
+    if not turn.acquire(blocking=False):
+        t0 = _time.perf_counter()
         turn.acquire()
+        stats["turn_wait_s"] += _time.perf_counter() - t0
 
 
 @functools.lru_cache(maxsize=None)
@@ -224,10 +240,12 @@ def _in_factorize_order(pt):
 def _sweep(PC, plan, batch, max_groups: int, stats, turn=None):
     """Partials of one row group under ``plan``, or None when a grouped
     pass is over ``max_groups``: the native one-pass kernel serve runs
-    (``hs_fused_filter_agg`` through ``AggState``), else — native not
-    loaded, a column outside the fused 8-byte set — its numpy twin
-    ``partials_from_batch``. The two are bit-identical per group, and
-    float sums keep the kernel's row order either way, with one
+    (``hs_fused_filter_agg`` through ``AggState``; outside the turn, its
+    seconds in ``stats["sweep_s"]``), else — native not loaded, a
+    column outside the fused 8-byte set — its numpy twin
+    ``partials_from_batch`` (Python, under the turn). The two are
+    bit-identical per group, and float sums keep the kernel's row order
+    either way, with one
     exception that the twin therefore decides: a float sum that came out
     NaN. Which NaN — the sign and payload of a data NaN, or of the one
     ``inf - inf`` makes — is the first operand's on x86, and the two
@@ -235,7 +253,7 @@ def _sweep(PC, plan, batch, max_groups: int, stats, turn=None):
     grouped = bool(plan.group_by)
     state = _capped_state_cls()(plan, max_groups if grouped else 1)
     try:
-        with _outside(turn):
+        with _outside(turn, stats, "sweep_s"):
             swept = state.accumulate(batch)
     except _OverCap:
         stats["sweeps_native"] += 1
@@ -254,8 +272,19 @@ def _sweep(PC, plan, batch, max_groups: int, stats, turn=None):
     return _in_factorize_order(pt) if reorder else pt
 
 
-#: what one ``file_agg_doc`` call adds up in its ``stats`` dict
-_FILE_STATS = ("read_s", "sweeps_native", "sweeps_twin", "early_rejects")
+#: what one ``file_agg_doc`` call adds up in its ``stats`` dict: the
+#: seconds of its calls outside the turn (``read_s``: the footer and the
+#: row groups from parquet; ``sweep_s``: the kernel's passes), the
+#: seconds it waited to take the turn up again after them, and the
+#: passes by what became of them
+_FILE_STATS = (
+    "read_s",
+    "sweep_s",
+    "turn_wait_s",
+    "sweeps_native",
+    "sweeps_twin",
+    "early_rejects",
+)
 
 
 def _capture_ops(count_only, numeric):
@@ -364,15 +393,20 @@ def file_agg_doc(
     query groups by, so a first serve over an unsidecar'd index pays one
     grouped sweep instead of one per numeric column; build-time capture
     leaves it None (every fusable candidate). ``stats`` has the
-    ``_FILE_STATS`` keys added to: the seconds spent reading and
-    decoding the row groups, the sweeps by the implementation that ran
-    them, and the grouped sweeps abandoned at the cap. ``turn`` is a
-    lock the caller holds, shared with other files' calls: it is put
-    down around the reads and the sweeps (:func:`_outside`)."""
+    ``_FILE_STATS`` keys added to: the seconds of the parquet reads and
+    of the kernel's sweeps (the calls made outside the turn), the
+    seconds waited for the turn after them, the sweeps by the
+    implementation that ran them, and the grouped sweeps abandoned at
+    the cap. ``turn`` is a lock the caller holds, shared with other
+    files' calls: it is put down around the reads and the sweeps
+    (:func:`_outside`); without one (the serve path's backfill) the
+    same two clock reads a call are all the account costs."""
     from hyperspace_tpu.execution import pipeline_compiler as PC
     from hyperspace_tpu.io.columnar import ColumnarBatch
 
-    with _outside(turn):
+    if stats is None:
+        stats = dict.fromkeys(_FILE_STATS, 0)
+    with _outside(turn, stats, "read_s"):
         pf = pq.ParquetFile(path)
     schema = pf.schema_arrow
     count_only, numeric = _capture_spec(schema)
@@ -398,17 +432,13 @@ def file_agg_doc(
         key_candidates = [c for c in key_candidates if c.lower() in wanted]
     for c in key_candidates:
         entry["groups"][c] = []
-    if stats is None:
-        stats = dict.fromkeys(_FILE_STATS, 0)
     ungrouped = _sweep_plan(PC, schema, None, ops)
     by_key = {kc: _sweep_plan(PC, schema, kc, ops) for kc in key_candidates}
     samples: List[pa.Table] = []
     for gi in range(pf.metadata.num_row_groups):
-        t_read = _time.perf_counter()
-        with _outside(turn):
+        with _outside(turn, stats, "read_s"):
             table = pf.read_row_group(gi)
         batch = ColumnarBatch.from_arrow(table)
-        stats["read_s"] += _time.perf_counter() - t_read
         n = batch.num_rows
         entry["rg_rows"].append(n)
         cols = _partials_to_cols(
@@ -502,10 +532,13 @@ def capture_index_dir(dir_path: str, index, conf=None) -> bool:
     )
     from hyperspace_tpu.indexes import covering_build
 
-    # build-tail I/O: one stage of the build's account. Its parts — the
-    # re-read of the files just written, their partials, the publish —
-    # are attrs of the one span, never a span per file; the work is a
-    # call of its own so that freeing the document is inside the stage
+    # build-tail I/O: one stage of the build's account. Its parts are
+    # attrs of the one span, never a span per file: the pool's wall
+    # (files_s, on so many workers), what the tasks' seconds went to —
+    # Python under the turn, the reads and the sweeps outside it, the
+    # wait for it (python_s, read_s, sweep_s, turn_wait_s) — the passes
+    # by kind, and the publish (publish_s, bytes); the work is a call of
+    # its own so that freeing the document is inside the stage
     with covering_build.stage("sidecar_capture", sidecar="aggstate") as sp:
         return _capture_files(dir_path, max_groups, sample_rows, sp)
 
@@ -514,7 +547,10 @@ def _map_files(fn, files: List[str]):
     """(``[fn(f) for f in files]``, workers): on a bounded pool of this
     call's own, as ``io/parquet._pool_map`` is — inline up to 4 files (a
     small refresh), and never ``scan_pool``, whose tasks may not wait on
-    each other."""
+    each other. ``workers`` goes on the capture's span beside
+    ``files_s``, the wall of this call: ``workers * files_s`` is the
+    pool's thread seconds, which the tasks' ``python_s + turn_wait_s +
+    sweep_s + read_s`` fill but for workers idle at the tail."""
     from hyperspace_tpu import native
 
     if len(files) <= 4:
@@ -545,15 +581,24 @@ def _capture_files(
 
     def file_doc(f: str):
         stats = dict.fromkeys(_FILE_STATS, 0)
-        t0 = _time.perf_counter()
-        with turn:
+        _take(turn, stats)
+        try:
+            waited, t0 = stats["turn_wait_s"], _time.perf_counter()
             entry, sample = file_agg_doc(
                 f, max_groups, sample_rows, stats=stats, turn=turn
             )
+            held_s = _time.perf_counter() - t0
+        finally:
+            turn.release()
+        # from its first taking the turn a task's seconds are one of
+        # three things: a call outside it, the wait to have it back, or
+        # Python with it held — the last is what is left of them
+        stats["python_s"] = held_s - (
+            stats["read_s"] + stats["sweep_s"] + stats["turn_wait_s"] - waited
+        )
         st = os.stat(f)
         entry["size"] = st.st_size
         entry["mtime_ns"] = st.st_mtime_ns
-        stats["partials_s"] = _time.perf_counter() - t0 - stats["read_s"]
         return entry, sample, stats
 
     # the files are independent, and their reads and sweeps run outside
@@ -572,7 +617,9 @@ def _capture_files(
     sp.set("files", len(files))
     sp.set("workers", workers)
     sp.set("files_s", round(t_publish - t_files, 6))
-    for k in (*_FILE_STATS, "partials_s"):  # sums over files, like sum_s
+    # sums over the files, like sum_s. python_s, the seconds the turn
+    # was held, is serial by construction: a floor of files_s
+    for k in ("python_s", *_FILE_STATS):
         sp.set(k, round(sum(st[k] for st in stats), 6))
     side_path = os.path.join(dir_path, SIDECAR_NAME)
     tmp = os.path.join(dir_path, f".{SIDECAR_NAME}.tmp.{os.getpid()}")

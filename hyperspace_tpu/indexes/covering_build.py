@@ -73,20 +73,62 @@ def _scan_with_lineage(
     file_ids: Optional[Dict[str, int]],
 ) -> ColumnarBatch:
     """Read the projection from each source file; attach `_data_file_id`
-    when lineage is on (CoveringIndex.createIndexData:177-186)."""
+    when lineage is on (CoveringIndex.createIndexData:177-186).
+
+    The four phases — the parquet read, the decode to columns, the
+    lineage fill, the one concat of the lot — are timed here, where they
+    run, and added to the span live in the caller's context
+    (``read_s``, ``decode_s``, ``lineage_s``, ``concat_s``: sums over
+    the files, which are read one after another, so they add up to a
+    ``scan`` stage but for the loop around them; ``max_read_s`` is the
+    slowest file's read). That span is the ``scan`` stage of an
+    in-memory build; the streamed build opens none around its waves, so
+    there they sum on the action's root."""
+    now = _time.perf_counter_ns
+    read_ns = decode_ns = lineage_ns = max_read_ns = 0
     batches = []
     for f in files:
+        t0 = now()
         t = pio.read_table([f], columns, fmt)
+        t1 = now()
         b = ColumnarBatch.from_arrow(t)
+        t2 = now()
         if file_ids is not None:
             fid = np.full(b.num_rows, file_ids[f], dtype=np.int64)
             b = b.with_column(
                 DATA_FILE_NAME_ID, Column("numeric", pa.int64(), values=fid)
             )
         batches.append(b)
+        read_ns += t1 - t0
+        max_read_ns = max(max_read_ns, t1 - t0)
+        decode_ns += t2 - t1
+        lineage_ns += now() - t2
     if not batches:
         raise HyperspaceException("No source files to index")
-    return ColumnarBatch.concat(batches)
+    t0 = now()
+    out = ColumnarBatch.concat(batches)
+    sp = _obs_trace.current()
+    if sp is not None:
+        _add_seconds(
+            sp,
+            read_s=read_ns,
+            decode_s=decode_ns,
+            lineage_s=lineage_ns,
+            concat_s=now() - t0,
+        )
+        sp.set(
+            "max_read_s",
+            max(sp.attrs.get("max_read_s", 0.0), round(max_read_ns / 1e9, 6)),
+        )
+    return out
+
+
+def _add_seconds(sp, **ns: int) -> None:
+    """Add each ``key=nanoseconds`` into the span's attr of that name,
+    as seconds: a pass that runs more than once under one span (a
+    composite scan's parts) sums there, as ``sum_s`` does."""
+    for key, value in ns.items():
+        sp.set(key, round(sp.attrs.get(key, 0.0) + value / 1e9, 6))
 
 
 @dataclasses.dataclass
@@ -473,14 +515,32 @@ def stage(name: str, **attrs):
     on the device trace's clock) and on exit adds the SAME seconds to
     ``last_build_breakdown[name]`` — one measurement, two views. Yields
     the span, for attrs that summarize repeated work (``buckets``,
-    ``sum_s``, ``max_s``: never a span per bucket or per file). Outside
-    an action the span is the no-op singleton and only the breakdown is
-    fed."""
+    ``sum_s``, ``max_s``: never a span per bucket or per file). Every
+    stage span also says what CPU its seconds had: ``cpu_s``, the
+    process's CPU seconds over the stage (``time.process_time_ns``: all
+    threads, pyarrow's and the native pools' included), so ``cpu_s /
+    seconds`` is the cores the stage kept busy and a stage that waited
+    reads wall up and ``cpu_s`` flat — or, on a span that names its
+    ``shard`` (the mesh's tails run side by side, and the process's
+    clock would count the neighbours), ``thread_cpu_s``, the task's own
+    ``time.thread_time_ns``. Outside an action the span is the no-op
+    singleton and only the breakdown is fed."""
     t0 = _time.perf_counter_ns()
+    # the shard tails run side by side: a span that names its shard
+    # holds its own thread's CPU seconds, under a name of their own
+    cpu_key, cpu_clock = (
+        ("thread_cpu_s", _time.thread_time_ns)
+        if "shard" in attrs
+        else ("cpu_s", _time.process_time_ns)
+    )
     sp = _obs_trace.NOOP
     try:
         with _obs_trace.span(name, **attrs) as sp:
-            yield sp
+            cpu0 = cpu_clock()
+            try:
+                yield sp
+            finally:
+                sp.set(cpu_key, round((cpu_clock() - cpu0) / 1e9, 6))
     finally:
         seconds = sp.duration_s
         if seconds is None:  # no live trace: the stage's own clock
@@ -498,13 +558,21 @@ def sidecar_published(sp, paths: Sequence[str], publish_s: float) -> None:
     _obs_trace.accumulate("sidecar_bytes", n_bytes)
 
 
-def _repeat_attrs(sp, seconds: Sequence[float], count_key: str) -> None:
+def _repeat_attrs(
+    sp, timings: Sequence[Tuple[float, float]], count_key: str
+) -> None:
     """Summarize repeated work on its enclosing span — count, sum and
     the slowest one — so a single stalled bucket shows as ``max_s`` far
-    above ``sum_s / count`` without a span apiece."""
+    above ``sum_s / count`` without a span apiece. ``timings`` holds a
+    task's (seconds, CPU seconds of its own thread): ``cpu_sum_s`` is
+    the latter summed, so ``sum_s - cpu_sum_s`` is the seconds the tasks
+    were off a CPU (a native sort's helper threads are not the task's
+    thread: their CPU is in the stage's ``cpu_s`` alone)."""
+    seconds = [sec for sec, _cpu in timings]
     sp.set(count_key, len(seconds))
     sp.set("sum_s", round(float(sum(seconds)), 6))
     sp.set("max_s", round(float(max(seconds, default=0.0)), 6))
+    sp.set("cpu_sum_s", round(float(sum(cpu for _s, cpu in timings)), 6))
 
 
 def reset_build_breakdown() -> None:
@@ -545,6 +613,7 @@ def lazy_or_materialized(ctx, scan):
             # schema
             out = scan.empty_batch()
         sp.set("files", len(local.files))
+        sp.set("rows", int(out.num_rows))
         _obs_trace.accumulate("rows", int(out.num_rows))
         _obs_trace.accumulate("source_bytes", _files_bytes(local.files))
     return out
@@ -823,13 +892,17 @@ def count_written(sp, paths: List[str]) -> None:
     _obs_trace.accumulate("index_bytes", n_bytes)
 
 
-def _timed_write_bucket_file(*args) -> Tuple[str, float]:
+def _timed_write_bucket_file(*args) -> Tuple[str, float, float]:
     """``pio.write_bucket_file`` -> (path, its seconds on the writer
-    thread): the per-file unit behind a write stage's ``sum_s`` /
-    ``max_s``."""
-    t0 = _time.perf_counter()
+    thread, that thread's CPU seconds over them): the per-file unit
+    behind a write stage's ``sum_s`` / ``max_s`` / ``cpu_sum_s``."""
+    t0, cpu0 = _time.perf_counter(), _time.thread_time_ns()
     path = pio.write_bucket_file(*args)
-    return path, _time.perf_counter() - t0
+    return (
+        path,
+        _time.perf_counter() - t0,
+        (_time.thread_time_ns() - cpu0) / 1e9,
+    )
 
 
 def _single_process() -> bool:
@@ -933,7 +1006,7 @@ def _write_bucketed_pipelined(
         )
         if written is not None:
             return written
-    sort_s: List[float] = []
+    sort_s: List[Tuple[float, float]] = []
     futures = []
     with contextlib.ExitStack() as cleanup:
         with stage("sort"):
@@ -969,10 +1042,12 @@ def _write_bucketed_pipelined(
             # in submission order: ascending bucket id
             done = [f.result() for f in futures]
             pool.shutdown()  # the writers' way out is the drain's too
-            written = [path for path, _s in done]
+            written = [path for path, _s, _cpu in done]
             # every file's seconds on its writer's thread, those that
             # ran under the sort stage included
-            _repeat_attrs(write_sp, [sec for _p, sec in done], "buckets")
+            _repeat_attrs(
+                write_sp, [(sec, cpu) for _p, sec, cpu in done], "buckets"
+            )
             count_written(write_sp, written)
     return written
 
@@ -1055,7 +1130,7 @@ def _write_bucketed_sharded(
 
     def run_shard(s: int) -> List[Tuple[int, str]]:
         lo, hi = int(shard_offs[s]), int(shard_offs[s + 1])
-        sort_s: List[float] = []
+        sort_s: List[Tuple[float, float]] = []
         # one writer thread per shard: bucket i+1 sorts while bucket i
         # writes, exactly the single-tail pipeline, D of them in flight
         with ThreadPoolExecutor(max_workers=1) as writer:
@@ -1089,9 +1164,11 @@ def _write_bucketed_sharded(
                 _repeat_attrs(sort_sp, sort_s, "buckets")
             with stage("write", shard=s) as write_sp:
                 done = [(b, f.result()) for b, f in futures]
-                out = [(b, path) for b, (path, _sec) in done]
+                out = [(b, path) for b, (path, _sec, _cpu) in done]
                 _repeat_attrs(
-                    write_sp, [sec for _b, (_p, sec) in done], "buckets"
+                    write_sp,
+                    [(sec, cpu) for _b, (_p, sec, cpu) in done],
+                    "buckets",
                 )
                 count_written(write_sp, [path for _b, path in out])
         return out

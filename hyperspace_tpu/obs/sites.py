@@ -3,7 +3,9 @@
 The SHARED_STATE / KERNEL_TWINS / COLLECTIVE_SITES doctrine applied to
 the observability plane: every call site that CREATES spans
 (``trace.root`` / ``trace.span`` / ``trace.stage``, and the build
-plane's one stage hook ``covering_build.stage``) or REGISTERS
+plane's one stage hook ``covering_build.stage``), puts ATTRS on a span
+it did not open (``trace.current()``: a pass that says what its
+caller's stage's seconds went to) or REGISTERS
 metrics (``registry.counter`` / ``gauge`` / ``labeled_counter`` /
 ``stage_timer`` / ``register_view`` / ``register_weak_view``) declares
 itself HERE with a
@@ -16,7 +18,7 @@ row. Propagation shims (``trace.carry``/``activate``) and point events
 Entry shape::
 
     "<dotted path of the function, method, or module>": (
-        "<kind: span | metric | view>",
+        "<kind: span | metric | view | attr>",
         "<one-line justification — why this site is instrumented>",
     )
 
@@ -32,7 +34,9 @@ is not listed below — a misspelled span name would silently fork the
 taxonomy the querylog, the benchmark's ``action_trace`` reader and
 docs/observability.md all key on. A span is declared where the time
 is, at a layer boundary — never per row, per bucket or per file:
-repeated work is summarized as attrs on its enclosing span.
+repeated work is summarized as attrs on its enclosing span, and so is
+what a stage's seconds went to (phases that add up to it, CPU beside
+wall, waiting apart from work: docs/observability.md has the table).
 
 Keep this module stdlib-only and import-cheap: the analyzer only ever
 parses it, and the obs plane imports it for the vocabulary.
@@ -43,7 +47,7 @@ from __future__ import annotations
 from typing import Dict, Tuple
 
 #: site kinds (HS903 rejects anything else)
-KINDS = ("span", "metric", "view")
+KINDS = ("span", "metric", "view", "attr")
 
 #: serve-side stage spans — the last_serve_breakdown keys plus the
 #: frontend's admission stages (docs/observability.md "Span taxonomy")
@@ -212,7 +216,11 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
         "span",
         "the ONE build stage hook: the stage span (and its hs.<name> "
         "profiler annotation) and the breakdown increment are the same "
-        "measurement, so they cannot disagree",
+        "measurement, so they cannot disagree; it also reads the CPU "
+        "clock at entry and exit — cpu_s (the process's, all threads) "
+        "on every stage, thread_cpu_s (the task's own thread) on a "
+        "span that names its shard — so a stage that waited is told "
+        "from one that worked",
     ),
     "hyperspace_tpu.indexes.covering_build.prepare_covering_index": (
         "span",
@@ -223,7 +231,16 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
     "hyperspace_tpu.indexes.covering_build.lazy_or_materialized": (
         "span",
         "scan stage: the projected source read; counts rows and source "
-        "bytes at the boundary where they enter the build",
+        "bytes at the boundary where they enter the build (files and "
+        "rows as attrs beside what _scan_with_lineage adds)",
+    ),
+    "hyperspace_tpu.indexes.covering_build._scan_with_lineage": (
+        "attr",
+        "read_s / decode_s / lineage_s / concat_s (sums over the files, "
+        "read one after another) and max_read_s on the span the scan "
+        "runs under: whether a 1.3 s scan is I/O, decode, the lineage "
+        "fill or the final copy decides its next cut, and the slowest "
+        "file's read is the stalled build's tell — never a span a file",
     ),
     "hyperspace_tpu.indexes.covering_build._hash_shuffle": (
         "span",
@@ -244,14 +261,17 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
         "span",
         "sort (partition / to_arrow / bucket_sorts) and write of the "
         "pipelined tail: per-bucket sorts and per-file writes are "
-        "summarized as buckets/sum_s/max_s attrs, so a stalled thread "
-        "shows without a span per bucket",
+        "summarized as buckets/sum_s/max_s/cpu_sum_s attrs (cpu_sum_s: "
+        "the tasks' own threads' CPU seconds, so sum_s - cpu_sum_s is "
+        "what they spent off a CPU), so a stalled thread shows without "
+        "a span per bucket",
     ),
     "hyperspace_tpu.indexes.covering_build._write_bucketed_sharded": (
         "span",
         "partition (order words) and to_arrow once before the shard "
         "pool, then one sort and one write span per SHARD tail (attr "
-        "shard), carried onto the shard pool's threads",
+        "shard; thread_cpu_s and cpu_sum_s as above), carried onto the "
+        "shard pool's threads",
     ),
     "hyperspace_tpu.parallel.shuffle._device_leg": (
         "span",
@@ -282,7 +302,15 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
         "span",
         "words / h2d / kernel / d2h: the z-address planes are host word "
         "scaling, two transfers and a device program; the 0.15 s kernel "
-        "inside seconds of zorder_interleave is told from them",
+        "inside seconds of zorder_interleave is told from them; words "
+        "names its passes as attrs (scale_s, stack_s, pad_s)",
+    ),
+    "hyperspace_tpu.ops.zorder.ZOrderEncoder.fit": (
+        "attr",
+        "order_s / minmax_s (sums over the columns) on the span the fit "
+        "runs under, zorder_encode in a build: which pass holds the "
+        "stage's seconds decides between fused numpy passes and a "
+        "native kernel — never a span a column",
     ),
     "hyperspace_tpu.ops.sort.lexsort_perm": (
         "span",
@@ -310,10 +338,13 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
         "span",
         "sidecar_capture (aggstate): build-tail I/O that re-reads every "
         "file just written, one pool task a file; files/workers/files_s "
-        "(the pool's wall), read_s/partials_s (summed over the files), "
-        "sweeps_native/sweeps_twin/early_rejects (row-group passes by "
-        "the implementation that ran them) and publish_s/bytes as "
-        "attrs, never a span per file",
+        "(the pool's wall), what the tasks' seconds went to, timed where "
+        "a task puts the turn down and takes it up (aggindex._outside, "
+        "_take) and summed over the files — python_s (turn held: "
+        "serial, a floor of files_s), read_s and sweep_s (outside it), "
+        "turn_wait_s — sweeps_native/sweeps_twin/early_rejects "
+        "(row-group passes by the implementation that ran them) and "
+        "publish_s/bytes as attrs, never a span per file",
     ),
     "hyperspace_tpu.indexes.zonemaps.capture_index_dir": (
         "span",
@@ -328,7 +359,13 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
         "span",
         "the lifecycle-action ROOT span, always recorded — every action "
         "is explainable after the fact, whatever the outcome and "
-        "whatever the serve-plane switch says",
+        "whatever the serve-plane switch says; counter cpu_s (the "
+        "process's CPU seconds over the action), and the root it opens "
+        "is what registers the compile listener (obs/trace."
+        "_listen_for_compiles, once a process where jax is imported): "
+        "compiles / compile_s / compile_cache_hits on the span a "
+        "compile ran under and on this root, so a warm-up build says "
+        "how many of its seconds were XLA's and under which stage",
     ),
     "hyperspace_tpu.actions.base.Action._run_protocol": (
         "span",

@@ -39,6 +39,15 @@ that instrumentation for the serve and build planes:
   imported — so on a profiled run every program span lies in the
   profiler's own trace, next to the device's "XLA Ops".
 
+* **Which span compiled.** Once ``jax`` is imported, :func:`root`
+  registers two ``jax.monitoring`` listeners (once a process): every
+  backend compile adds ``compiles`` and ``compile_s``, every
+  persistent-cache hit ``compile_cache_hits``, to the span live in the
+  compiling thread's context and to its root. jax reports a compile
+  that the cache served as a compile too (its seconds are then the
+  retrieval), so ``compiles - compile_cache_hits`` is what XLA built.
+  No span live: nothing recorded.
+
 * **Context propagation.** The current span rides a ``contextvars``
   ContextVar. Thread pools do not propagate context, so every pool
   boundary on the serve path (the shared ``io/scan.scan_pool``, the
@@ -96,6 +105,13 @@ _rec_lock = threading.Lock()
 #: finished ROOT spans, oldest-first (guarded by _rec_lock)
 _finished: deque = deque(maxlen=C.OBS_TRACE_RETAIN_DEFAULT)
 
+#: the compile listeners are registered (guarded by _rec_lock: once a
+#: process, by the first root() that finds jax imported)
+_compile_listening = False
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
 #: the active span of the calling context (set via activate()/span())
 _current: contextvars.ContextVar = contextvars.ContextVar(
     "hs_obs_span", default=None
@@ -126,6 +142,48 @@ def _annotation(name: str):
     if jax is None:
         return None
     return jax.profiler.TraceAnnotation("hs." + name)
+
+
+def _add_to_span_and_root(deltas: Dict[str, float]) -> None:
+    """Add ``deltas`` into the attrs of the calling context's span and
+    of its root (once, where they are one span), under the record lock:
+    a span carried onto pool threads may compile on several at once."""
+    cur = _current.get()
+    if cur is None:
+        return
+    targets = (cur,) if cur.root is cur else (cur, cur.root)
+    with _rec_lock:
+        for sp in targets:
+            for key, value in deltas.items():
+                sp.attrs[key] = round(sp.attrs.get(key, 0) + value, 6)
+
+
+def _on_compile_duration(event: str, duration: float, **_kw) -> None:
+    if event == _COMPILE_EVENT:
+        _add_to_span_and_root({"compiles": 1, "compile_s": duration})
+
+
+def _on_compile_event(event: str, **_kw) -> None:
+    if event == _CACHE_HIT_EVENT:
+        _add_to_span_and_root({"compile_cache_hits": 1})
+
+
+def _listen_for_compiles() -> None:
+    """Register the two ``jax.monitoring`` listeners, once a process and
+    only where ``jax`` is already imported (:func:`_annotation`'s rule:
+    a trace is never what imports it; a process without jax compiles
+    nothing). jax calls a listener in the thread that compiles, so the
+    span it finds in the context is the one the compile ran under."""
+    global _compile_listening
+    monitoring = getattr(sys.modules.get("jax"), "monitoring", None)
+    if monitoring is None:
+        return
+    with _rec_lock:  # a root takes it again for every span it finishes
+        if _compile_listening:
+            return
+        _compile_listening = True
+    monitoring.register_event_duration_secs_listener(_on_compile_duration)
+    monitoring.register_event_listener(_on_compile_event)
 
 
 class Span:
@@ -400,6 +458,7 @@ def root(name: str, *, always: bool = False, **attrs) -> Span:
     the build's account, recorded whatever the switch says)."""
     if not (always or _enabled):
         return NOOP
+    _listen_for_compiles()
     return Span(name, parent=None, attrs=attrs)
 
 

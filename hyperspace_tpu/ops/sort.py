@@ -250,9 +250,10 @@ def bucket_key_sort_runs(
     (``workers=1``, the shard thread is the concurrency unit) and hands
     each shard a slice of the native-sort thread budget.
 
-    ``seconds_out``, when given, receives each bucket's sort seconds
-    (appended from the sorting threads) — the build's trace keeps their
-    count, sum and max on ONE span instead of a span per bucket.
+    ``seconds_out``, when given, receives each bucket's (sort seconds,
+    CPU seconds of the sorting thread over them), appended from the
+    sorting threads — the build's trace keeps their count, sums and max
+    on ONE span instead of a span per bucket.
     """
     import time
     from concurrent.futures import ThreadPoolExecutor
@@ -268,14 +269,19 @@ def bucket_key_sort_runs(
         threads = max(1, n_threads or 1)
 
     def sort_one(b: int) -> np.ndarray:
-        t0 = time.perf_counter()
+        t0, cpu0 = time.perf_counter(), time.thread_time_ns()
         idx = order[offsets[b] : offsets[b + 1]]
         perm = lexsort_perm(
             np.ascontiguousarray(planes[:, idx]), n_threads=threads
         )
         out = idx[perm]
         if seconds_out is not None:
-            seconds_out.append(time.perf_counter() - t0)
+            seconds_out.append(
+                (
+                    time.perf_counter() - t0,
+                    (time.thread_time_ns() - cpu0) / 1e9,
+                )
+            )
         return out
 
     if workers == 1:

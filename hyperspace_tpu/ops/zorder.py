@@ -37,14 +37,17 @@ Upstream's ``ZOrderField`` instead keeps ``value − min`` at the bit
 length of ``max − min`` and interleaves through a bit-index map.
 
 Under a live trace ``planes_from_encodings`` is four spans — ``words``
-(the host's scaling, stack and padding), ``h2d``, ``kernel`` (dispatch
-to ``block_until_ready``), ``d2h`` — with the bytes each way counted on
-the root, as ``ops/hash.bucket_ids_np`` has them.
+(the host's scaling, stack and padding: ``scale_s``, ``stack_s``,
+``pad_s`` on it), ``h2d``, ``kernel`` (dispatch to
+``block_until_ready``), ``d2h`` — with the bytes each way counted on
+the root, as ``ops/hash.bucket_ids_np`` has them; ``ZOrderEncoder.fit``
+adds ``order_s`` and ``minmax_s`` to the span it runs under.
 """
 
 from __future__ import annotations
 
 import functools
+import time
 from typing import List, Tuple
 
 import jax
@@ -131,17 +134,29 @@ class ZOrderEncoder:
         columns: List, bits: int, quantile: bool, relative_error: float
     ):
         """(encoder, per-column encodings) from in-memory Columns — the
-        encodings are returned so the caller never encodes twice."""
+        encodings are returned so the caller never encodes twice.
+
+        What the seconds went to is added to the span live in the
+        caller's context (the ``zorder_encode`` stage of a build):
+        ``order_s``, the order encodings, and ``minmax_s``, each
+        encoding's min and max (or its quantile sample), summed over the
+        columns. Outside an action nothing is recorded."""
+        now = time.perf_counter_ns
+        order_ns = minmax_ns = 0
         specs = []
         encs = []
         for col in columns:
+            t0 = now()
             if col.kind == "string":
                 spec = ("dict", sorted(set(col.dictionary)))
                 specs.append(spec)
                 encs.append(_dict_encode(col, spec[1]))
+                order_ns += now() - t0
                 continue
             e = order_u64_np(col)
             encs.append(e)
+            t1 = now()
+            order_ns += t1 - t0
             if quantile:
                 max_sample = max(
                     int(1.0 / max(relative_error, 1e-4) ** 2), 1024
@@ -158,6 +173,11 @@ class ZOrderEncoder:
                         e.max() if len(e) else np.uint64(0),
                     )
                 )
+            minmax_ns += now() - t1
+        sp = _obs_trace.current()
+        if sp is not None:
+            for key, ns in (("order_s", order_ns), ("minmax_s", minmax_ns)):
+                sp.set(key, round(sp.attrs.get(key, 0.0) + ns / 1e9, 6))
         return ZOrderEncoder(bits, specs), encs
 
     # -- encoding -----------------------------------------------------------
@@ -196,10 +216,17 @@ class ZOrderEncoder:
         from hyperspace_tpu.ops import pad_len
 
         n = len(encs[0]) if encs else 0
-        with _obs_trace.span("words"):
-            words = np.stack(
-                [self._words(e, s) for e, s in zip(encs, self.specs)]
-            ) if encs else np.zeros((0, 0), dtype=np.uint32)
+        with _obs_trace.span("words") as sp:
+            t0 = time.perf_counter_ns()
+            scaled = [self._words(e, s) for e, s in zip(encs, self.specs)]
+            t1 = time.perf_counter_ns()
+            words = (
+                np.stack(scaled)
+                if encs
+                else np.zeros((0, 0), dtype=np.uint32)
+            )
+            del scaled  # as before: the columns' words die with the stack
+            t2 = time.perf_counter_ns()
             n_pad = pad_len(max(n, 1))
             if n_pad != n:
                 fill = np.full(
@@ -207,6 +234,12 @@ class ZOrderEncoder:
                     np.uint32((1 << self.bits) - 1),
                 )
                 words = np.concatenate([words, fill], axis=1)
+            # what the span's seconds went to: the columns' scaling to
+            # words, their stack into one array, the pad to the
+            # program's length
+            sp.set("scale_s", round((t1 - t0) / 1e9, 6))
+            sp.set("stack_s", round((t2 - t1) / 1e9, 6))
+            sp.set("pad_s", round((time.perf_counter_ns() - t2) / 1e9, 6))
         with _obs_trace.span("h2d", bytes=int(words.nbytes)):
             on_device = jax.block_until_ready(jnp.asarray(words))
         with _obs_trace.span("kernel"):

@@ -675,7 +675,8 @@ class TestCaptureSweep:
             out = real(path, *a, **k)
             i = files.index(path)
             if i + 1 < len(files):
-                with aggindex._outside(k["turn"]):  # as a read would wait
+                # as a read would wait
+                with aggindex._outside(k["turn"], k["stats"], "read_s"):
                     assert done[files[i + 1]].wait(30), "tasks did not overlap"
             finished.append(path)
             done[path].set()

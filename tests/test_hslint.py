@@ -1789,6 +1789,41 @@ class TestObsSites:
         assert len(findings) == 1
         assert "pkg.rogue.hot_loop" in findings[0].message
 
+    @pytest.mark.parametrize("declared", [False, True])
+    def test_attrs_on_the_callers_span_are_a_site(self, tmp_path, declared):
+        """A pass that reaches the live span (``trace.current()``) to say
+        what its caller's stage's seconds went to is instrumentation
+        too: declared with kind ``attr``, or HS901."""
+        registry = OBS_REGISTRY.replace(
+            'KINDS = ("span", "metric", "view")',
+            'KINDS = ("span", "metric", "view", "attr")',
+        )
+        if declared:
+            registry = registry.replace(
+                '"pkg.app.serve": ("span", "roots the query at admission"),',
+                '"pkg.app.serve": ("span", "roots the query at admission"),\n'
+                '        "pkg.scan.read_files": ("attr", "read_s on the scan"),',
+            )
+        files = {
+            "sites.py": registry,
+            "app.py": OBS_APP,
+            "scan.py": """
+                from pkg.obs import trace as _obs_trace
+
+                def read_files(files):
+                    sp = _obs_trace.current()
+                    if sp is not None:
+                        sp.set("read_s", 0.0)
+            """,
+        }
+        findings = [f for f in _lint(tmp_path, files) if f.rule.startswith("HS9")]
+        if declared:
+            assert findings == []
+        else:
+            assert [f.rule for f in findings] == ["HS901"]
+            assert "pkg.scan.read_files" in findings[0].message
+            assert "'current'" in findings[0].message
+
     def test_nested_def_attributes_to_outermost(self, tmp_path):
         files = {
             "sites.py": OBS_REGISTRY,

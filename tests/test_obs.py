@@ -570,10 +570,11 @@ class TestActionAccount:
             if sp.name == "sidecar_capture"
         }
         assert set(sidecars) == {"aggstate", "zonemap"}
-        for key in ("files", "read_s", "partials_s", "publish_s", "bytes",
-                    "workers", "files_s", "sweeps_native", "sweeps_twin",
-                    "early_rejects"):
+        for key in ("files", "python_s", "read_s", "sweep_s", "turn_wait_s",
+                    "publish_s", "bytes", "workers", "files_s",
+                    "sweeps_native", "sweeps_twin", "early_rejects"):
             assert key in sidecars["aggstate"], key
+        assert "partials_s" not in sidecars["aggstate"]
 
     def test_aggstate_span_counts_its_sweeps(
         self, session_factory, tmp_path, monkeypatch
@@ -609,10 +610,19 @@ class TestActionAccount:
         if native.load() is not None:
             assert at["sweeps_twin"] == 0
         assert 0 <= at["early_rejects"] <= at["sweeps_native"]
-        # read_s and partials_s are sums over the files, on their threads
-        for key in ("read_s", "partials_s", "files_s", "publish_s"):
+        # what the tasks' seconds went to, summed over the files on their
+        # threads: Python under the turn, the reads and the sweeps
+        # outside it, the wait for it
+        parts = ("python_s", "read_s", "sweep_s", "turn_wait_s")
+        for key in (*parts, "files_s", "publish_s"):
             assert at[key] >= 0.0, key
         assert at["files_s"] + at["publish_s"] <= spans[0].duration_s
+        # the turn is one task's at a time: its seconds cannot pass the
+        # pool's wall, and the four cannot pass the pool's thread seconds
+        assert at["python_s"] <= at["files_s"] + 1e-3
+        assert sum(at[k] for k in parts) <= at["workers"] * at["files_s"] + 1e-3
+        if at["sweeps_native"]:
+            assert at["sweep_s"] > 0.0
 
     def test_breakdown_is_the_same_measurement(
         self, session_factory, tmp_path
@@ -654,6 +664,162 @@ class TestActionAccount:
         for sp in (by_name["bucket_sorts"], by_name["write"]):
             assert sp.attrs["max_s"] <= sp.attrs["sum_s"] + 1e-9
         assert by_name["write"].attrs["files"] == root.attrs["index_files"]
+
+    def test_one_worker_capture_never_waits_for_the_turn(
+        self, session_factory, tmp_path
+    ):
+        """Up to four files the capture runs inline: the turn is always
+        free, no wait is counted, and the task's three other states are
+        the whole of ``files_s``."""
+        _build(session_factory, tmp_path, warm=False, buckets=4)
+        root = trace.finished("action.CreateAction")[-1]
+        (at,) = [
+            sp.attrs for sp in root.spans
+            if sp.name == "sidecar_capture" and sp.attrs["sidecar"] == "aggstate"
+        ]
+        assert at["files"] == 4 and at["workers"] == 1
+        assert at["turn_wait_s"] == 0
+        account = at["python_s"] + at["read_s"] + at["sweep_s"]
+        assert account <= at["files_s"] + 1e-4
+        assert account >= 0.9 * at["files_s"]
+
+    def test_scan_span_says_what_its_seconds_went_to(
+        self, session_factory, tmp_path
+    ):
+        _build(session_factory, tmp_path)
+        root = trace.finished("action.CreateAction")[-1]
+        (scan,) = [sp for sp in root.spans if sp.name == "scan"]
+        at = scan.attrs
+        assert at["files"] == 4 and at["rows"] == root.attrs["rows"] == 20_000
+        phases = [at[k] for k in ("read_s", "decode_s", "lineage_s", "concat_s")]
+        assert all(p >= 0.0 for p in phases)
+        # one file after another: the phases add up to the span but for
+        # the loop around them
+        assert sum(phases) <= scan.duration_s + 1e-6
+        assert sum(phases) >= 0.9 * scan.duration_s
+        assert 0.0 < at["max_read_s"] <= at["read_s"] + 1e-9
+        assert not [sp for sp in root.spans if sp.parent_id == scan.span_id]
+
+    def test_every_stage_holds_its_cpu_seconds(self, session_factory, tmp_path):
+        from hyperspace_tpu.indexes import covering_build
+
+        _build(session_factory, tmp_path, warm=False, buckets=200)
+        root = trace.finished("action.CreateAction")[-1]
+        staged = set(covering_build.last_build_breakdown)
+        assert {"resolve", "scan", "hash_shuffle", "dict_probe", "sort",
+                "write", "sidecar_capture"} <= staged
+        for sp in root.spans:
+            if sp.name in staged:
+                assert sp.attrs["cpu_s"] >= 0.0, sp.name
+                assert "thread_cpu_s" not in sp.attrs, sp.name
+            elif sp is not root:    # the hook alone reads the CPU clock
+                assert "cpu_s" not in sp.attrs, sp.name
+        # the action's own, over all of it: no stage can have had more
+        assert root.attrs["cpu_s"] > 0.0
+        assert max(
+            sp.attrs["cpu_s"] for sp in root.spans if sp.name in staged
+        ) <= root.attrs["cpu_s"] + 1e-6
+        by_name = {sp.name: sp for sp in root.spans}
+        for sp in (by_name["bucket_sorts"], by_name["write"]):
+            assert 0.0 <= sp.attrs["cpu_sum_s"]
+            assert sp.attrs["cpu_sum_s"] <= sp.attrs["sum_s"] + 0.05, sp.name
+
+    def test_shard_tails_hold_their_own_threads_cpu(
+        self, session_factory, tmp_path
+    ):
+        """The mesh's shard tails run side by side, so a span that names
+        its shard holds its own thread's CPU seconds under a name of
+        their own, never the process's."""
+        s = session_factory(4)
+        idir, _odir = _lake(tmp_path)
+        items = s.read.parquet(idir)
+        Hyperspace(s).create_index(items, CoveringIndexConfig("sh1", ["k"], ["q"]))
+        root = trace.finished("action.CreateAction")[-1]
+        tails = [sp for sp in root.spans if "shard" in sp.attrs]
+        assert sorted((sp.name, sp.attrs["shard"]) for sp in tails) == [
+            (name, shard) for name in ("sort", "write") for shard in range(4)
+        ]
+        for sp in tails:
+            assert "cpu_s" not in sp.attrs
+            assert 0.0 <= sp.attrs["thread_cpu_s"] <= sp.duration_s + 0.05
+            assert 0.0 <= sp.attrs["cpu_sum_s"]
+        assert len(root.spans) <= 64 and root.spans_dropped == 0
+
+    def test_zorder_stages_name_their_passes(self, session_factory, tmp_path):
+        from hyperspace_tpu.indexes.zorder import ZOrderCoveringIndexConfig
+
+        s = session_factory(1)
+        idir, _odir = _lake(tmp_path)
+        items = s.read.parquet(idir)
+        Hyperspace(s).create_index(
+            items, ZOrderCoveringIndexConfig("zo1", ["k", "q"], [])
+        )
+        root = trace.finished("action.CreateAction")[-1]
+        by_name = {sp.name: sp for sp in root.spans}
+        encode, words = by_name["zorder_encode"], by_name["words"]
+        # sums over the columns, not a span a column
+        assert not [sp for sp in root.spans if sp.parent_id == encode.span_id]
+        assert encode.attrs["order_s"] > 0.0 and encode.attrs["minmax_s"] > 0.0
+        assert (
+            encode.attrs["order_s"] + encode.attrs["minmax_s"]
+            <= encode.duration_s + 1e-6
+        )
+        parts = [words.attrs[k] for k in ("scale_s", "stack_s", "pad_s")]
+        assert parts[0] > 0.0 and all(p >= 0.0 for p in parts)
+        assert sum(parts) <= words.duration_s + 1e-6
+        assert words.parent_id == by_name["zorder_interleave"].span_id
+        assert len(root.spans) <= 64 and root.spans_dropped == 0
+
+    def test_a_compile_names_the_span_it_ran_under(self):
+        """A jitted function first called inside a span leaves the
+        compile on that span and on its root; one called with no span
+        live leaves nothing anywhere."""
+        import jax
+        import jax.numpy as jnp
+
+        root = trace.root("action.T", always=True)
+        with trace.activate(root):
+            with trace.span("scan") as quiet:
+                pass
+            with trace.span("sort") as sp:
+                jax.jit(lambda x: x * 3 + 1)(jnp.arange(37)).block_until_ready()
+        root.finish()
+        assert sp.attrs["compiles"] >= 1 and sp.attrs["compile_s"] > 0.0
+        assert root.attrs["compiles"] == sp.attrs["compiles"]
+        assert root.attrs["compile_s"] == pytest.approx(sp.attrs["compile_s"])
+        assert sp.attrs.get("compile_cache_hits", 0) <= sp.attrs["compiles"]
+        assert not set(quiet.attrs) & {"compiles", "compile_s"}
+        # a compile under the root itself counts once, not twice
+        lone = trace.root("action.T", always=True)
+        with trace.activate(lone):
+            jax.jit(lambda x: x * 5 + 2)(jnp.arange(39)).block_until_ready()
+        lone.finish()
+        assert lone.attrs["compiles"] >= 1
+        # no span live: nothing recorded, on either finished root
+        before = [dict(r.attrs) for r in (root, lone)]
+        assert trace.current() is None
+        jax.jit(lambda x: x * 7 + 3)(jnp.arange(41)).block_until_ready()
+        assert [dict(r.attrs) for r in (root, lone)] == before
+        quiet_root = trace.root("action.T", always=True)
+        quiet_root.finish()
+        assert not set(quiet_root.attrs) & {"compiles", "compile_s"}
+
+    def test_a_compile_on_a_carried_thread_finds_its_span(self):
+        import jax
+        import jax.numpy as jnp
+        from concurrent.futures import ThreadPoolExecutor
+
+        root = trace.root("action.T", always=True)
+
+        def work(n):
+            jax.jit(lambda x: x - 11)(jnp.arange(n)).block_until_ready()
+
+        with trace.activate(root):
+            with trace.span("sort") as sp:
+                with ThreadPoolExecutor(max_workers=2) as pool:
+                    list(pool.map(trace.carry(work), [43, 47]))
+        root.finish()
+        assert sp.attrs["compiles"] == root.attrs["compiles"] >= 2
 
     def test_write_span_names_its_writers(
         self, session_factory, tmp_path, monkeypatch
