@@ -51,6 +51,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import itertools
 import json
 import logging
 import os
@@ -398,9 +399,35 @@ def file_agg_doc(
     seconds waited for the turn after them, the sweeps by the
     implementation that ran them, and the grouped sweeps abandoned at
     the cap. ``turn`` is a lock the caller holds, shared with other
-    files' calls: it is put down around the reads and the sweeps
+    tasks' calls: it is put down around the reads and the sweeps
     (:func:`_outside`); without one (the serve path's backfill) the
-    same two clock reads a call are all the account costs."""
+    same two clock reads a call are all the account costs.
+
+    The row groups carry nothing from one to the next, so the file is
+    :func:`_range_agg_doc` over all of them and :func:`_whole_file` of
+    that; a capture with fewer files than workers runs the same two
+    over a file's ranges (:func:`_plan_tasks`)."""
+    whole = _range_agg_doc(
+        path, None, max_groups, sample_rows, group_keys, stats=stats, turn=turn
+    )
+    return _whole_file([whole])
+
+
+def _range_agg_doc(
+    path: str,
+    row_groups: Optional[range],
+    max_groups: int,
+    sample_rows: int,
+    group_keys: Optional[Tuple[str, ...]] = None,
+    stats: Optional[Dict[str, float]] = None,
+    turn: Optional[threading.Lock] = None,
+) -> Tuple[dict, List[pa.Table]]:
+    """(entry, one sample table a row group) over ``row_groups`` of one
+    file (None: all of them), through a ``pq.ParquetFile`` of this
+    call's own: :func:`file_agg_doc`'s body, short of what is decided
+    over the whole file (:func:`_whole_file`). A row group's cells and
+    its sample depend on the file's name and the row group's number
+    alone (:func:`_sample_rng`), so ranges put end to end are the file."""
     from hyperspace_tpu.execution import pipeline_compiler as PC
     from hyperspace_tpu.io.columnar import ColumnarBatch
 
@@ -435,7 +462,9 @@ def file_agg_doc(
     ungrouped = _sweep_plan(PC, schema, None, ops)
     by_key = {kc: _sweep_plan(PC, schema, kc, ops) for kc in key_candidates}
     samples: List[pa.Table] = []
-    for gi in range(pf.metadata.num_row_groups):
+    if row_groups is None:
+        row_groups = range(pf.metadata.num_row_groups)
+    for gi in row_groups:
         with _outside(turn, stats, "read_s"):
             table = pf.read_row_group(gi)
         batch = ColumnarBatch.from_arrow(table)
@@ -491,6 +520,29 @@ def file_agg_doc(
                 0, "__file", pa.array([base] * k, type=pa.string())
             )
             samples.append(sampled)
+    return entry, samples
+
+
+def _whole_file(
+    ranges: List[Tuple[dict, List[pa.Table]]],
+) -> Tuple[dict, Optional[pa.Table]]:
+    """One file's (entry, sample table) from what :func:`_range_agg_doc`
+    gave for its ranges, in row-group order: the per-row-group lists end
+    to end (``f64`` is a scalar), then what only the whole file decides
+    — a grouped candidate is dropped where it is None in EVERY row
+    group; over the cap in some ranges and under it in others, it stays
+    — and the one concatenation of the row groups' samples."""
+    entry, samples = ranges[0]
+    for more, more_samples in ranges[1:]:
+        entry["rg_rows"] += more["rg_rows"]
+        for c, cell in more["cols"].items():
+            dst = entry["cols"][c]
+            for k, vals in cell.items():
+                if k != "f64":
+                    dst[k] += vals
+        for kc, cells in more["groups"].items():
+            entry["groups"][kc] += cells
+        samples += more_samples
     # prune all-None grouped candidates (over-cap everywhere)
     entry["groups"] = {
         k: v for k, v in entry["groups"].items() if any(e is not None for e in v)
@@ -543,9 +595,52 @@ def capture_index_dir(dir_path: str, index, conf=None) -> bool:
         return _capture_files(dir_path, max_groups, sample_rows, sp)
 
 
-def _map_files(fn, files: List[str]):
-    """(``[fn(f) for f in files]``, workers): on a bounded pool of this
-    call's own, as ``io/parquet._pool_map`` is — inline up to 4 files (a
+#: a range task's grain, ``native._n_threads``' (a thread per ~64k
+#: rows): a file is cut only where its footer shows two such tasks
+_TASK_ROWS = 1 << 16
+#: ranges a worker where files are cut: a few, so that the workers that
+#: run out first at the tail wait for a quarter of a share and not a
+#: whole one, and files that do not divide the workers still fill them;
+#: each costs a ``pq.ParquetFile`` of its own (``read_s``). On the
+#: chip's 13-core host, a 16M-row file of 245 row groups (PERF.md §6,
+#: PR 37), the pool's wall: 1.04–1.25 s at 1 a worker, 1.13–1.30 at 2
+#: and at 4, 1.19–1.40 at 8, 1.40–1.51 at a range a row group (4.1 on
+#: one worker): the turn paces the tasks, so no tail shows at 1
+_RANGES_PER_WORKER = 4
+
+
+def _plan_tasks(files: List[str]) -> Tuple[List[Tuple[str, Optional[range]]], int]:
+    """(the pool's tasks, files cut into ranges). A task is a file —
+    ``(path, None)`` — wherever the files alone fill the workers
+    (``native.core_budget()``; no footer is opened then). With fewer
+    files than that (a z-order build's one file, an optimize's few
+    large ones) the footers say which hold more than a task's rows, and
+    such a file becomes contiguous row-group ranges of about equal
+    length, ``_RANGES_PER_WORKER`` a worker over all the files; a small
+    refresh's few small files stay a task each."""
+    from hyperspace_tpu import native
+
+    budget = native.core_budget()
+    if len(files) >= budget:
+        return [(f, None) for f in files], 0
+    most = -(-_RANGES_PER_WORKER * budget // len(files))
+    tasks: List[Tuple[str, Optional[range]]] = []
+    split_files = 0
+    for f in files:
+        md = pq.read_metadata(f)
+        n = min(md.num_row_groups, md.num_rows // _TASK_ROWS, most)
+        if n < 2:
+            tasks.append((f, None))
+            continue
+        split_files += 1
+        cuts = [md.num_row_groups * i // n for i in range(n + 1)]
+        tasks.extend((f, range(lo, hi)) for lo, hi in zip(cuts, cuts[1:]))
+    return tasks, split_files
+
+
+def _map_tasks(fn, tasks: list):
+    """(``[fn(t) for t in tasks]``, workers): on a bounded pool of this
+    call's own, as ``io/parquet._pool_map`` is — inline up to 4 tasks (a
     small refresh), and never ``scan_pool``, whose tasks may not wait on
     each other. ``workers`` goes on the capture's span beside
     ``files_s``, the wall of this call: ``workers * files_s`` is the
@@ -553,15 +648,15 @@ def _map_files(fn, files: List[str]):
     sweep_s + read_s`` fill but for workers idle at the tail."""
     from hyperspace_tpu import native
 
-    if len(files) <= 4:
-        return [fn(f) for f in files], 1
+    if len(tasks) <= 4:
+        return [fn(t) for t in tasks], 1
     from concurrent.futures import ThreadPoolExecutor
 
-    workers = min(native.core_budget(), len(files))
+    workers = min(native.core_budget(), len(tasks))
     with ThreadPoolExecutor(
         max_workers=workers, thread_name_prefix="hs-aggcapture"
     ) as pool:
-        return list(pool.map(fn, files)), workers
+        return list(pool.map(fn, tasks)), workers
 
 
 def _capture_files(
@@ -579,14 +674,20 @@ def _capture_files(
 
     turn = threading.Lock()
 
-    def file_doc(f: str):
+    def task_doc(task: Tuple[str, Optional[range]]):
+        f, row_groups = task
         stats = dict.fromkeys(_FILE_STATS, 0)
         _take(turn, stats)
         try:
             waited, t0 = stats["turn_wait_s"], _time.perf_counter()
-            entry, sample = file_agg_doc(
-                f, max_groups, sample_rows, stats=stats, turn=turn
-            )
+            if row_groups is None:
+                part = file_agg_doc(
+                    f, max_groups, sample_rows, stats=stats, turn=turn
+                )
+            else:
+                part = _range_agg_doc(
+                    f, row_groups, max_groups, sample_rows, stats=stats, turn=turn
+                )
             held_s = _time.perf_counter() - t0
         finally:
             turn.release()
@@ -596,28 +697,41 @@ def _capture_files(
         stats["python_s"] = held_s - (
             stats["read_s"] + stats["sweep_s"] + stats["turn_wait_s"] - waited
         )
+        return part, stats
+
+    # the row groups are independent, and their reads and sweeps run
+    # outside the interpreter lock: one task a file, or a range of a
+    # file's row groups where the files are fewer than the workers, the
+    # Python of the tasks by turns; the document and the sample table
+    # take the order of ``files`` and of a file's row groups whatever
+    # order the tasks finish in
+    t_files = _time.perf_counter()
+    tasks, split_files = _plan_tasks(files)
+    docs, workers = _map_tasks(task_doc, tasks)
+    parts, stats = zip(*docs)
+    entries, sample_tables = {}, []
+    for f, of_file in itertools.groupby(
+        zip(tasks, parts), key=lambda task_part: task_part[0][0]
+    ):
+        of_file = list(of_file)
+        (_f, row_groups), part = of_file[0]
+        if row_groups is not None:
+            part = _whole_file([p for _task, p in of_file])
+        entry, sample = part
         st = os.stat(f)
         entry["size"] = st.st_size
         entry["mtime_ns"] = st.st_mtime_ns
-        return entry, sample, stats
-
-    # the files are independent, and their reads and sweeps run outside
-    # the interpreter lock: one task a file, the Python of the tasks by
-    # turns; the document and the sample table take the order of
-    # ``files`` whatever order the tasks finish in
-    t_files = _time.perf_counter()
-    docs, workers = _map_files(file_doc, files)
+        entries[os.path.basename(f)] = entry
+        if sample is not None:
+            sample_tables.append(sample)
     t_publish = _time.perf_counter()
-    entries, samples, stats = zip(*docs)
-    doc: dict = {
-        "version": _SIDECAR_VERSION,
-        "files": {os.path.basename(f): e for f, e in zip(files, entries)},
-    }
-    sample_tables = [t for t in samples if t is not None]
+    doc: dict = {"version": _SIDECAR_VERSION, "files": entries}
     sp.set("files", len(files))
+    sp.set("tasks", len(tasks))
+    sp.set("split_files", split_files)
     sp.set("workers", workers)
     sp.set("files_s", round(t_publish - t_files, 6))
-    # sums over the files, like sum_s. python_s, the seconds the turn
+    # sums over the tasks, like sum_s. python_s, the seconds the turn
     # was held, is serial by construction: a floor of files_s
     for k in ("python_s", *_FILE_STATS):
         sp.set(k, round(sum(st[k] for st in stats), 6))

@@ -465,7 +465,7 @@ def core_budget() -> int:
     sorts (``ops/sort._sort_pool_plan``), the shard tails
     (``ops/sort.shard_tail_plan``), the bucket-file writers
     (``indexes/covering_build``) and the aggregate capture
-    (``indexes/aggindex._map_files``) each split or take."""
+    (``indexes/aggindex._map_tasks``) each split or take."""
     return min(_cores(), 16)
 
 

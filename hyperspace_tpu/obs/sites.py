@@ -337,10 +337,13 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
     "hyperspace_tpu.indexes.aggindex.capture_index_dir": (
         "span",
         "sidecar_capture (aggstate): build-tail I/O that re-reads every "
-        "file just written, one pool task a file; files/workers/files_s "
+        "file just written, one pool task a file — or a range of a "
+        "file's row groups where the files are fewer than the workers: "
+        "tasks (units handed to the pool), split_files (files cut into "
+        "ranges); files/workers/files_s "
         "(the pool's wall), what the tasks' seconds went to, timed where "
         "a task puts the turn down and takes it up (aggindex._outside, "
-        "_take) and summed over the files — python_s (turn held: "
+        "_take) and summed over the tasks — python_s (turn held: "
         "serial, a floor of files_s), read_s and sweep_s (outside it), "
         "turn_wait_s — sweeps_native/sweeps_twin/early_rejects "
         "(row-group passes by the implementation that ran them) and "

@@ -706,6 +706,143 @@ class TestCaptureSweep:
             pa.concat_tables(golden_samples, promote_options="permissive")
         )
 
+    # case -> (row counts of the files, how the tasks finish); row groups
+    # of 512 rows, a task's grain cut from 65,536 rows to 1,024
+    _RANGE_CASES = {
+        "one_file_of_many_row_groups": ([512 * 40], "as_they_come"),
+        "two_files_of_unequal_row_groups": ([512 * 37, 512 * 5 + 9], "as_they_come"),
+        "ranges_finish_in_reverse": ([512 * 24], "reversed"),
+        "over_the_cap_in_some_ranges_only": ([512 * 30], "as_they_come"),
+        "short_last_range": ([512 * 8 + 3], "as_they_come"),
+        "a_file_too_small_to_cut_beside_one_cut": ([900, 512 * 12], "reversed"),
+    }
+
+    @staticmethod
+    def _range_case_dir(tmp_path, case, rows_of_files):
+        rng = np.random.default_rng(53)
+        d = tmp_path / "v__=0"
+        d.mkdir()
+        for i, n in enumerate(rows_of_files):
+            rg = np.arange(n) // 512
+            # "k" stays under the cap of 8 everywhere; "m" is over it in
+            # the row groups of the middle third alone — whole ranges of
+            # None between ranges that keep it; "h" is over it in every
+            # row group but the file's last, so all ranges but one would
+            # prune it and the file may not; "v" is over it everywhere
+            third = max(1, (int(rg[-1]) + 1) // 3)
+            m = np.where((rg >= third) & (rg < 2 * third),
+                         np.arange(n) % 64, np.arange(n) % 5)
+            h = np.where(rg == rg[-1], np.arange(n) % 3, np.arange(n) % 97)
+            if case != "over_the_cap_in_some_ranges_only":
+                m = h = np.arange(n) % 4
+            pq.write_table(
+                pa.table({
+                    "k": pa.array(rng.integers(0, 5, n), type=pa.int64()),
+                    "m": pa.array(m, type=pa.int64()),
+                    "h": pa.array(h, type=pa.int64()),
+                    "v": pa.array(rng.normal(0, 2, n)),
+                    "s": pa.array([f"r{j % 3}" for j in range(n)]),
+                }),
+                str(d / f"part-{i:05d}.parquet"),
+                row_group_size=512,
+            )
+        return str(d)
+
+    @pytest.mark.parametrize("case", list(_RANGE_CASES))
+    def test_ranges_of_a_file_are_the_file(self, case, tmp_path, monkeypatch):
+        """Fewer files than workers: a file with rows for more than one
+        task is swept as row-group ranges, and the sidecars are still the
+        serial ``file_agg_doc`` loop over the twin, byte for byte — lists
+        end to end in row-group order, the prune of a grouped candidate
+        decided over the file and not a range."""
+        import threading
+        import types
+
+        from hyperspace_tpu import native
+
+        rows_of_files, finish = self._RANGE_CASES[case]
+        d = self._range_case_dir(tmp_path, case, rows_of_files)
+        files = pio.list_format_files(d, "parquet")
+        with monkeypatch.context() as m:
+            m.setattr(native, "fused_filter_agg", lambda *a, **k: None)
+            golden = {"version": 1, "files": {}}
+            golden_samples = []
+            for f in files:
+                entry, sample = aggindex.file_agg_doc(f, _CAP, 16)
+                st = os.stat(f)
+                entry["size"], entry["mtime_ns"] = st.st_size, st.st_mtime_ns
+                golden["files"][os.path.basename(f)] = entry
+                golden_samples.append(sample)
+        if case == "over_the_cap_in_some_ranges_only":
+            (entry,) = golden["files"].values()
+            assert set(entry["groups"]) == {"k", "m", "h"}
+            assert entry["groups"]["m"][:10] != [None] * 10
+            assert entry["groups"]["m"][10:20] == [None] * 10
+            assert entry["groups"]["h"][:-1] == [None] * 29
+            assert entry["groups"]["h"][-1] is not None
+
+        monkeypatch.setattr(native, "_cores", lambda: 8)
+        monkeypatch.setattr(aggindex, "_TASK_ROWS", 1024)
+        tasks, split = aggindex._plan_tasks(files)
+        cut = [f for f, n in zip(files, rows_of_files) if n >= 2048]
+        assert split == len(cut) and len(tasks) > len(files)
+        for f, n in zip(files, rows_of_files):
+            ranges = [r for tf, r in tasks if tf == f]
+            if f not in cut:
+                assert ranges == [None]
+                continue
+            # contiguous, in order, all of the file, none empty, and no
+            # more of them than the file has tasks' worth of rows
+            assert 2 <= len(ranges) <= n // 1024
+            assert [r.start for r in ranges[1:]] == [r.stop for r in ranges[:-1]]
+            assert ranges[0].start == 0 and ranges[-1].stop == -(-n // 512)
+            assert all(len(r) > 0 for r in ranges)
+        if case == "two_files_of_unequal_row_groups":
+            a, b = (sum(tf == f for tf, _r in tasks) for f in files)
+            assert a > b >= 2   # 32 ranges between two files, by their rows
+
+        # every task waits for the task after it: completion is reversed
+        # (a whole file's task comes through here too, row_groups None)
+        real, finished = aggindex._range_agg_doc, []
+        done = [threading.Event() for _t in tasks]
+
+        def after_the_next(path, row_groups, *a, **k):
+            out = real(path, row_groups, *a, **k)
+            i = tasks.index((path, row_groups))
+            if i + 1 < len(tasks):
+                # as a read would wait
+                with aggindex._outside(k["turn"], k["stats"], "read_s"):
+                    assert done[i + 1].wait(30), "tasks did not overlap"
+            finished.append(i)
+            done[i].set()
+            return out
+
+        conf = types.SimpleNamespace(
+            index_agg_enabled=True, index_agg_max_groups=_CAP,
+            index_agg_sample_rows=16)
+        with monkeypatch.context() as m:
+            if finish == "reversed":
+                assert len(tasks) <= 16  # a thread a task: the last can start
+                m.setattr(native, "_cores", lambda: 16)
+                m.setattr(aggindex, "_plan_tasks", lambda _files: (tasks, split))
+                m.setattr(aggindex, "_range_agg_doc", after_the_next)
+            assert aggindex.capture_index_dir(d, _CoveringKind(), conf)
+        if finish == "reversed":
+            assert finished == list(range(len(tasks)))[::-1]
+
+        with open(os.path.join(d, aggindex.SIDECAR_NAME), encoding="utf-8") as fh:
+            text = fh.read()
+        assert text == json.dumps(golden)
+        sample = pq.read_table(os.path.join(d, aggindex.SAMPLE_NAME))
+        assert sample.equals(
+            pa.concat_tables(golden_samples, promote_options="permissive")
+        )
+        # and the serve path's backfill, which gets no pool, agrees
+        for f in files:
+            entry, _sample = aggindex.file_agg_doc(f, _CAP, 16)
+            assert {**entry, "size": 0, "mtime_ns": 0} == {
+                **golden["files"][os.path.basename(f)], "size": 0, "mtime_ns": 0}
+
     def test_one_file_failing_fails_no_build_and_publishes_nothing(
         self, s1, tmp_path, monkeypatch
     ):

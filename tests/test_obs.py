@@ -570,8 +570,9 @@ class TestActionAccount:
             if sp.name == "sidecar_capture"
         }
         assert set(sidecars) == {"aggstate", "zonemap"}
-        for key in ("files", "python_s", "read_s", "sweep_s", "turn_wait_s",
-                    "publish_s", "bytes", "workers", "files_s",
+        for key in ("files", "tasks", "split_files", "python_s", "read_s",
+                    "sweep_s", "turn_wait_s", "publish_s", "bytes", "workers",
+                    "files_s",
                     "sweeps_native", "sweeps_twin", "early_rejects"):
             assert key in sidecars["aggstate"], key
         assert "partials_s" not in sidecars["aggstate"]
@@ -605,7 +606,9 @@ class TestActionAccount:
         assert {sp.name for sp in root.spans if sp.parent_id == spans[0].span_id} == set()
         at = spans[0].attrs
         assert at["files"] == root.attrs["index_files"] == 8
-        assert at["workers"] == 3  # min(16, cores, files), past 4 files
+        assert at["workers"] == 3  # min(16, cores, tasks), past 4 tasks
+        # as many files as workers or more: a task a file, no footer opened
+        assert at["tasks"] == 8 and at["split_files"] == 0
         assert at["sweeps_native"] + at["sweeps_twin"] == next(passes) > 0
         if native.load() is not None:
             assert at["sweeps_twin"] == 0
@@ -678,10 +681,43 @@ class TestActionAccount:
             if sp.name == "sidecar_capture" and sp.attrs["sidecar"] == "aggstate"
         ]
         assert at["files"] == 4 and at["workers"] == 1
+        # fewer files than cores, but none with rows for two tasks
+        assert at["tasks"] == 4 and at["split_files"] == 0
         assert at["turn_wait_s"] == 0
         account = at["python_s"] + at["read_s"] + at["sweep_s"]
         assert account <= at["files_s"] + 1e-4
         assert account >= 0.9 * at["files_s"]
+
+    def test_one_file_with_rows_for_many_tasks_is_swept_by_a_pool(
+        self, session_factory, tmp_path, monkeypatch
+    ):
+        """One bucket, so one file: its row groups go to the pool as
+        ranges, a task per ``_TASK_ROWS`` rows — and the capture is still
+        the one span with its parts as attrs, no span a task."""
+        from hyperspace_tpu import native
+        from hyperspace_tpu.indexes import aggindex
+        from hyperspace_tpu.io import parquet as pio
+
+        monkeypatch.setattr(native, "_cores", lambda: 4)
+        monkeypatch.setattr(pio, "INDEX_ROW_GROUP_SIZE", 1000)
+        monkeypatch.setattr(aggindex, "_TASK_ROWS", 2000)
+        _build(session_factory, tmp_path, warm=False, buckets=1)
+        root = trace.finished("action.CreateAction")[-1]
+        spans = [
+            sp for sp in root.spans
+            if sp.name == "sidecar_capture" and sp.attrs["sidecar"] == "aggstate"
+        ]
+        assert len(spans) == 1
+        assert not [sp for sp in root.spans if sp.parent_id == spans[0].span_id]
+        at = spans[0].attrs
+        assert at["files"] == root.attrs["index_files"] == 1
+        # 20,000 rows in 20 row groups: ten tasks' worth of rows
+        assert at["split_files"] == 1 and at["tasks"] == 10
+        assert at["workers"] == 4
+        assert at["sweeps_native"] + at["sweeps_twin"] >= 20
+        parts = ("python_s", "read_s", "sweep_s", "turn_wait_s")
+        assert at["python_s"] <= at["files_s"] + 1e-3
+        assert sum(at[k] for k in parts) <= at["workers"] * at["files_s"] + 1e-3
 
     def test_scan_span_says_what_its_seconds_went_to(
         self, session_factory, tmp_path

@@ -21,7 +21,7 @@ import pytest
 
 from hyperspace_tpu import constants as C
 from hyperspace_tpu.hyperspace import Hyperspace
-from hyperspace_tpu.indexes import zonemaps
+from hyperspace_tpu.indexes import aggindex, zonemaps
 from hyperspace_tpu.indexes.zorder import (
     WrittenZSpans,
     ZOrderCoveringIndex,
@@ -294,3 +294,78 @@ def test_an_optimize_hands_its_spans_over(tmp_path):
     assert _zspans(compacted)[0] == {os.path.basename(only): [[
         min((lo for lo, _hi in before), key=lambda z: int(z, 16)),
         max((hi for _lo, hi in before), key=lambda z: int(z, 16))]]}
+
+
+# -- the aggregate capture beside it: the one file's row groups on a pool ------
+
+def _agg_capture(root):
+    (span,) = [sp for sp in root.spans
+               if sp.name == "sidecar_capture" and sp.attrs["sidecar"] == "aggstate"]
+    return span
+
+
+def _sidecar_bytes(version_dir):
+    out = []
+    for name in (aggindex.SIDECAR_NAME, aggindex.SAMPLE_NAME):
+        with open(os.path.join(version_dir, name), "rb") as fh:
+            out.append(fh.read())
+    return out
+
+
+def test_one_file_swept_by_one_worker_or_eight_is_the_same_sidecars(tmp_path, monkeypatch):
+    """The z-order build's one file, captured by a task a file (one core)
+    and by row-group ranges on a pool (eight): the same bytes of both
+    sidecars, and the same metadata answer served from each."""
+    from hyperspace_tpu import functions as F
+    from hyperspace_tpu import native
+    from hyperspace_tpu.execution import pipeline_compiler as PC
+
+    few = pa.array(np.random.default_rng(19).integers(0, 6, 400_000), type=pa.int64())
+    src = _source(tmp_path / "src", pa.table(dict(_numbers(400_000, 17), g=few)))
+    built = {}
+    for cores in (1, 8):
+        monkeypatch.setattr(native, "_cores", lambda cores=cores: cores)
+        session = _session(tmp_path / f"idx{cores}")
+        items = session.read.parquet(src)
+        Hyperspace(session).create_index(items, ZOrderCoveringIndexConfig("z", ["a", "b"], ["p", "g"]))
+        root = trace.finished("action.CreateAction")[-1]
+        (version_dir,) = _version_dirs(tmp_path / f"idx{cores}", "z")
+        (only,) = _data_files(version_dir)
+        assert pq.read_metadata(only).num_row_groups == 7
+        span = _agg_capture(root)
+        assert _children(root, span) == []
+        want = {"files": 1, "tasks": 1, "split_files": 0, "workers": 1}
+        if cores == 8:    # six tasks' worth of rows in seven row groups
+            want = {"files": 1, "tasks": 6, "split_files": 1, "workers": 6}
+        assert {k: span.attrs[k] for k in want} == want
+        assert (span.attrs["turn_wait_s"] == 0) == (cores == 1)
+        session.enable_hyperspace()
+        aggindex.invalidate_local_cache()
+        PC.last_aggplane_stats = {}
+        answer = (items.filter(items["a"] >= -1000).group_by("g")
+                  .agg(F.count().alias("n"), F.sum("b").alias("sb"), F.max("p").alias("mp")).collect())
+        assert PC.last_aggplane_stats.get("mode") == "agg_metadata", PC.last_aggplane_stats
+        assert PC.last_aggplane_stats["rows_scanned"] == 0
+        built[cores] = (version_dir, only, answer.sort_by("g"))
+    assert built[1][2].equals(built[8][2]) and built[1][2].num_rows == 6
+    assert built[8][2].column("n").to_pylist() == np.bincount(few.to_numpy()).tolist()
+    # the two builds' data files are the same bytes under the same name, so
+    # their sidecars can differ in the files' mtime alone: a copy of the
+    # pool's directory that keeps the times, swept again on one core
+    (dir1, file1, _), (dir8, file8, _) = built[1], built[8]
+    assert os.path.basename(file1) == os.path.basename(file8)
+    with open(file1, "rb") as one, open(file8, "rb") as eight:
+        assert one.read() == eight.read()
+    state1, sample1 = _sidecar_bytes(dir1)
+    state8, sample8 = _sidecar_bytes(dir8)
+    assert sample1 == sample8
+    mtime = lambda f: f'"mtime_ns": {os.stat(f).st_mtime_ns}'.encode()
+    assert state1.count(mtime(file1)) == 1
+    assert state1.replace(mtime(file1), mtime(file8)) == state8
+    copy = str(tmp_path / "again" / os.path.basename(dir8))
+    shutil.copytree(dir8, copy)
+    for name in (aggindex.SIDECAR_NAME, aggindex.SAMPLE_NAME):
+        os.remove(os.path.join(copy, name))
+    monkeypatch.setattr(native, "_cores", lambda: 1)
+    assert aggindex.capture_index_dir(copy, ZOrderCoveringIndex(["a", "b"], [], "", 1 << 30))
+    assert _sidecar_bytes(copy) == [state8, sample8]
