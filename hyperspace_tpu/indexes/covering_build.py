@@ -724,8 +724,14 @@ def _hash_shuffle(
     owns), or None when no exchange ran (single device / tiny batch)."""
     import jax
 
+    # how many columns the key has: on the span that encodes them and,
+    # set and not summed (a streamed build hashes once a wave), on the
+    # action's root
+    live = _obs_trace.current()
+    if live is not None:
+        live.root.set("key_columns", len(indexed_cols))
     with stage("hash_shuffle"):
-        with _obs_trace.span("key_reps"):
+        with _obs_trace.span("key_reps", key_columns=len(indexed_cols)):
             reps = batch.key_reps(indexed_cols)
         mesh = ctx.mesh
         shard_offs = None
@@ -892,17 +898,38 @@ def count_written(sp, paths: List[str]) -> None:
     _obs_trace.accumulate("index_bytes", n_bytes)
 
 
-def _timed_write_bucket_file(*args) -> Tuple[str, float, float]:
+def _timed_write_bucket_file(*args) -> Tuple[str, float, float, float, float]:
     """``pio.write_bucket_file`` -> (path, its seconds on the writer
-    thread, that thread's CPU seconds over them): the per-file unit
-    behind a write stage's ``sum_s`` / ``max_s`` / ``cpu_sum_s``."""
+    thread, that thread's CPU seconds over them, the seconds of its
+    gather, those of its parquet write): the per-file unit behind a
+    write stage's ``sum_s`` / ``max_s`` / ``cpu_sum_s`` and ``take_s``
+    / ``encode_s``."""
     t0, cpu0 = _time.perf_counter(), _time.thread_time_ns()
     path = pio.write_bucket_file(*args)
+    take_s, encode_s = pio.last_bucket_file_phases()
     return (
         path,
         _time.perf_counter() - t0,
         (_time.thread_time_ns() - cpu0) / 1e9,
+        take_s,
+        encode_s,
     )
+
+
+def _written_attrs(sp, done: Sequence[Tuple], columns: int) -> List[str]:
+    """What a write stage's files took, on its span: ``done`` holds
+    ``_timed_write_bucket_file``'s tuples. ``sum_s`` / ``max_s`` /
+    ``cpu_sum_s`` over the files as every repeated task has them;
+    ``take_s`` and ``encode_s``, thread seconds as ``sum_s`` is, split
+    it into the rows' gather and the parquet write; ``columns`` is what
+    each file holds. -> the files' paths."""
+    _repeat_attrs(sp, [(sec, cpu) for _p, sec, cpu, _t, _e in done], "buckets")
+    sp.set("take_s", round(float(sum(take for *_, take, _e in done)), 6))
+    sp.set("encode_s", round(float(sum(enc for *_, enc in done)), 6))
+    sp.set("columns", columns)
+    written = [path for path, *_ in done]
+    count_written(sp, written)
+    return written
 
 
 def _single_process() -> bool:
@@ -1038,17 +1065,17 @@ def _write_bucketed_pipelined(
                         )
                     )
                 _repeat_attrs(sorts_sp, sort_s, "buckets")
+                # the order words a sort compares (two a key column) and
+                # the largest bucket's rows: one sort's working set
+                sorts_sp.set("planes", int(planes.shape[0]))
+                sorts_sp.set("max_rows", int(np.diff(offsets).max()))
         with stage("write", writers=writers) as write_sp:
             # in submission order: ascending bucket id
             done = [f.result() for f in futures]
             pool.shutdown()  # the writers' way out is the drain's too
-            written = [path for path, _s, _cpu in done]
             # every file's seconds on its writer's thread, those that
             # ran under the sort stage included
-            _repeat_attrs(
-                write_sp, [(sec, cpu) for _p, sec, cpu in done], "buckets"
-            )
-            count_written(write_sp, written)
+            written = _written_attrs(write_sp, done, table.num_columns)
     return written
 
 
@@ -1163,14 +1190,9 @@ def _write_bucketed_sharded(
                     )
                 _repeat_attrs(sort_sp, sort_s, "buckets")
             with stage("write", shard=s) as write_sp:
-                done = [(b, f.result()) for b, f in futures]
-                out = [(b, path) for b, (path, _sec, _cpu) in done]
-                _repeat_attrs(
-                    write_sp,
-                    [(sec, cpu) for _b, (_p, sec, cpu) in done],
-                    "buckets",
-                )
-                count_written(write_sp, [path for _b, path in out])
+                done = [f.result() for _b, f in futures]
+                paths = _written_attrs(write_sp, done, table.num_columns)
+                out = [(b, path) for (b, _f), path in zip(futures, paths)]
         return out
 
     # the shard tails run on pool threads: hand them the action's span

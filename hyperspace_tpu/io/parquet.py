@@ -15,6 +15,8 @@ from __future__ import annotations
 import functools as _functools
 import os
 import re
+import threading
+import time
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -465,6 +467,19 @@ def dictionary_columns_for_batch(batch: ColumnarBatch):
     return _dictionary_columns(batch.to_arrow())
 
 
+# (gather seconds, encode seconds) of the last ``write_bucket_file`` on
+# THIS thread: the rows' gather (``table.take``, or the slice that
+# stands in for it) against the parquet write. Thread-local, so a pool
+# of writers reads each its own; the build sums them onto its ``write``
+# span as ``take_s`` / ``encode_s`` (covering_build.
+# _timed_write_bucket_file).
+_bucket_file_phases = threading.local()
+
+
+def last_bucket_file_phases() -> Tuple[float, float]:
+    return getattr(_bucket_file_phases, "seconds", (0.0, 0.0))
+
+
 def write_bucket_file(
     out_dir: str,
     bucket: int,
@@ -483,6 +498,7 @@ def write_bucket_file(
     # version dir under a transient log entry — the orphans recovery GC
     # must quarantine
     faults.crash("mid_data_write", path)
+    t0 = time.perf_counter()
     if (
         len(idx)
         and len(idx) == int(idx[-1]) - int(idx[0]) + 1
@@ -496,12 +512,14 @@ def write_bucket_file(
         sub = table.slice(int(idx[0]), len(idx))
     else:
         sub = table.take(pa.array(idx))
+    t1 = time.perf_counter()
     pq.write_table(
         sub,
         path,
         row_group_size=INDEX_ROW_GROUP_SIZE,
         use_dictionary=use_dictionary,
     )
+    _bucket_file_phases.seconds = (t1 - t0, time.perf_counter() - t1)
     return path
 
 

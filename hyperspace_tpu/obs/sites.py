@@ -246,7 +246,9 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
         "span",
         "hash_shuffle stage, with key_reps split off so the hash's "
         "device round trip (ops/hash) is not confused with the host's "
-        "key encoding",
+        "key encoding; key_columns (the indexed columns of the key) on "
+        "key_reps and, set, on the root: every host pass and the h2d "
+        "go by it",
     ),
     "hyperspace_tpu.indexes.covering_build.bucketize": (
         "span",
@@ -264,14 +266,18 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
         "summarized as buckets/sum_s/max_s/cpu_sum_s attrs (cpu_sum_s: "
         "the tasks' own threads' CPU seconds, so sum_s - cpu_sum_s is "
         "what they spent off a CPU), so a stalled thread shows without "
-        "a span per bucket",
+        "a span per bucket; bucket_sorts also says planes (order words "
+        "a sort compares) and max_rows (the largest bucket), write "
+        "take_s / encode_s (a file's gather against its parquet write, "
+        "thread seconds from io/parquet.write_bucket_file) and columns",
     ),
     "hyperspace_tpu.indexes.covering_build._write_bucketed_sharded": (
         "span",
         "partition (order words) and to_arrow once before the shard "
         "pool, then one sort and one write span per SHARD tail (attr "
-        "shard; thread_cpu_s and cpu_sum_s as above), carried onto the "
-        "shard pool's threads",
+        "shard; thread_cpu_s and cpu_sum_s, take_s / encode_s / "
+        "columns on write as above), carried onto the shard pool's "
+        "threads",
     ),
     "hyperspace_tpu.parallel.shuffle._device_leg": (
         "span",
@@ -296,7 +302,9 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
         "span",
         "split_words / h2d / kernel / d2h (or host_hash): a 0.7 ms "
         "kernel inside a 1.2 s stage is explained only by separating "
-        "the host's word split and each transfer from the kernel wait",
+        "the host's word split and each transfer from the kernel wait; "
+        "split_words says words (the uint32 block's first dimension, "
+        "two a key column)",
     ),
     "hyperspace_tpu.ops.zorder.ZOrderEncoder.planes_from_encodings": (
         "span",

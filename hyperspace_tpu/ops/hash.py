@@ -183,7 +183,8 @@ def bucket_ids_np(key_reps: np.ndarray, num_buckets: int, seed: int = 42) -> np.
     small ones use the same arithmetic directly in numpy.
 
     Under a live trace the device path is four spans — ``split_words``
-    (the host's int64 -> uint32 word split and padding), ``h2d`` (to
+    (the host's int64 -> uint32 word split and padding; ``words`` is the
+    block's first dimension, two a key column), ``h2d`` (to
     ``block_until_ready`` of the words on the device), ``kernel``
     (dispatch to ``block_until_ready``), ``d2h`` — with the bytes each
     way counted on the root; the host path is one ``host_hash``."""
@@ -193,8 +194,9 @@ def bucket_ids_np(key_reps: np.ndarray, num_buckets: int, seed: int = 42) -> np.
     if n <= _host_hash_max_rows():
         with _obs_trace.span("host_hash"):
             return bucket_ids_host(key_reps, num_buckets, seed)
-    with _obs_trace.span("split_words"):
+    with _obs_trace.span("split_words") as split_sp:
         words = split_words_np(key_reps)
+        split_sp.set("words", int(words.shape[0]))
         n_pad = pad_len(n)
         if n_pad != n:
             words = np.concatenate(

