@@ -731,8 +731,15 @@ def _hash_shuffle(
     if live is not None:
         live.root.set("key_columns", len(indexed_cols))
     with stage("hash_shuffle"):
-        with _obs_trace.span("key_reps", key_columns=len(indexed_cols)):
+        with _obs_trace.span(
+            "key_reps", key_columns=len(indexed_cols)
+        ) as reps_sp:
             reps = batch.key_reps(indexed_cols)
+            # key columns whose bytes were copied on the way: none where
+            # the reps are a view of the one column that already held them
+            reps_sp.set(
+                "copied", len(indexed_cols) if reps.flags.owndata else 0
+            )
         mesh = ctx.mesh
         shard_offs = None
         # multi-process: ALWAYS exchange, even a zero/tiny local batch —
@@ -752,7 +759,12 @@ def _hash_shuffle(
                 strategy=conf.build_exchange_strategy,
                 twostage_hosts=conf.build_exchange_twostage_hosts,
             )
-            reps = np.stack(moved[:k]) if k else np.zeros((0, len(buckets)))
+            if k == 1:  # the exchanged column holds the reps: no copy
+                reps = moved[0][None, :]
+            elif k:
+                reps = np.stack(moved[:k])
+            else:
+                reps = np.zeros((0, len(buckets)))
             batch = _reassemble(spec, moved[k:])
             _record_shuffle_telemetry(_shuffle.last_shuffle_stats)
         else:

@@ -233,6 +233,18 @@ class Column:
             rep = np.where(self.validity, rep, NULL_KEY_REP)
         return rep
 
+    def _held_key_rep(self) -> Optional[np.ndarray]:
+        """The column's own values where they ARE its key rep, bit for
+        bit (non-null int64: ``key_rep()`` would only copy them), else
+        None."""
+        if (
+            self.kind != "string"
+            and self.validity is None
+            and self.values.dtype == np.int64
+        ):
+            return self.values
+        return None
+
     # -- row ops ------------------------------------------------------------
     def take(self, idx: np.ndarray) -> "Column":
         if self.kind == "string":
@@ -471,8 +483,26 @@ class ColumnarBatch:
         return self.take(np.nonzero(np.asarray(mask))[0])
 
     def key_reps(self, names: Sequence[str]) -> np.ndarray:
-        """[num_keys, num_rows] int64 key representations."""
-        return np.stack([self.column(n).key_rep() for n in names])
+        """[num_keys, num_rows] int64 key representations, at most one
+        pass over a key column's bytes. A non-null int64 column already
+        holds its rep: one such key is a READ-ONLY view of the column
+        (``values[None, :]``: nothing is copied, and a write into the
+        reps raises instead of corrupting the source column); several
+        keys are written once each into one preallocated array. Any
+        other column (string, float, unsigned, bool, nullable) goes
+        through ``Column.key_rep()`` as before."""
+        cols = [self.column(n) for n in names]
+        if len(cols) == 1:
+            held = cols[0]._held_key_rep()
+            if held is not None and held.flags.c_contiguous:
+                view = held[None, :]
+                view.flags.writeable = False
+                return view
+        out = np.empty((len(cols), self.num_rows), dtype=np.int64)
+        for row, col in zip(out, cols):
+            held = col._held_key_rep()
+            row[:] = col.key_rep() if held is None else held
+        return out
 
     def null_any(self, names: Sequence[str]) -> np.ndarray:
         """[num_rows] bool: True where ANY named column is null. The

@@ -71,6 +71,10 @@ KERNEL_TWINS = {
         "expand_match_ranges_i64",
         "hyperspace_tpu.ops.join.expand_match_ranges_numpy",
     ),
+    "hs_split_words_i64": (
+        "split_words_i64",
+        "hyperspace_tpu.ops.hash.split_words_np",
+    ),
     "hs_gather_i64": ("gather_i64", "numpy.take"),
     "hs_gather_f64": ("gather_f64", "numpy.take"),
     "hs_range_mask": (
@@ -352,6 +356,17 @@ def load(wait: bool = True):
                 _i64p,
                 ctypes.c_int32,
             ]
+            lib.hs_split_words_i64.restype = ctypes.c_int
+            lib.hs_split_words_i64.argtypes = [
+                _i64p,
+                ctypes.c_int64,
+                ctypes.POINTER(ctypes.c_uint32),
+                ctypes.POINTER(ctypes.c_uint32),
+                ctypes.c_int64,
+                ctypes.c_uint32,
+                ctypes.c_uint32,
+                ctypes.c_int32,
+            ]
             lib.hs_expand_match_ranges_i64.restype = ctypes.c_int64
             lib.hs_expand_match_ranges_i64.argtypes = [
                 _i64p,
@@ -535,6 +550,68 @@ def partition_by_bucket_i32(
     if rc != 0:
         return None
     return order, offsets
+
+
+def split_words_i64(
+    key_reps: np.ndarray,
+    out: np.ndarray,
+    hi_xor: int = 0,
+    hi_first: bool = False,
+    pad: int = 0,
+) -> bool:
+    """The int64 -> uint32 word split of ``[k, n]`` key reps, one
+    threaded pass a key column, written straight into the caller's
+    C-contiguous ``uint32[2k, row_len >= n]`` block: key ``j``'s low
+    words into row ``2j`` and its high words, XORed with ``hi_xor``, into
+    row ``2j + 1`` (the other way round under ``hi_first``), and every
+    row's slots past ``n`` set to ``pad``. Bit-exact twin of
+    ``ops/hash.split_words_np`` plus its zero tail (``hi_xor`` 0) and of
+    ``ops/sort._order_words_numpy`` (``hi_xor`` 0x80000000,
+    ``hi_first``). False — nothing of ``out`` is then to be relied on,
+    the caller runs the numpy twin — when the kernel is unavailable or a
+    key row is not 8-byte integers laid out contiguously."""
+    if (
+        out.dtype != np.uint32
+        or not out.flags.c_contiguous
+        or not out.flags.writeable
+        or out.ndim != 2
+        or key_reps.ndim != 2
+        or out.shape[0] != 2 * key_reps.shape[0]
+        or out.shape[1] < key_reps.shape[1]
+    ):
+        # the kernel writes through raw row pointers: a contract
+        # violation must be loud, never a clobbered neighbour
+        raise ValueError(
+            "split_words_i64 requires a writeable C-contiguous "
+            "uint32[2k, >= n] output"
+        )
+    lib = load(wait=False)
+    if lib is None:
+        return False
+    k, n = key_reps.shape
+    if key_reps.dtype not in (np.int64, np.uint64) or (
+        n > 1 and key_reps.strides[1] != 8
+    ):
+        return False
+    _i64p = ctypes.POINTER(ctypes.c_int64)
+    _u32p = ctypes.POINTER(ctypes.c_uint32)
+    row_len = out.shape[1]
+    threads = _n_threads(row_len)
+    for j in range(k):
+        lo_row, hi_row = (2 * j + 1, 2 * j) if hi_first else (2 * j, 2 * j + 1)
+        rc = lib.hs_split_words_i64(
+            key_reps[j].ctypes.data_as(_i64p),
+            ctypes.c_int64(n),
+            out[lo_row].ctypes.data_as(_u32p),
+            out[hi_row].ctypes.data_as(_u32p),
+            ctypes.c_int64(row_len),
+            ctypes.c_uint32(hi_xor),
+            ctypes.c_uint32(pad),
+            ctypes.c_int32(threads),
+        )
+        if rc != 0:
+            return False
+    return True
 
 
 def merge_join_count_i64(

@@ -865,4 +865,51 @@ int hs_bucket_ids_i64(const int64_t** keys, int32_t k, int64_t n,
   return 0;
 }
 
+// The int64 -> uint32 word split of ONE contiguous key column, one pass:
+// lo_out[i] = low word of src[i], hi_out[i] = high word ^ hi_xor for
+// i < n, and both rows' slots [n, row_len) = pad. The two layouts the
+// build needs are the same pass: the hash's word block (hi_xor 0, lo row
+// before hi row, zero tail up to the padded length the device program is
+// compiled for; twin ops/hash.split_words_np + its np.concatenate) and
+// the sort's order words (hi_xor 0x80000000 flips the sign bit so that
+// unsigned plane order is signed int64 order, hi row first, no tail;
+// twin ops/sort._order_words_numpy). The numpy twins make five or six
+// full-array passes with a fresh temporary each on one thread; this
+// reads 8 B and writes 8 B a row, threaded by contiguous row chunks so
+// that the freshly mapped output pages are first touched in parallel.
+// Returns 0 on success, 1 on bad arguments, 2 on resource exhaustion.
+int hs_split_words_i64(const int64_t* src, int64_t n, uint32_t* lo_out,
+                       uint32_t* hi_out, int64_t row_len, uint32_t hi_xor,
+                       uint32_t pad, int32_t n_threads) {
+  if (n < 0 || row_len < n || (n > 0 && src == nullptr) ||
+      (row_len > 0 && (lo_out == nullptr || hi_out == nullptr)))
+    return 1;
+  if (row_len == 0) return 0;
+  if (n_threads < 1) n_threads = 1;
+  const int T = static_cast<int>(
+      std::min<int64_t>(row_len < (1 << 16) ? 1 : n_threads, row_len));
+  try {
+    // whole cache lines of both outputs a chunk: no line has two writers
+    const int64_t chunk = ((row_len + T - 1) / T + 15) & ~int64_t{15};
+    auto work = [&](int t) {
+      const int64_t lo = std::min<int64_t>(row_len, t * chunk);
+      const int64_t hi = std::min<int64_t>(row_len, lo + chunk);
+      const int64_t mid = std::max(lo, std::min(hi, n));
+      for (int64_t i = lo; i < mid; ++i) {
+        const uint64_t v = static_cast<uint64_t>(src[i]);
+        lo_out[i] = static_cast<uint32_t>(v);
+        hi_out[i] = static_cast<uint32_t>(v >> 32) ^ hi_xor;
+      }
+      for (int64_t i = mid; i < hi; ++i) {
+        lo_out[i] = pad;
+        hi_out[i] = pad;
+      }
+    };
+    run_on_threads(T, work);
+  } catch (...) {
+    return 2;
+  }
+  return 0;
+}
+
 }  // extern "C"

@@ -248,7 +248,8 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
         "device round trip (ops/hash) is not confused with the host's "
         "key encoding; key_columns (the indexed columns of the key) on "
         "key_reps and, set, on the root: every host pass and the h2d "
-        "go by it",
+        "go by it; copied on key_reps: the key columns whose bytes were "
+        "copied on the way (0 where one int64 column is its own reps)",
     ),
     "hyperspace_tpu.indexes.covering_build.bucketize": (
         "span",
@@ -304,7 +305,18 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
         "kernel inside a 1.2 s stage is explained only by separating "
         "the host's word split and each transfer from the kernel wait; "
         "split_words says words (the uint32 block's first dimension, "
-        "two a key column)",
+        "two a key column) and native (1: the block was made by the one "
+        "native pass, 0: by its numpy twin)",
+    ),
+    "hyperspace_tpu.ops.sort._order_words_np": (
+        "attr",
+        "native (1 | 0: the order words were made by the one native "
+        "pass, or by the numpy twin) on the partition span of both "
+        "build tails where the order words run under it, and on no "
+        "other (the serve path sorts through here too): whether 0.7 s a key "
+        "column of partition are five numpy passes or one native one "
+        "is the first thing its seconds are read against — never a "
+        "span of its own",
     ),
     "hyperspace_tpu.ops.zorder.ZOrderEncoder.planes_from_encodings": (
         "span",

@@ -177,6 +177,29 @@ def bucket_ids_numpy(
     return (h % np.uint32(num_buckets)).astype(np.int32)
 
 
+def _padded_words(key_reps: np.ndarray, n_pad: int) -> tuple[np.ndarray, bool]:
+    """The device kernel's input: ``split_words_np(key_reps)`` with a
+    zero tail up to ``n_pad`` rows, and whether the native pass made it.
+    At or above the native hash threshold one native pass a key column
+    writes words and tail straight into the block
+    (``native.split_words_i64``); below it, or where the library is not
+    loaded or a key row is not contiguous 8-byte integers, the numpy
+    twin and a ``np.concatenate``. The same shape, dtype and bytes
+    either way: one device program."""
+    k, n = key_reps.shape
+    if k and n >= _native_hash_min_rows():
+        from hyperspace_tpu import native
+
+        words = np.empty((2 * k, n_pad), dtype=np.uint32)
+        if native.split_words_i64(key_reps, words):
+            return words, True
+    words = split_words_np(key_reps)
+    if n_pad != n:
+        tail = np.zeros((words.shape[0], n_pad - n), dtype=np.uint32)
+        words = np.concatenate([words, tail], axis=1)
+    return words, False
+
+
 def bucket_ids_np(key_reps: np.ndarray, num_buckets: int, seed: int = 42) -> np.ndarray:
     """Host entry: [k, n] int64 key reps -> int32 bucket ids. Large inputs
     hash on device (padded to a power of two, ops/__init__ shape policy);
@@ -184,7 +207,8 @@ def bucket_ids_np(key_reps: np.ndarray, num_buckets: int, seed: int = 42) -> np.
 
     Under a live trace the device path is four spans — ``split_words``
     (the host's int64 -> uint32 word split and padding; ``words`` is the
-    block's first dimension, two a key column), ``h2d`` (to
+    block's first dimension, two a key column, ``native`` 1 where the
+    native pass made the block and 0 where its numpy twin did), ``h2d`` (to
     ``block_until_ready`` of the words on the device), ``kernel``
     (dispatch to ``block_until_ready``), ``d2h`` — with the bytes each
     way counted on the root; the host path is one ``host_hash``."""
@@ -195,17 +219,9 @@ def bucket_ids_np(key_reps: np.ndarray, num_buckets: int, seed: int = 42) -> np.
         with _obs_trace.span("host_hash"):
             return bucket_ids_host(key_reps, num_buckets, seed)
     with _obs_trace.span("split_words") as split_sp:
-        words = split_words_np(key_reps)
+        words, ran_native = _padded_words(key_reps, pad_len(n))
         split_sp.set("words", int(words.shape[0]))
-        n_pad = pad_len(n)
-        if n_pad != n:
-            words = np.concatenate(
-                [
-                    words,
-                    np.zeros((words.shape[0], n_pad - n), dtype=np.uint32),
-                ],
-                axis=1,
-            )
+        split_sp.set("native", int(ran_native))
     with _obs_trace.span("h2d", bytes=int(words.nbytes)):
         on_device = jax.block_until_ready(jnp.asarray(words))
     with _obs_trace.span("kernel"):

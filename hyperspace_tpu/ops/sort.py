@@ -89,13 +89,43 @@ def _native_partition_min_rows() -> int:
     )
 
 
-def _order_words_np(key_reps: np.ndarray) -> np.ndarray:
-    """[k, n] int64 -> [2k, n] uint32 planes whose lexicographic order
-    (row 0 major) equals signed-int64 order of the keys."""
+def _order_words_numpy(key_reps: np.ndarray) -> np.ndarray:
+    """The pure-numpy leg of :func:`_order_words_np`, never dispatching
+    to the native pass: its bit-exact twin."""
     u = np.ascontiguousarray(key_reps).view(np.uint64)
     lo = (u & np.uint64(0xFFFFFFFF)).astype(np.uint32)
     hi = ((u >> np.uint64(32)).astype(np.uint32)) ^ _SIGN  # flip sign bit
     return np.stack([w for pair in zip(hi, lo) for w in pair])
+
+
+def _order_words_np(key_reps: np.ndarray) -> np.ndarray:
+    """[k, n] int64 -> [2k, n] uint32 planes whose lexicographic order
+    (row 0 major) equals signed-int64 order of the keys.
+
+    At or above the native partition threshold (a plain O(n) pass, like
+    the counting scatter it runs beside) one native pass a key column
+    writes the planes (``native.split_words_i64``: the hash's word
+    split with the sign bit flipped and the high plane first); below it,
+    or where the library is not loaded or a key row is not contiguous
+    8-byte integers, :func:`_order_words_numpy`. A build's ``partition``
+    span, where it is the span live in the caller, gets ``native`` 1 | 0
+    for which of the two ran (no other span does: the serve path's
+    sorts come through here too)."""
+    k, n = key_reps.shape
+    ran_native = False
+    if k and n >= _native_partition_min_rows():
+        from hyperspace_tpu import native
+
+        planes = np.empty((2 * k, n), dtype=np.uint32)
+        ran_native = native.split_words_i64(
+            key_reps, planes, hi_xor=int(_SIGN), hi_first=True
+        )
+    if not ran_native:
+        planes = _order_words_numpy(key_reps)
+    sp = _obs_trace.current()
+    if sp is not None and sp.name == "partition":
+        sp.set("native", int(ran_native))
+    return planes
 
 
 @jax.jit

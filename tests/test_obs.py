@@ -595,11 +595,60 @@ class TestActionAccount:
         by_name = {sp.name: sp.attrs for sp in root.spans}
         assert root.attrs["key_columns"] == 1
         assert by_name["key_reps"]["key_columns"] == 1
+        # one int64 key is its own reps: nothing was copied on the way;
+        # the order words say which of the pass and its twin made them
+        assert by_name["key_reps"]["copied"] == 0
+        assert by_name["partition"]["native"] in (0, 1)
         assert by_name["bucket_sorts"]["planes"] == 2
         assert 0 < by_name["bucket_sorts"]["max_rows"] <= 20_000
         write = by_name["write"]
         assert write["columns"] == 2
         assert 0 < write["take_s"] + write["encode_s"] <= write["sum_s"] + 1e-6
+
+    @pytest.mark.parametrize("keys", [["k"], ["k", "q"]], ids=["1key", "2keys"])
+    def test_the_word_split_keeps_its_spans_and_says_what_ran(
+        self, session_factory, tmp_path, monkeypatch, keys
+    ):
+        """PR 39: the int64 -> uint32 word split is one native pass
+        into the hash's block and into the sort's order planes. The
+        spans are where they were (``key_reps`` and ``split_words``
+        under ``hash_shuffle``, ``partition`` under ``sort``); what ran
+        is attrs on them; the device is handed the bytes it was."""
+        from hyperspace_tpu import native
+        from hyperspace_tpu.ops import hash as hash_ops
+        from hyperspace_tpu.ops import pad_len
+
+        rows = 1 << 20
+        monkeypatch.setattr(hash_ops, "_HOST_HASH_MAX_ROWS", rows - 1)
+        s = session_factory(1)
+        idir, _odir = _lake(tmp_path, n=rows)
+        Hyperspace(s).create_index(
+            s.read.parquet(idir),
+            CoveringIndexConfig("ws1", keys, [c for c in ("k", "q") if c not in keys]),
+        )
+        root = trace.finished("action.CreateAction")[-1]
+        _assert_trace_integrity(root)
+        by_id = {sp.span_id: sp for sp in root.spans}
+        found = {}
+        for name in ("key_reps", "split_words", "partition"):
+            (found[name],) = [sp for sp in root.spans if sp.name == name]
+        parents = {n: by_id[sp.parent_id].name for n, sp in found.items()}
+        assert parents == {
+            "key_reps": "hash_shuffle",
+            "split_words": "hash_shuffle",
+            "partition": "sort",
+        }
+        ran = int(native.load() is not None)  # 2^20 rows: over every threshold
+        assert found["split_words"].attrs["native"] == ran
+        assert found["partition"].attrs["native"] == ran
+        assert found["split_words"].attrs["words"] == 2 * len(keys)
+        assert found["key_reps"].attrs["key_columns"] == len(keys)
+        assert found["key_reps"].attrs["copied"] == (0 if len(keys) == 1 else 2)
+        # 8 B a key column a padded row, as before the pass
+        assert root.attrs["h2d_bytes"] == 8 * len(keys) * pad_len(rows)
+        assert root.attrs["h2d_bytes"] == 8 * len(keys) * rows
+        (h2d,) = [sp for sp in root.spans if sp.name == "h2d"]
+        assert h2d.attrs["bytes"] == root.attrs["h2d_bytes"]
 
     def test_aggstate_span_counts_its_sweeps(
         self, session_factory, tmp_path, monkeypatch
