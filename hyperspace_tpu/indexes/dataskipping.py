@@ -12,6 +12,7 @@ just fewer files (``DataSkippingFileIndex``,
 from __future__ import annotations
 
 import json
+import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -24,7 +25,24 @@ from hyperspace_tpu.indexes.registry import register_index
 from hyperspace_tpu.indexes.sketches import Sketch, sketch_from_dict
 from hyperspace_tpu.io import parquet as pio
 from hyperspace_tpu.io.columnar import ColumnarBatch
+from hyperspace_tpu.obs import trace
 from hyperspace_tpu.plan import expressions as E
+
+#: the ``sketch`` span's attrs, each a sum over the source files: the
+#: read (parquet + ColumnarBatch), each sketch kind's ``aggregate`` (its
+#: ``seconds_attr``; a kind the index does not hold reads 0, one not
+#: named here appears where it runs), inside the bloom's the word split
+#: and pad, the device leg as the host sees it and the host's bit
+#: setting, and last the sketch table's assembly
+_SKETCH_SECONDS = (
+    "read_s",
+    "minmax_s",
+    "bloom_s",
+    "words_s",
+    "device_s",
+    "set_bits_s",
+    "table_s",
+)
 
 
 @register_index
@@ -101,37 +119,58 @@ class DataSkippingIndex(Index):
         fields: List[Tuple[str, pa.DataType]] = [(DATA_FILE_NAME_ID, pa.int64())]
         rows: List[Dict] = []
         out_fields = None
-        for f, size, mtime in sorted(
-            source_file_infos(ctx.session, plan_relation)
-        ):
-            fid = ctx.file_id_tracker.add_file(f, size, mtime)
-            batch = ColumnarBatch.from_arrow(pio.read_table([f], cols, fmt))
-            row = {DATA_FILE_NAME_ID: fid}
-            if out_fields is None:
-                out_fields = list(fields)
+        # what the span's seconds went to, summed over the files
+        acc = dict.fromkeys(_SKETCH_SECONDS, 0.0)
+        acc.update(h2d_bytes=0, d2h_bytes=0)
+        n_rows = 0
+        with trace.span("sketch") as sp:
+            for f, size, mtime in sorted(
+                source_file_infos(ctx.session, plan_relation)
+            ):
+                fid = ctx.file_id_tracker.add_file(f, size, mtime)
+                t0 = time.perf_counter()
+                batch = ColumnarBatch.from_arrow(pio.read_table([f], cols, fmt))
+                acc["read_s"] += time.perf_counter() - t0
+                row = {DATA_FILE_NAME_ID: fid}
+                if out_fields is None:
+                    out_fields = list(fields)
+                    for s in self.sketches:
+                        src_t = batch.column(s.referenced_columns()[0]).arrow_type
+                        out_fields.extend(s.output_fields(src_t))
                 for s in self.sketches:
-                    src_t = batch.column(s.referenced_columns()[0]).arrow_type
-                    out_fields.extend(s.output_fields(src_t))
-            for s in self.sketches:
-                row.update(s.aggregate(batch))
-            rows.append(row)
-        if out_fields is None:
-            raise HyperspaceException("No source files to sketch")
-        return pa.table(
-            {
-                name: pa.array([r.get(name) for r in rows], type=t)
-                for name, t in out_fields
-            }
-        )
+                    t0 = time.perf_counter()
+                    row.update(s.aggregate(batch, acc))
+                    acc[s.seconds_attr] = (
+                        acc.get(s.seconds_attr, 0.0) + time.perf_counter() - t0
+                    )
+                rows.append(row)
+                n_rows += batch.num_rows
+            if out_fields is None:
+                raise HyperspaceException("No source files to sketch")
+            t0 = time.perf_counter()
+            table = pa.table(
+                {
+                    name: pa.array([r.get(name) for r in rows], type=t)
+                    for name, t in out_fields
+                }
+            )
+            acc["table_s"] += time.perf_counter() - t0
+            trace.accumulate("h2d_bytes", acc.pop("h2d_bytes"))
+            trace.accumulate("d2h_bytes", acc.pop("d2h_bytes"))
+            sp.set("files", len(rows))
+            sp.set("rows", n_rows)
+            for key, seconds in acc.items():
+                sp.set(key, round(seconds, 6))
+        return table
 
     def write(self, ctx, index_data: pa.Table) -> None:
         import os
 
-        os.makedirs(ctx.index_data_path, exist_ok=True)
-        pio.write_table(
-            os.path.join(ctx.index_data_path, "part-00000-sketch.parquet"),
-            index_data,
-        )
+        path = os.path.join(ctx.index_data_path, "part-00000-sketch.parquet")
+        with trace.span("write", files=1) as sp:
+            os.makedirs(ctx.index_data_path, exist_ok=True)
+            pio.write_table(path, index_data)
+            sp.set("bytes", os.path.getsize(path))
 
     def optimize(self, ctx, files_to_optimize: List[str]) -> None:
         table = pio.read_table(files_to_optimize, None)

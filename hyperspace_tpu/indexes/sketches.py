@@ -50,6 +50,8 @@ _normalize_conjunct = E.normalize_comparison
 
 class Sketch:
     kind = "Sketch"
+    #: the build's ``sketch`` span attr that sums this kind's aggregate seconds
+    seconds_attr = "sketch_s"
 
     def __init__(self, column: str):
         self.column = column
@@ -83,8 +85,12 @@ class Sketch:
     def output_fields(self, source_type: pa.DataType) -> List[Tuple[str, pa.DataType]]:
         raise NotImplementedError
 
-    def aggregate(self, batch: ColumnarBatch) -> Dict[str, Any]:
-        """One source file's batch -> sketch cell values."""
+    def aggregate(
+        self, batch: ColumnarBatch, acc: Optional[Dict[str, float]] = None
+    ) -> Dict[str, Any]:
+        """One source file's batch -> sketch cell values. ``acc``, where
+        given, sums what a sketch's seconds went to (``perf_counter``
+        deltas by name, the build span's attrs)."""
         raise NotImplementedError
 
     def convert_predicate(
@@ -98,6 +104,7 @@ class Sketch:
 @register_sketch
 class MinMaxSketch(Sketch):
     kind = "MinMaxSketch"
+    seconds_attr = "minmax_s"
 
     def output_fields(self, source_type):
         return [
@@ -105,7 +112,7 @@ class MinMaxSketch(Sketch):
             (f"MinMax_{self.column}__max", source_type),
         ]
 
-    def aggregate(self, batch):
+    def aggregate(self, batch, acc=None):
         lo, hi = _column_min_max(batch.column(self.column))
         return {
             f"MinMax_{self.column}__min": lo,
@@ -192,6 +199,7 @@ class MinMaxSketch(Sketch):
 @register_sketch
 class BloomFilterSketch(Sketch):
     kind = "BloomFilterSketch"
+    seconds_attr = "bloom_s"
 
     def __init__(self, column: str, fpp: float = 0.01, expected_items: int = 10000):
         super().__init__(column)
@@ -221,7 +229,7 @@ class BloomFilterSketch(Sketch):
     def output_fields(self, source_type):
         return [(f"BloomFilter_{self.column}__bits", pa.binary())]
 
-    def aggregate(self, batch):
+    def aggregate(self, batch, acc=None):
         from hyperspace_tpu.ops.bloom import build_bloom
 
         col = batch.column(self.column)
@@ -229,7 +237,7 @@ class BloomFilterSketch(Sketch):
         nulls = col.null_mask
         if nulls is not None:
             reps = reps[~nulls]
-        words = build_bloom(reps, self.m, self.k)
+        words = build_bloom(reps, self.m, self.k, acc)
         return {f"BloomFilter_{self.column}__bits": words.tobytes()}
 
     def _probe(self, table: pa.Table, values) -> Optional[np.ndarray]:
@@ -333,6 +341,7 @@ class PartitionSketch(Sketch):
     constancy per file at build time, which also covers partition dirs)."""
 
     kind = "PartitionSketch"
+    seconds_attr = "partition_s"
 
     def output_fields(self, source_type):
         return [
@@ -340,7 +349,7 @@ class PartitionSketch(Sketch):
             (f"Partition_{self.column}__const", pa.bool_()),
         ]
 
-    def aggregate(self, batch):
+    def aggregate(self, batch, acc=None):
         col = batch.column(self.column)
         val, const = None, False
         if batch.num_rows:

@@ -124,6 +124,9 @@ BUILD_STAGES = (
     "words",
     "zorder_sort",
     "take",
+    # a data-skipping build (indexes/dataskipping): its one per-file
+    # loop, with write as above
+    "sketch",
 )
 
 #: advisor-side stage spans (advisor/: query-log mining and what-if
@@ -353,6 +356,20 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
         "span",
         "the same stage names where the streamed build's spill and "
         "merge run the same steps, once a wave or a z-range",
+    ),
+    "hyperspace_tpu.indexes.dataskipping.DataSkippingIndex.build_sketch_rows": (
+        "span",
+        "sketch: the data-skipping build's one loop over the source "
+        "files (read, then each sketch, file after file) is one span a "
+        "build; files / rows and what its seconds went to, perf_counter "
+        "deltas summed over the files, as attrs (read_s, minmax_s, "
+        "bloom_s and inside it words_s / device_s / set_bits_s, "
+        "table_s), the bloom's h2d_bytes / d2h_bytes from shapes on the "
+        "root — never a span a file or a sketch",
+    ),
+    "hyperspace_tpu.indexes.dataskipping.DataSkippingIndex.write": (
+        "span",
+        "write of the one sketch file, files / bytes as attrs",
     ),
     "hyperspace_tpu.indexes.aggindex.capture_index_dir": (
         "span",

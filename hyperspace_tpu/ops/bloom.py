@@ -11,7 +11,8 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Tuple
+import time
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,12 +43,18 @@ def _bit_indices(words, m: int, k: int):
     return jnp.stack(idx)
 
 
-def bit_indices_np(key_reps: np.ndarray, m: int, k: int) -> np.ndarray:
+def bit_indices_np(
+    key_reps: np.ndarray, m: int, k: int, acc: Optional[Dict] = None
+) -> np.ndarray:
     """Host entry of :func:`_bit_indices`: int64 key reps [n] -> [k, n]
     int32 bit indices. The row dimension is padded to ``pad_len``
     (ops/__init__ shape policy) — a data-skipping build hashes one batch
     per source file, and an unpadded ``n`` would compile once per
-    distinct file row count."""
+    distinct file row count. ``acc``, where given, sums ``words_s`` (the
+    split and pad), ``device_s`` (from the upload to the fetch's return:
+    dispatch, both transfers and the kernel as the host sees them) and
+    the bytes each way, ``h2d_bytes`` and ``d2h_bytes``, from shapes."""
+    t0 = time.perf_counter()
     n = len(key_reps)
     words = split_words_np(np.asarray(key_reps, dtype=np.int64)[None, :])
     n_pad = pad_len(n)
@@ -55,17 +62,32 @@ def bit_indices_np(key_reps: np.ndarray, m: int, k: int) -> np.ndarray:
         words = np.concatenate(
             [words, np.zeros((2, n_pad - n), dtype=np.uint32)], axis=1
         )
-    return np.asarray(_bit_indices(jnp.asarray(words), m, k))[:, :n]
+    t1 = time.perf_counter()
+    idx = np.asarray(_bit_indices(jnp.asarray(words), m, k))
+    if acc is not None:
+        acc["words_s"] += t1 - t0
+        acc["device_s"] += time.perf_counter() - t1
+        acc["h2d_bytes"] += words.nbytes
+        acc["d2h_bytes"] += idx.nbytes
+    return idx[:, :n]
 
 
-def build_bloom(key_reps: np.ndarray, m: int, k: int) -> np.ndarray:
-    """int64 key reps [n] -> packed bit array as uint64 words [m/64]."""
+def build_bloom(
+    key_reps: np.ndarray, m: int, k: int, acc: Optional[Dict] = None
+) -> np.ndarray:
+    """int64 key reps [n] -> packed bit array as uint64 words [m/64].
+    ``acc``, where given, sums :func:`bit_indices_np`'s and
+    ``set_bits_s``, the host's setting and packing of the bits."""
     if len(key_reps) == 0:
         return np.zeros(m // 64, dtype=np.uint64)
-    idx = bit_indices_np(key_reps, m, k).ravel()
+    idx = bit_indices_np(key_reps, m, k, acc)
+    t0 = time.perf_counter()
     bits = np.zeros(m, dtype=bool)
-    bits[idx] = True
-    return np.packbits(bits, bitorder="little").view(np.uint64)
+    bits[idx.ravel()] = True
+    packed = np.packbits(bits, bitorder="little").view(np.uint64)
+    if acc is not None:
+        acc["set_bits_s"] += time.perf_counter() - t0
+    return packed
 
 
 def might_contain(bloom_words: np.ndarray, key_reps: np.ndarray, m: int, k: int):
