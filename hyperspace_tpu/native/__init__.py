@@ -75,6 +75,14 @@ KERNEL_TWINS = {
         "split_words_i64",
         "hyperspace_tpu.ops.hash.split_words_np",
     ),
+    "hs_order_u64": (
+        "order_u64",
+        "hyperspace_tpu.ops.zorder.order_u64_numpy",
+    ),
+    "hs_zorder_words": (
+        "zorder_words",
+        "hyperspace_tpu.ops.zorder.range_words_np",
+    ),
     "hs_gather_i64": ("gather_i64", "numpy.take"),
     "hs_gather_f64": ("gather_f64", "numpy.take"),
     "hs_range_mask": (
@@ -367,6 +375,26 @@ def load(wait: bool = True):
                 ctypes.c_uint32,
                 ctypes.c_int32,
             ]
+            lib.hs_order_u64.restype = ctypes.c_int
+            lib.hs_order_u64.argtypes = [
+                ctypes.c_void_p,
+                ctypes.c_int64,
+                ctypes.c_int32,
+                ctypes.POINTER(ctypes.c_uint8),
+                ctypes.POINTER(ctypes.c_uint64),
+                ctypes.c_int32,
+            ]
+            lib.hs_zorder_words.restype = ctypes.c_int
+            lib.hs_zorder_words.argtypes = [
+                ctypes.POINTER(ctypes.c_uint64),
+                ctypes.c_int64,
+                ctypes.c_uint64,
+                ctypes.c_double,
+                ctypes.c_uint32,
+                ctypes.POINTER(ctypes.c_uint32),
+                ctypes.c_int64,
+                ctypes.c_int32,
+            ]
             lib.hs_expand_match_ranges_i64.restype = ctypes.c_int64
             lib.hs_expand_match_ranges_i64.argtypes = [
                 _i64p,
@@ -612,6 +640,92 @@ def split_words_i64(
         if rc != 0:
             return False
     return True
+
+
+def order_u64(
+    values: np.ndarray, validity: Optional[np.ndarray] = None
+) -> Optional[np.ndarray]:
+    """The order-preserving uint64 of one numeric column, one threaded
+    pass into a fresh array: int64 (dates and timestamps as their int64
+    view) with the sign bit flipped, float64 bits in IEEE total order,
+    and under a bool ``validity`` mask ``max(enc, 1)`` on valid rows and
+    0 on nulls. Bit-exact twin of ``ops/zorder.order_u64_numpy``. None —
+    the caller runs the twin — when the kernel is unavailable or the
+    column is not contiguous little-endian int64/float64 with a
+    contiguous mask of its length."""
+    lib = load(wait=False)
+    if lib is None:
+        return None
+    if (
+        values.ndim != 1
+        or values.dtype not in (np.int64, np.float64)
+        or not values.flags.c_contiguous
+    ):
+        return None
+    n = len(values)
+    if validity is not None and (
+        validity.dtype != np.bool_
+        or validity.shape != (n,)
+        or not validity.flags.c_contiguous
+    ):
+        return None
+    out = np.empty(n, dtype=np.uint64)
+    rc = lib.hs_order_u64(
+        ctypes.c_void_p(values.ctypes.data),
+        ctypes.c_int64(n),
+        ctypes.c_int32(values.dtype == np.float64),
+        None
+        if validity is None
+        else validity.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+        out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
+        ctypes.c_int32(_n_threads(n)),
+    )
+    return out if rc == 0 else None
+
+
+def zorder_words(
+    enc: np.ndarray, mn: int, scale: float, top: int, out: np.ndarray
+) -> bool:
+    """One column's z-words under a range spec, one threaded pass
+    written straight into ``out`` (a C-contiguous ``uint32`` row of at
+    least ``len(enc)`` slots, a row of the block the interleave
+    uploads): ``trunc(clip(float64(enc - mn) * scale, 0, top))``, and
+    ``top`` in every slot past ``len(enc)``. ``scale`` is the caller's
+    float64, used as given. Bit-exact twin of
+    ``ops/zorder.range_words_np`` plus that fill. False — nothing of
+    ``out`` is then to be relied on, the caller runs the twin — when the
+    kernel is unavailable or ``enc`` is not contiguous uint64."""
+    if (
+        out.dtype != np.uint32
+        or out.ndim != 1
+        or not out.flags.c_contiguous
+        or not out.flags.writeable
+        or enc.ndim != 1
+        or len(out) < len(enc)
+    ):
+        # the kernel writes through a raw pointer: a contract violation
+        # must be loud, never a clobbered neighbour
+        raise ValueError(
+            "zorder_words requires a writeable C-contiguous uint32[>= n] "
+            "output"
+        )
+    lib = load(wait=False)
+    if lib is None:
+        return False
+    if enc.dtype != np.uint64 or not enc.flags.c_contiguous:
+        return False
+    row_len = len(out)
+    rc = lib.hs_zorder_words(
+        enc.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
+        ctypes.c_int64(len(enc)),
+        ctypes.c_uint64(int(mn)),
+        ctypes.c_double(float(scale)),
+        ctypes.c_uint32(int(top)),
+        out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
+        ctypes.c_int64(row_len),
+        ctypes.c_int32(_n_threads(row_len)),
+    )
+    return rc == 0
 
 
 def merge_join_count_i64(

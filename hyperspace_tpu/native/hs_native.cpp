@@ -169,6 +169,19 @@ inline uint64_t mix64(uint64_t x) {
   return x;
 }
 
+// Rows [lo, hi) of hs_order_u64, one loop a dtype and validity case.
+template <bool F64, bool VALID>
+void order_u64_rows(const uint64_t* src, const uint8_t* valid, uint64_t* out,
+                    int64_t lo, int64_t hi) {
+  const uint64_t sign = uint64_t{1} << 63;
+  for (int64_t i = lo; i < hi; ++i) {
+    const uint64_t b = src[i];
+    uint64_t e = F64 ? ((b >> 63) ? ~b : (b | sign)) : (b ^ sign);
+    if (VALID) e = valid[i] ? (e ? e : 1) : 0;
+    out[i] = e;
+  }
+}
+
 }  // namespace
 
 extern "C" {
@@ -904,6 +917,90 @@ int hs_split_words_i64(const int64_t* src, int64_t n, uint32_t* lo_out,
         lo_out[i] = pad;
         hi_out[i] = pad;
       }
+    };
+    run_on_threads(T, work);
+  } catch (...) {
+    return 2;
+  }
+  return 0;
+}
+
+// The order-preserving uint64 of ONE contiguous 8-byte numeric column
+// (the z-order build's per-column encoding), one pass: float64 bits in
+// IEEE total order (sign bit set -> all bits flipped, else the sign bit
+// set), int64 with its sign bit flipped (dates and timestamps arrive as
+// int64), and where `valid` is given, max(enc, 1) on a valid row and 0
+// on a null. Bit-exact twin of ops/zorder.order_u64_numpy, which makes
+// two or three whole-array passes with a temporary each on one thread;
+// this reads 8 B and writes 8 B a row, threaded by contiguous row
+// chunks so that the fresh output's pages are first touched in
+// parallel. Returns 0 on success, 1 on bad arguments, 2 on resource
+// exhaustion.
+int hs_order_u64(const void* src, int64_t n, int32_t is_f64,
+                 const uint8_t* valid, uint64_t* out, int32_t n_threads) {
+  if (n < 0 || (n > 0 && (src == nullptr || out == nullptr))) return 1;
+  if (n == 0) return 0;
+  if (n_threads < 1) n_threads = 1;
+  const int T = static_cast<int>(
+      std::min<int64_t>(n < (1 << 16) ? 1 : n_threads, n));
+  const uint64_t* s = static_cast<const uint64_t*>(src);
+  try {
+    // whole cache lines of the output a chunk: no line has two writers
+    const int64_t chunk = ((n + T - 1) / T + 7) & ~int64_t{7};
+    auto work = [&](int t) {
+      const int64_t lo = std::min<int64_t>(n, t * chunk);
+      const int64_t hi = std::min<int64_t>(n, lo + chunk);
+      if (is_f64) {
+        if (valid) order_u64_rows<true, true>(s, valid, out, lo, hi);
+        else order_u64_rows<true, false>(s, valid, out, lo, hi);
+      } else {
+        if (valid) order_u64_rows<false, true>(s, valid, out, lo, hi);
+        else order_u64_rows<false, false>(s, valid, out, lo, hi);
+      }
+    };
+    run_on_threads(T, work);
+  } catch (...) {
+    return 2;
+  }
+  return 0;
+}
+
+// The z-word of ONE column under a range spec, one pass, written into
+// one row of the block the interleave uploads: out[i] =
+// trunc(clip(double(enc[i] - mn) * scale, 0, top)) for i < n (the
+// subtraction wraps as numpy's uint64 does), and slots [n, row_len) =
+// top, the block's fill. `scale` is computed by the caller, once, the
+// twin's way; here the same IEEE operations in the same order (the
+// uint64 -> double conversion, one multiply, the clip, truncation) and
+// no flag that reassociates or contracts them. Bit-exact twin of
+// ops/zorder.range_words_np plus the tail its caller fills, which make
+// five whole-array passes with four temporaries on one thread. Returns
+// 0 on success, 1 on bad arguments, 2 on resource exhaustion.
+int hs_zorder_words(const uint64_t* enc, int64_t n, uint64_t mn,
+                    double scale, uint32_t top, uint32_t* out,
+                    int64_t row_len, int32_t n_threads) {
+  if (n < 0 || row_len < n || (n > 0 && enc == nullptr) ||
+      (row_len > 0 && out == nullptr))
+    return 1;
+  if (row_len == 0) return 0;
+  if (n_threads < 1) n_threads = 1;
+  const int T = static_cast<int>(
+      std::min<int64_t>(row_len < (1 << 16) ? 1 : n_threads, row_len));
+  const double top_f = static_cast<double>(top);
+  try {
+    // whole cache lines of the output a chunk: no line has two writers
+    const int64_t chunk = ((row_len + T - 1) / T + 15) & ~int64_t{15};
+    auto work = [&](int t) {
+      const int64_t lo = std::min<int64_t>(row_len, t * chunk);
+      const int64_t hi = std::min<int64_t>(row_len, lo + chunk);
+      const int64_t mid = std::max(lo, std::min(hi, n));
+      for (int64_t i = lo; i < mid; ++i) {
+        double x = static_cast<double>(enc[i] - mn) * scale;
+        x = x < 0.0 ? 0.0 : x;
+        x = x > top_f ? top_f : x;
+        out[i] = static_cast<uint32_t>(x);
+      }
+      for (int64_t i = mid; i < hi; ++i) out[i] = top;
     };
     run_on_threads(T, work);
   } catch (...) {

@@ -326,14 +326,16 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
         "words / h2d / kernel / d2h: the z-address planes are host word "
         "scaling, two transfers and a device program; the 0.15 s kernel "
         "inside seconds of zorder_interleave is told from them; words "
-        "names its passes as attrs (scale_s, stack_s, pad_s)",
+        "names its passes as attrs (scale_s, stack_s, pad_s) and counts "
+        "the rows the native pass wrote (native, of columns)",
     ),
     "hyperspace_tpu.ops.zorder.ZOrderEncoder.fit": (
         "attr",
         "order_s / minmax_s (sums over the columns) on the span the fit "
         "runs under, zorder_encode in a build: which pass holds the "
         "stage's seconds decides between fused numpy passes and a "
-        "native kernel — never a span a column",
+        "native kernel — never a span a column; native (of columns) "
+        "counts the encodings the native pass made",
     ),
     "hyperspace_tpu.ops.sort.lexsort_perm": (
         "span",

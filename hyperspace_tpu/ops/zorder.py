@@ -36,12 +36,19 @@ sort is stable over the input's row order, which nothing relies on).
 Upstream's ``ZOrderField`` instead keeps ``value − min`` at the bit
 length of ``max − min`` and interleaves through a bit-index map.
 
+From the native hash's row threshold up, a numeric column's encoding
+and its words are each one threaded native pass (``native.order_u64``,
+``native.zorder_words``), the words written straight into the block the
+interleave uploads; :func:`order_u64_numpy` and :func:`range_words_np`
+are their bit-exact numpy twins, which every other case takes.
+
 Under a live trace ``planes_from_encodings`` is four spans — ``words``
-(the host's scaling, stack and padding: ``scale_s``, ``stack_s``,
-``pad_s`` on it), ``h2d``, ``kernel`` (dispatch to
+(the host's words: ``scale_s``, ``stack_s``, ``pad_s``, ``columns`` and
+``native`` on it), ``h2d``, ``kernel`` (dispatch to
 ``block_until_ready``), ``d2h`` — with the bytes each way counted on
 the root, as ``ops/hash.bucket_ids_np`` has them; ``ZOrderEncoder.fit``
-adds ``order_s`` and ``minmax_s`` to the span it runs under.
+adds ``order_s``, ``minmax_s``, ``columns`` and ``native`` to the span
+it runs under.
 """
 
 from __future__ import annotations
@@ -56,11 +63,36 @@ import numpy as np
 
 import hyperspace_tpu.ops  # noqa: F401  (enables x64)
 from hyperspace_tpu.obs import trace as _obs_trace
+# the native passes' row threshold is the native hash's: the same
+# elementwise shape of pass over a column
+from hyperspace_tpu.ops.hash import _native_hash_min_rows
 
 
 def order_u64_np(col) -> np.ndarray:
-    """Order-preserving uint64 of a Column's values (host prep; O(n) but
-    trivially vectorized; nulls sort first)."""
+    """Order-preserving uint64 of a Column's values (host prep, O(n);
+    nulls sort first)."""
+    return _order_u64(col)[0]
+
+
+def _order_u64(col) -> Tuple[np.ndarray, bool]:
+    """:func:`order_u64_np` and whether the native pass made it. A
+    contiguous int64 or float64 column (dates and timestamps arrive as
+    int64) of at least the native hash's row threshold is one threaded
+    native pass (``native.order_u64``); every other column, or where the
+    library is not loaded, :func:`order_u64_numpy`. The same bytes
+    either way."""
+    if col.kind != "string" and len(col.values) >= _native_hash_min_rows():
+        from hyperspace_tpu import native
+
+        enc = native.order_u64(col.values, col.validity)
+        if enc is not None:
+            return enc, True
+    return order_u64_numpy(col), False
+
+
+def order_u64_numpy(col) -> np.ndarray:
+    """The numpy body of :func:`order_u64_np`, never dispatching to the
+    native pass: whole-array passes on one thread."""
     if col.kind == "string":
         order = sorted(range(len(col.dictionary)), key=lambda i: col.dictionary[i])
         rank = np.empty(max(len(col.dictionary), 1), dtype=np.uint64)
@@ -145,6 +177,7 @@ class ZOrderEncoder:
         order_ns = minmax_ns = 0
         specs = []
         encs = []
+        ran_native = 0
         for col in columns:
             t0 = now()
             if col.kind == "string":
@@ -153,7 +186,8 @@ class ZOrderEncoder:
                 encs.append(_dict_encode(col, spec[1]))
                 order_ns += now() - t0
                 continue
-            e = order_u64_np(col)
+            e, by_native = _order_u64(col)
+            ran_native += by_native
             encs.append(e)
             t1 = now()
             order_ns += t1 - t0
@@ -178,6 +212,8 @@ class ZOrderEncoder:
         if sp is not None:
             for key, ns in (("order_s", order_ns), ("minmax_s", minmax_ns)):
                 sp.set(key, round(sp.attrs.get(key, 0.0) + ns / 1e9, 6))
+            for key, count in (("columns", len(columns)), ("native", ran_native)):
+                sp.set(key, sp.attrs.get(key, 0) + count)
         return ZOrderEncoder(bits, specs), encs
 
     # -- encoding -----------------------------------------------------------
@@ -188,6 +224,17 @@ class ZOrderEncoder:
             return _dict_encode(col, spec[1])
         return order_u64_np(col)
 
+    def _range(self, spec) -> Tuple[np.uint64, float]:
+        """(min, scale) of a "range" or "dict" spec's min/max scaling."""
+        if spec[0] == "dict":
+            # global ranks in [0, len]: plain range scaling over the rank
+            # space (rank IS the quantile of the unique-value distribution)
+            mn, mx = np.uint64(0), np.uint64(len(spec[1]))
+        else:
+            _tag, mn, mx = spec
+        rng = float(int(mx) - int(mn))
+        return mn, ((2.0**self.bits) - 1) / rng if rng > 0 else 0.0
+
     def _words(self, enc: np.ndarray, spec) -> np.ndarray:
         bits = self.bits
         top = (1 << bits) - 1
@@ -197,49 +244,59 @@ class ZOrderEncoder:
             return ((pos / max(len(bounds), 1)) * np.float64(top)).astype(
                 np.uint32
             )
-        if spec[0] == "dict":
-            # global ranks in [0, len]: plain range scaling over the rank
-            # space (rank IS the quantile of the unique-value distribution)
-            mn, mx = np.uint64(0), np.uint64(len(spec[1]))
-        else:
-            _tag, mn, mx = spec
         # min/max scaling on host (per-wave word computation is O(n)
         # elementwise; device dispatch pays transfers)
-        off = (enc - mn).astype(np.float64)
-        rng = float(int(mx) - int(mn))
-        scale = ((2.0**bits) - 1) / rng if rng > 0 else 0.0
-        return np.clip(off * scale, 0, top).astype(np.uint32)
+        return range_words_np(enc, *self._range(spec), top)
 
     def planes_from_encodings(self, encs: List[np.ndarray]) -> np.ndarray:
         """[nplanes, n] uint32 planes (most-significant first) from
-        per-column encodings produced by :meth:`encode`."""
+        per-column encodings produced by :meth:`encode`.
+
+        The interleave's input is one ``uint32[k, pad_len(n)]`` block,
+        each column's words in its row and the fill ``top`` past ``n``.
+        From the native hash's row threshold up a "range" or "dict"
+        column's row is one native pass (``native.zorder_words``) that
+        writes words and fill straight into the block; a quantile
+        column, a smaller input, or a library not loaded takes
+        :meth:`_words` and copies its row in. ``native`` on the
+        ``words`` span counts the rows the pass made, of ``columns``."""
+        from hyperspace_tpu import native
         from hyperspace_tpu.ops import pad_len
 
         n = len(encs[0]) if encs else 0
+        top = (1 << self.bits) - 1
+        big = n >= _native_hash_min_rows()
         with _obs_trace.span("words") as sp:
-            t0 = time.perf_counter_ns()
-            scaled = [self._words(e, s) for e, s in zip(encs, self.specs)]
-            t1 = time.perf_counter_ns()
-            words = (
-                np.stack(scaled)
-                if encs
-                else np.zeros((0, 0), dtype=np.uint32)
-            )
-            del scaled  # as before: the columns' words die with the stack
-            t2 = time.perf_counter_ns()
-            n_pad = pad_len(max(n, 1))
-            if n_pad != n:
-                fill = np.full(
-                    (words.shape[0], n_pad - n),
-                    np.uint32((1 << self.bits) - 1),
-                )
-                words = np.concatenate([words, fill], axis=1)
-            # what the span's seconds went to: the columns' scaling to
-            # words, their stack into one array, the pad to the
-            # program's length
-            sp.set("scale_s", round((t1 - t0) / 1e9, 6))
-            sp.set("stack_s", round((t2 - t1) / 1e9, 6))
-            sp.set("pad_s", round((time.perf_counter_ns() - t2) / 1e9, 6))
+            now = time.perf_counter_ns
+            scale_ns = stack_ns = pad_ns = 0
+            ran_native = 0
+            words = np.empty((len(encs), pad_len(max(n, 1))), dtype=np.uint32)
+            for j, (enc, spec) in enumerate(zip(encs, self.specs)):
+                t0 = now()
+                if (
+                    big
+                    and spec[0] != "quantile"
+                    and native.zorder_words(enc, *self._range(spec), top, words[j])
+                ):
+                    ran_native += 1
+                    scale_ns += now() - t0
+                    continue
+                row = self._words(enc, spec)
+                t1 = now()
+                words[j, :n] = row
+                t2 = now()
+                words[j, n:] = top
+                scale_ns += t1 - t0
+                stack_ns += t2 - t1
+                pad_ns += now() - t2
+            # what the span's seconds went to: the columns' words (the
+            # native pass writes its row and fill whole), the twin's rows
+            # copied into the block, and their fill
+            sp.set("columns", len(encs))
+            sp.set("native", ran_native)
+            sp.set("scale_s", round(scale_ns / 1e9, 6))
+            sp.set("stack_s", round(stack_ns / 1e9, 6))
+            sp.set("pad_s", round(pad_ns / 1e9, 6))
         with _obs_trace.span("h2d", bytes=int(words.nbytes)):
             on_device = jax.block_until_ready(jnp.asarray(words))
         with _obs_trace.span("kernel"):
@@ -254,6 +311,14 @@ class ZOrderEncoder:
         return self.planes_from_encodings(
             [self.encode(c, j) for j, c in enumerate(columns)]
         )
+
+
+def range_words_np(enc: np.ndarray, mn, scale: float, top: int) -> np.ndarray:
+    """uint32 z-words of one column's encodings under min/max scaling:
+    ``trunc(clip(float64(enc - mn) * scale, 0, top))`` — the numpy twin
+    of ``native.zorder_words`` (without its fill past the rows)."""
+    off = (enc - mn).astype(np.float64)
+    return np.clip(off * scale, 0, top).astype(np.uint32)
 
 
 def _dict_encode(col, sorted_global: List[str]) -> np.ndarray:
