@@ -66,6 +66,13 @@ _log = logging.getLogger("hyperspace_tpu.build")
 # ---------------------------------------------------------------------------
 
 
+# Source bytes on disk that earn a scan one more worker: about 64k rows
+# of a wide fact table, the rows one thread of ``native._n_threads``
+# takes. Below it more threads cost what overlapping the files saves,
+# and one worker reads the files one after another.
+_SCAN_BYTES_PER_WORKER = 2 << 20
+
+
 def _scan_with_lineage(
     files: Sequence[str],
     fmt: str,
@@ -75,51 +82,162 @@ def _scan_with_lineage(
     """Read the projection from each source file; attach `_data_file_id`
     when lineage is on (CoveringIndex.createIndexData:177-186).
 
-    The four phases — the parquet read, the decode to columns, the
-    lineage fill, the one concat of the lot — are timed here, where they
-    run, and added to the span live in the caller's context
-    (``read_s``, ``decode_s``, ``lineage_s``, ``concat_s``: sums over
-    the files, which are read one after another, so they add up to a
-    ``scan`` stage but for the loop around them; ``max_read_s`` is the
-    slowest file's read). That span is the ``scan`` stage of an
-    in-memory build; the streamed build opens none around its waves, so
-    there they sum on the action's root."""
+    The files are read as tasks on a pool of this call's own (up to
+    ``native.core_budget()`` threads, one a file and one a
+    ``_SCAN_BYTES_PER_WORKER`` of source; never ``io/scan.scan_pool``,
+    whose tasks may not wait on each other). Then each column whose
+    type ``columnar.direct_layout`` knows is allocated once, whole, and
+    each file's task copies its chunks straight into its rows' slice:
+    one copy a value, where per-file ``Column.from_arrow`` and
+    ``ColumnarBatch.concat`` made two. Every other column, and a call
+    with fewer than two files that hold rows, takes that per-file path
+    as before. The batch is the one that path gives, column for column.
+
+    Timed here and added to the span live in the caller's context:
+    ``read_s``, ``decode_s`` (the copies, or ``from_arrow``),
+    ``lineage_s`` — task seconds summed over the files, thread seconds
+    where the files overlap — and ``concat_s``, the caller's wall
+    outside the two rounds of tasks (the pool, the allocation, the
+    per-file columns' concat, the batch); ``max_read_s`` is the slowest
+    file's read, ``workers`` the pool's threads (1: the files one after
+    another, and the phases add up to the call), ``columns`` and
+    ``direct`` the columns read and those copied once. That span is the
+    ``scan`` stage of an in-memory build; the streamed build opens none
+    around its waves, so there they sum on the action's root."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from hyperspace_tpu import native
+    from hyperspace_tpu.io.columnar import copy_chunks_into, direct_layout
+
+    if not files:
+        raise HyperspaceException("No source files to index")
     now = _time.perf_counter_ns
-    read_ns = decode_ns = lineage_ns = max_read_ns = 0
-    batches = []
-    for f in files:
+    t_call = now()
+    workers = max(
+        1,
+        min(
+            native.core_budget(),
+            len(files),
+            _files_bytes(files) // _SCAN_BYTES_PER_WORKER,
+        ),
+    )
+
+    def read(f):
         t0 = now()
         t = pio.read_table([f], columns, fmt)
+        return t, now() - t0
+
+    def decode(i):
+        """File ``i``'s rows into its slice of each direct column and of
+        the lineage column; its other columns as ``Column``s."""
+        t0 = now()
+        table, at = tables[i], starts[i]
+        for name, (values, validity, storage) in direct.items():
+            copy_chunks_into(table.column(name), at, values, validity, storage)
+        cols = {
+            # the lineage column's place is kept, for the ids put there
+            n: None if n == lineage_name else Column.from_arrow(table.column(n))
+            for n in (table.column_names if per_file is None else per_file)
+        }
         t1 = now()
-        b = ColumnarBatch.from_arrow(t)
-        t2 = now()
-        if file_ids is not None:
-            fid = np.full(b.num_rows, file_ids[f], dtype=np.int64)
-            b = b.with_column(
-                DATA_FILE_NAME_ID, Column("numeric", pa.int64(), values=fid)
+        if lineage is not None:
+            lineage[at : at + table.num_rows] = file_ids[files[i]]
+        elif file_ids is not None:
+            cols[DATA_FILE_NAME_ID] = Column(
+                "numeric",
+                pa.int64(),
+                values=np.full(table.num_rows, file_ids[files[i]], dtype=np.int64),
             )
-        batches.append(b)
-        read_ns += t1 - t0
-        max_read_ns = max(max_read_ns, t1 - t0)
-        decode_ns += t2 - t1
-        lineage_ns += now() - t2
-    if not batches:
-        raise HyperspaceException("No source files to index")
-    t0 = now()
-    out = ColumnarBatch.concat(batches)
+        return cols, t1 - t0, now() - t1
+
+    lineage_name = DATA_FILE_NAME_ID if file_ids is not None else None
+    with contextlib.ExitStack() as stack:
+        # one worker runs the tasks on the caller's thread: no hand-offs
+        # between the phases, which then add up to the call
+        run = (
+            stack.enter_context(
+                ThreadPoolExecutor(workers, thread_name_prefix="hs-scan")
+            ).map
+            if workers > 1
+            else map
+        )
+        t0 = now()
+        reads = list(run(read, files))
+        tasks_ns = now() - t0
+        tables = [t for t, _ns in reads]
+        full = [i for i, t in enumerate(tables) if t.num_rows]
+        names = tables[full[0]].column_names if full else []
+        direct: Dict[str, tuple] = {}
+        lineage = per_file = starts = None
+        if len(full) > 1 and all(tables[i].column_names == names for i in full):
+            offsets = np.cumsum([0] + [tables[i].num_rows for i in full])
+            starts = dict(zip(full, offsets.tolist()))
+            total = int(offsets[-1])
+            for n in names:
+                t = tables[full[0]].schema.field(n).type
+                layout = direct_layout(t)
+                if (
+                    n == lineage_name
+                    or layout is None
+                    or any(tables[i].schema.field(n).type != t for i in full)
+                ):
+                    continue
+                nulls = any(tables[i].column(n).null_count for i in full)
+                direct[n] = (
+                    np.empty(total, dtype=layout[1]),
+                    np.empty(total, dtype=bool) if nulls else None,
+                    layout[0],
+                )
+            per_file = [n for n in names if n not in direct and n != lineage_name]
+            if file_ids is not None:
+                lineage = np.empty(total, dtype=np.int64)
+        else:
+            full = list(range(len(tables)))
+            starts = dict.fromkeys(full, 0)
+        t0 = now()
+        decoded = list(run(decode, full))
+        tasks_ns += now() - t0
+    parts = [cols for cols, _d, _l in decoded]
+    if per_file is None:
+        out = ColumnarBatch.concat([ColumnarBatch(cols) for cols in parts])
+    else:
+        out_cols = {}
+        for n in names:
+            if n == lineage_name:
+                out_cols[n] = None  # keeps the column's place in the batch
+            elif n in direct:
+                values, validity, _storage = direct[n]
+                out_cols[n] = Column(
+                    "numeric",
+                    tables[full[0]].schema.field(n).type,
+                    values=values,
+                    validity=validity,
+                )
+            else:
+                out_cols[n] = Column.concat([cols[n] for cols in parts])
+        if lineage is not None:
+            out_cols[DATA_FILE_NAME_ID] = Column(
+                "numeric", pa.int64(), values=lineage
+            )
+        out = ColumnarBatch(out_cols)
     sp = _obs_trace.current()
     if sp is not None:
+        read_ns = [ns for _t, ns in reads]
         _add_seconds(
             sp,
-            read_s=read_ns,
-            decode_s=decode_ns,
-            lineage_s=lineage_ns,
-            concat_s=now() - t0,
+            read_s=sum(read_ns),
+            decode_s=sum(d for _c, d, _l in decoded),
+            lineage_s=sum(ln for _c, _d, ln in decoded),
+            concat_s=now() - t_call - tasks_ns,
         )
         sp.set(
             "max_read_s",
-            max(sp.attrs.get("max_read_s", 0.0), round(max_read_ns / 1e9, 6)),
+            max(sp.attrs.get("max_read_s", 0.0), round(max(read_ns) / 1e9, 6)),
         )
+        sp.set("workers", max(sp.attrs.get("workers", 0), workers))
+        read_cols = len(out.column_names) - (lineage_name is not None)
+        sp.set("columns", sp.attrs.get("columns", 0) + read_cols)
+        sp.set("direct", sp.attrs.get("direct", 0) + len(direct))
     return out
 
 

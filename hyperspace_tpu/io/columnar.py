@@ -398,6 +398,54 @@ def remap_codes(target_dictionary: List[str], col: "Column") -> np.ndarray:
     return np.where(col.codes < 0, -3, remap[np.maximum(col.codes, 0)])
 
 
+def direct_layout(t: pa.DataType):
+    """(dtype of the arrow buffer, dtype of ``Column.values``) for a
+    fixed-width type whose chunks can be copied straight into a
+    preallocated column, with the values that ``Column.from_arrow``
+    gives: integers and floats as they are; date32 days, date64,
+    timestamp and duration ticks as int64. None for every other type
+    (string, dictionary, bool, time, decimal, nested), which goes
+    through ``Column.from_arrow``."""
+    if pa.types.is_integer(t) or pa.types.is_floating(t):
+        d = np.dtype(t.to_pandas_dtype())
+        return d, d
+    if pa.types.is_date32(t):
+        return np.dtype(np.int32), np.dtype(np.int64)
+    if pa.types.is_date64(t) or pa.types.is_timestamp(t) or pa.types.is_duration(t):
+        return np.dtype(np.int64), np.dtype(np.int64)
+    return None
+
+
+def copy_chunks_into(
+    arr: pa.ChunkedArray,
+    at: int,
+    values: np.ndarray,
+    validity: Optional[np.ndarray],
+    storage: np.dtype,
+) -> None:
+    """Write ``arr``'s rows into ``values[at:]`` (and ``validity[at:]``
+    where the column has one), each chunk read as a zero-copy view of
+    its buffer in ``storage``, the one copy widening it to
+    ``values.dtype``. Null slots hold 0, as ``fill_null`` leaves them in
+    ``Column.from_arrow``."""
+    for chunk in arr.chunks:
+        n = len(chunk)
+        if not n:
+            continue
+        dst = values[at : at + n]
+        dst[:] = np.frombuffer(
+            chunk.buffers()[1], dtype=storage, count=chunk.offset + n
+        )[chunk.offset :]
+        if validity is not None:
+            if chunk.null_count:
+                valid = chunk.is_valid().to_numpy(zero_copy_only=False)
+                validity[at : at + n] = valid
+                np.copyto(dst, 0, where=~valid)
+            else:
+                validity[at : at + n] = True
+        at += n
+
+
 def _numpy_dtype_for(t: pa.DataType):
     try:
         return t.to_pandas_dtype()

@@ -266,9 +266,10 @@ ALLOC_SITES: Dict[str, Tuple[str, str, str]] = {
     "hyperspace_tpu.indexes.covering_build._scan_with_lineage": (
         "build",
         "chunk-bounded",
-        "per-file read loop whose concat accumulator is exactly the "
-        "file subset the caller passed — wave-planned stripes from the "
-        "streaming writers, never the whole relation on the build path",
+        "per-file reads whose tables, and the columns preallocated to "
+        "hold their rows once, are exactly the file subset the caller "
+        "passed — wave-planned stripes from the streaming writers, "
+        "never the whole relation on the build path",
     ),
     "hyperspace_tpu.indexes.covering_build._write_bucketed_streaming": (
         "build",

@@ -239,11 +239,14 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
     ),
     "hyperspace_tpu.indexes.covering_build._scan_with_lineage": (
         "attr",
-        "read_s / decode_s / lineage_s / concat_s (sums over the files, "
-        "read one after another) and max_read_s on the span the scan "
-        "runs under: whether a 1.3 s scan is I/O, decode, the lineage "
-        "fill or the final copy decides its next cut, and the slowest "
-        "file's read is the stalled build's tell — never a span a file",
+        "read_s / decode_s / lineage_s (the files' task seconds, thread "
+        "seconds where the pool overlaps them), concat_s (the caller's "
+        "wall outside the tasks), max_read_s, workers, and columns / "
+        "direct (columns read, and those copied once into a "
+        "preallocated column) on the span the scan runs under: whether "
+        "a scan is I/O, decode, the lineage fill or the assembly decides "
+        "its next cut, and the slowest file's read is the stalled "
+        "build's tell — never a span a file",
     ),
     "hyperspace_tpu.indexes.covering_build._hash_shuffle": (
         "span",

@@ -802,10 +802,18 @@ class TestActionAccount:
         assert at["files"] == 4 and at["rows"] == root.attrs["rows"] == 20_000
         phases = [at[k] for k in ("read_s", "decode_s", "lineage_s", "concat_s")]
         assert all(p >= 0.0 for p in phases)
-        # one file after another: the phases add up to the span but for
-        # the loop around them
-        assert sum(phases) <= scan.duration_s + 1e-6
-        assert sum(phases) >= 0.9 * scan.duration_s
+        assert at["workers"] >= 1 and at["columns"] == 2
+        assert 0 <= at["direct"] <= at["columns"]
+        if at["workers"] == 1:
+            # one file after another: the phases add up to the span but
+            # for the loop around them
+            assert sum(phases) <= scan.duration_s + 1e-6
+            assert sum(phases) >= 0.9 * scan.duration_s
+        else:
+            # the files' tasks overlap: thread seconds, at most the
+            # pool's; concat_s is the caller's own wall
+            assert sum(phases[:3]) <= at["workers"] * scan.duration_s + 1e-6
+            assert at["concat_s"] <= scan.duration_s + 1e-6
         assert 0.0 < at["max_read_s"] <= at["read_s"] + 1e-9
         assert not [sp for sp in root.spans if sp.parent_id == scan.span_id]
 
