@@ -32,13 +32,15 @@ the previous index data minus deleted-lineage rows.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
+import itertools
 import logging
 import os
 import threading as _threading
 import time as _time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 import pyarrow as pa
@@ -73,46 +75,20 @@ _log = logging.getLogger("hyperspace_tpu.build")
 _SCAN_BYTES_PER_WORKER = 2 << 20
 
 
-def _scan_with_lineage(
-    files: Sequence[str],
-    fmt: str,
-    columns: List[str],
-    file_ids: Optional[Dict[str, int]],
-) -> ColumnarBatch:
-    """Read the projection from each source file; attach `_data_file_id`
-    when lineage is on (CoveringIndex.createIndexData:177-186).
-
-    The files are read as tasks on a pool of this call's own (up to
-    ``native.core_budget()`` threads, one a file and one a
-    ``_SCAN_BYTES_PER_WORKER`` of source; never ``io/scan.scan_pool``,
-    whose tasks may not wait on each other). Then each column whose
-    type ``columnar.direct_layout`` knows is allocated once, whole, and
-    each file's task copies its chunks straight into its rows' slice:
-    one copy a value, where per-file ``Column.from_arrow`` and
-    ``ColumnarBatch.concat`` made two. Every other column, and a call
-    with fewer than two files that hold rows, takes that per-file path
-    as before. The batch is the one that path gives, column for column.
-
-    Timed here and added to the span live in the caller's context:
-    ``read_s``, ``decode_s`` (the copies, or ``from_arrow``),
-    ``lineage_s`` — task seconds summed over the files, thread seconds
-    where the files overlap — and ``concat_s``, the caller's wall
-    outside the two rounds of tasks (the pool, the allocation, the
-    per-file columns' concat, the batch); ``max_read_s`` is the slowest
-    file's read, ``workers`` the pool's threads (1: the files one after
-    another, and the phases add up to the call), ``columns`` and
-    ``direct`` the columns read and those copied once. That span is the
-    ``scan`` stage of an in-memory build; the streamed build opens none
-    around its waves, so there they sum on the action's root."""
-    from concurrent.futures import ThreadPoolExecutor
-
+def _read_source_files(
+    files: Sequence[str], fmt: str, columns: List[str]
+) -> Tuple[int, Iterator[Tuple[pa.Table, int]]]:
+    """The one read of a build's source files -> (workers, the reads):
+    ``(table, read_ns)`` a file in file order, ``read_ns`` taken on the
+    thread that read it. ``workers``: up to ``native.core_budget()``, one
+    a file and one a ``_SCAN_BYTES_PER_WORKER`` of source. More than one
+    reads on a pool of the reads' own (never ``io/scan.scan_pool``, whose
+    tasks may not wait on each other) at most ``workers`` files ahead of
+    the consumer, so a loop over many large files holds no more tables
+    than that; one reads each file on the consumer's thread when asked."""
     from hyperspace_tpu import native
-    from hyperspace_tpu.io.columnar import copy_chunks_into, direct_layout
 
-    if not files:
-        raise HyperspaceException("No source files to index")
     now = _time.perf_counter_ns
-    t_call = now()
     workers = max(
         1,
         min(
@@ -126,6 +102,66 @@ def _scan_with_lineage(
         t0 = now()
         t = pio.read_table([f], columns, fmt)
         return t, now() - t0
+
+    def pooled():
+        from concurrent.futures import ThreadPoolExecutor
+
+        todo = iter(files)
+        ahead = collections.deque()
+        with ThreadPoolExecutor(workers, thread_name_prefix="hs-scan") as pool:
+            try:
+                for f in itertools.islice(todo, workers):
+                    ahead.append(pool.submit(read, f))
+                while ahead:
+                    done = ahead.popleft().result()
+                    for f in itertools.islice(todo, 1):  # into the freed place
+                        ahead.append(pool.submit(read, f))
+                    yield done
+            finally:  # a consumer that stops early waits for no more reads
+                for future in ahead:
+                    future.cancel()
+
+    return workers, (pooled() if workers > 1 else (read(f) for f in files))
+
+
+def _scan_with_lineage(
+    files: Sequence[str],
+    fmt: str,
+    columns: List[str],
+    file_ids: Optional[Dict[str, int]],
+) -> ColumnarBatch:
+    """Read the projection from each source file; attach `_data_file_id`
+    when lineage is on (CoveringIndex.createIndexData:177-186).
+
+    The files are read by ``_read_source_files``; then as many threads
+    as it read them on decode them. Each column whose
+    type ``columnar.direct_layout`` knows is allocated once, whole, and
+    each file's task copies its chunks straight into its rows' slice:
+    one copy a value, where per-file ``Column.from_arrow`` and
+    ``ColumnarBatch.concat`` made two. Every other column, and a call
+    with fewer than two files that hold rows, takes that per-file path
+    as before. The batch is the one that path gives, column for column.
+
+    Timed here and added to the span live in the caller's context:
+    ``read_s``, ``decode_s`` (the copies, or ``from_arrow``),
+    ``lineage_s`` — task seconds summed over the files, thread seconds
+    where the files overlap — and ``concat_s``, the caller's wall
+    outside the two rounds of tasks (the allocation, the per-file
+    columns' concat, the batch); ``max_read_s`` is the slowest
+    file's read, ``workers`` the pool's threads (1: the files one after
+    another, and the phases add up to the call), ``columns`` and
+    ``direct`` the columns read and those copied once. That span is the
+    ``scan`` stage of an in-memory build; the streamed build opens none
+    around its waves, so there they sum on the action's root."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from hyperspace_tpu.io.columnar import copy_chunks_into, direct_layout
+
+    if not files:
+        raise HyperspaceException("No source files to index")
+    now = _time.perf_counter_ns
+    t_call = now()
+    workers, reads = _read_source_files(files, fmt, columns)
 
     def decode(i):
         """File ``i``'s rows into its slice of each direct column and of
@@ -151,52 +187,48 @@ def _scan_with_lineage(
         return cols, t1 - t0, now() - t1
 
     lineage_name = DATA_FILE_NAME_ID if file_ids is not None else None
-    with contextlib.ExitStack() as stack:
-        # one worker runs the tasks on the caller's thread: no hand-offs
-        # between the phases, which then add up to the call
-        run = (
-            stack.enter_context(
-                ThreadPoolExecutor(workers, thread_name_prefix="hs-scan")
-            ).map
-            if workers > 1
-            else map
-        )
-        t0 = now()
-        reads = list(run(read, files))
-        tasks_ns = now() - t0
-        tables = [t for t, _ns in reads]
-        full = [i for i, t in enumerate(tables) if t.num_rows]
-        names = tables[full[0]].column_names if full else []
-        direct: Dict[str, tuple] = {}
-        lineage = per_file = starts = None
-        if len(full) > 1 and all(tables[i].column_names == names for i in full):
-            offsets = np.cumsum([0] + [tables[i].num_rows for i in full])
-            starts = dict(zip(full, offsets.tolist()))
-            total = int(offsets[-1])
-            for n in names:
-                t = tables[full[0]].schema.field(n).type
-                layout = direct_layout(t)
-                if (
-                    n == lineage_name
-                    or layout is None
-                    or any(tables[i].schema.field(n).type != t for i in full)
-                ):
-                    continue
-                nulls = any(tables[i].column(n).null_count for i in full)
-                direct[n] = (
-                    np.empty(total, dtype=layout[1]),
-                    np.empty(total, dtype=bool) if nulls else None,
-                    layout[0],
-                )
-            per_file = [n for n in names if n not in direct and n != lineage_name]
-            if file_ids is not None:
-                lineage = np.empty(total, dtype=np.int64)
-        else:
-            full = list(range(len(tables)))
-            starts = dict.fromkeys(full, 0)
-        t0 = now()
-        decoded = list(run(decode, full))
-        tasks_ns += now() - t0
+    t0 = now()
+    reads = list(reads)
+    tasks_ns = now() - t0
+    tables = [t for t, _ns in reads]
+    full = [i for i, t in enumerate(tables) if t.num_rows]
+    names = tables[full[0]].column_names if full else []
+    direct: Dict[str, tuple] = {}
+    lineage = per_file = starts = None
+    if len(full) > 1 and all(tables[i].column_names == names for i in full):
+        offsets = np.cumsum([0] + [tables[i].num_rows for i in full])
+        starts = dict(zip(full, offsets.tolist()))
+        total = int(offsets[-1])
+        for n in names:
+            t = tables[full[0]].schema.field(n).type
+            layout = direct_layout(t)
+            if (
+                n == lineage_name
+                or layout is None
+                or any(tables[i].schema.field(n).type != t for i in full)
+            ):
+                continue
+            nulls = any(tables[i].column(n).null_count for i in full)
+            direct[n] = (
+                np.empty(total, dtype=layout[1]),
+                np.empty(total, dtype=bool) if nulls else None,
+                layout[0],
+            )
+        per_file = [n for n in names if n not in direct and n != lineage_name]
+        if file_ids is not None:
+            lineage = np.empty(total, dtype=np.int64)
+    else:
+        full = list(range(len(tables)))
+        starts = dict.fromkeys(full, 0)
+    # one worker decodes on the caller's thread: no hand-offs between
+    # the phases, which then add up to the call
+    t0 = now()
+    if workers > 1:
+        with ThreadPoolExecutor(workers, thread_name_prefix="hs-scan") as pool:
+            decoded = list(pool.map(decode, full))
+    else:
+        decoded = list(map(decode, full))
+    tasks_ns += now() - t0
     parts = [cols for cols, _d, _l in decoded]
     if per_file is None:
         out = ColumnarBatch.concat([ColumnarBatch(cols) for cols in parts])

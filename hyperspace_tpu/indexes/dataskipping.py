@@ -29,7 +29,9 @@ from hyperspace_tpu.obs import trace
 from hyperspace_tpu.plan import expressions as E
 
 #: the ``sketch`` span's attrs, each a sum over the source files: the
-#: read (parquet + ColumnarBatch), each sketch kind's ``aggregate`` (its
+#: read (``covering_build._read_source_files``' task seconds, thread
+#: seconds where its pool overlaps the files, and the ColumnarBatch on
+#: the caller's thread), each sketch kind's ``aggregate`` (its
 #: ``seconds_attr``; a kind the index does not hold reads 0, one not
 #: named here appears where it runs), inside the bloom's the word split
 #: and pad, the device leg as the host sees it and the host's bit
@@ -112,10 +114,11 @@ class DataSkippingIndex(Index):
         """One sketch row per source file (createIndexData:291-317). File
         ids are keyed by the provider's (path,size,mtime) view so they
         match the ids recorded in the log entry's source content."""
-        from hyperspace_tpu.indexes.covering_build import source_file_infos
+        from hyperspace_tpu.indexes.covering_build import (
+            _read_source_files,
+            source_file_infos,
+        )
 
-        fmt = plan_relation.fmt
-        cols = self.indexed_columns
         fields: List[Tuple[str, pa.DataType]] = [(DATA_FILE_NAME_ID, pa.int64())]
         rows: List[Dict] = []
         out_fields = None
@@ -124,13 +127,17 @@ class DataSkippingIndex(Index):
         acc.update(h2d_bytes=0, d2h_bytes=0)
         n_rows = 0
         with trace.span("sketch") as sp:
-            for f, size, mtime in sorted(
-                source_file_infos(ctx.session, plan_relation)
-            ):
+            infos = sorted(source_file_infos(ctx.session, plan_relation))
+            workers, reads = _read_source_files(
+                [f for f, _size, _mtime in infos],
+                plan_relation.fmt,
+                self.indexed_columns,
+            )
+            for (table, read_ns), (f, size, mtime) in zip(reads, infos):
                 fid = ctx.file_id_tracker.add_file(f, size, mtime)
                 t0 = time.perf_counter()
-                batch = ColumnarBatch.from_arrow(pio.read_table([f], cols, fmt))
-                acc["read_s"] += time.perf_counter() - t0
+                batch = ColumnarBatch.from_arrow(table)
+                acc["read_s"] += read_ns / 1e9 + time.perf_counter() - t0
                 row = {DATA_FILE_NAME_ID: fid}
                 if out_fields is None:
                     out_fields = list(fields)
@@ -159,6 +166,7 @@ class DataSkippingIndex(Index):
             trace.accumulate("d2h_bytes", acc.pop("d2h_bytes"))
             sp.set("files", len(rows))
             sp.set("rows", n_rows)
+            sp.set("workers", workers)
             for key, seconds in acc.items():
                 sp.set(key, round(seconds, 6))
         return table

@@ -263,13 +263,22 @@ ALLOC_SITES: Dict[str, Tuple[str, str, str]] = {
         "plane's contract, config-capped by INDEX_AGG_SAMPLE_ROWS",
     ),
     # -- build plane: wave loops and per-file passes -------------------------
+    "hyperspace_tpu.indexes.covering_build._read_source_files": (
+        "build",
+        "chunk-bounded",
+        "the one read of a build's source files, a table a file, at "
+        "most `workers` files read ahead of the consumer; what stays "
+        "resident is the callers': _scan_with_lineage keeps its file "
+        "subset, DataSkippingIndex.build_sketch_rows one O(1) sketch "
+        "row a file (peak: `workers` + 1 files)",
+    ),
     "hyperspace_tpu.indexes.covering_build._scan_with_lineage": (
         "build",
         "chunk-bounded",
-        "per-file reads whose tables, and the columns preallocated to "
-        "hold their rows once, are exactly the file subset the caller "
-        "passed — wave-planned stripes from the streaming writers, "
-        "never the whole relation on the build path",
+        "the _read_source_files tables, and the columns preallocated "
+        "to hold their rows once, are exactly the file subset the "
+        "caller passed — wave-planned stripes from the streaming "
+        "writers, never the whole relation on the build path",
     ),
     "hyperspace_tpu.indexes.covering_build._write_bucketed_streaming": (
         "build",
@@ -282,12 +291,6 @@ ALLOC_SITES: Dict[str, Tuple[str, str, str]] = {
         "wave-budget",
         "wave-planned z-order rewrite: one build_memory_budget wave "
         "resident at a time, sorted runs spill to disk between waves",
-    ),
-    "hyperspace_tpu.indexes.dataskipping.DataSkippingIndex.build_sketch_rows": (
-        "build",
-        "chunk-bounded",
-        "reads one source file per iteration and keeps only its O(1) "
-        "sketch row; peak residency is the largest single file",
     ),
     "hyperspace_tpu.indexes.zonemaps._spans_by_reread": (
         "build",

@@ -246,7 +246,11 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
         "preallocated column) on the span the scan runs under: whether "
         "a scan is I/O, decode, the lineage fill or the assembly decides "
         "its next cut, and the slowest file's read is the stalled "
-        "build's tell — never a span a file",
+        "build's tell — never a span a file. The reads are "
+        "_read_source_files' (workers, and at most that many files read "
+        "ahead of the consumer), which times each on its thread and "
+        "records nothing itself: its callers, this scan and "
+        "DataSkippingIndex.build_sketch_rows, sum its read_ns",
     ),
     "hyperspace_tpu.indexes.covering_build._hash_shuffle": (
         "span",
@@ -365,12 +369,14 @@ OBS_SITES: Dict[str, Tuple[str, str]] = {
     "hyperspace_tpu.indexes.dataskipping.DataSkippingIndex.build_sketch_rows": (
         "span",
         "sketch: the data-skipping build's one loop over the source "
-        "files (read, then each sketch, file after file) is one span a "
-        "build; files / rows and what its seconds went to, perf_counter "
-        "deltas summed over the files, as attrs (read_s, minmax_s, "
-        "bloom_s and inside it words_s / device_s / set_bits_s, "
-        "table_s), the bloom's h2d_bytes / d2h_bytes from shapes on the "
-        "root — never a span a file or a sketch",
+        "files (each file's table from _read_source_files, then each "
+        "sketch, file after file) is one span a build; files / rows / "
+        "workers and what its seconds went to, perf_counter deltas "
+        "summed over the files, as attrs (read_s the read's task "
+        "seconds as on scan, minmax_s, bloom_s and inside it words_s / "
+        "device_s / set_bits_s, table_s), the bloom's h2d_bytes / "
+        "d2h_bytes from shapes on the root — never a span a file or a "
+        "sketch",
     ),
     "hyperspace_tpu.indexes.dataskipping.DataSkippingIndex.write": (
         "span",

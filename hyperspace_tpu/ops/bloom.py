@@ -20,7 +20,7 @@ import numpy as np
 
 import hyperspace_tpu.ops  # noqa: F401  (enables x64)
 from hyperspace_tpu.ops import pad_len
-from hyperspace_tpu.ops.hash import hash_words, split_words_np
+from hyperspace_tpu.ops.hash import _padded_words, hash_words
 
 
 def optimal_params(expected_items: int, fpp: float) -> Tuple[int, int]:
@@ -50,18 +50,15 @@ def bit_indices_np(
     int32 bit indices. The row dimension is padded to ``pad_len``
     (ops/__init__ shape policy) — a data-skipping build hashes one batch
     per source file, and an unpadded ``n`` would compile once per
-    distinct file row count. ``acc``, where given, sums ``words_s`` (the
-    split and pad), ``device_s`` (from the upload to the fetch's return:
+    distinct file row count; the padded block is the hash's own
+    (``ops/hash._padded_words``). ``acc``, where given, sums ``words_s``
+    (the split and pad), ``device_s`` (from the upload to the fetch's return:
     dispatch, both transfers and the kernel as the host sees them) and
     the bytes each way, ``h2d_bytes`` and ``d2h_bytes``, from shapes."""
     t0 = time.perf_counter()
     n = len(key_reps)
-    words = split_words_np(np.asarray(key_reps, dtype=np.int64)[None, :])
-    n_pad = pad_len(n)
-    if n_pad != n:
-        words = np.concatenate(
-            [words, np.zeros((2, n_pad - n), dtype=np.uint32)], axis=1
-        )
+    reps = np.asarray(key_reps, dtype=np.int64)
+    words, _native = _padded_words(reps[None, :], pad_len(n))
     t1 = time.perf_counter()
     idx = np.asarray(_bit_indices(jnp.asarray(words), m, k))
     if acc is not None:

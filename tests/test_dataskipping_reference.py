@@ -11,7 +11,8 @@ sketch table, the files kept by point lookups and ranges (the bloom's
 false positives as the filter has them, and no file with a row of the
 predicate pruned), their answers, and the build's account — one
 ``sketch`` span a build whatever the file count, with what its seconds
-went to as attrs, and a sketch file that the tracing does not touch.
+went to as attrs, and a sketch file that neither the tracing nor
+reading the files on a pool touches.
 """
 
 import json
@@ -164,7 +165,23 @@ def _root():
     return trace.finished("action.CreateAction")[-1]
 
 
-def test_one_sketch_span_a_build_whatever_the_file_count(lake, tmp_path):
+#: the read's threads in each mode: ``native._cores`` and a byte per
+#: worker set as the scan's own tests set them (tests/test_scan_assembly.py)
+WORKERS = {"inline": 1, "pool": 4}
+
+
+def _read(monkeypatch, mode):
+    """Send the build's source reads inline (one thread) or to a pool."""
+    from hyperspace_tpu import native
+    from hyperspace_tpu.indexes import covering_build
+
+    monkeypatch.setattr(native, "_cores", lambda: WORKERS[mode])
+    monkeypatch.setattr(covering_build, "_SCAN_BYTES_PER_WORKER", 1)
+
+
+@pytest.mark.parametrize("read", sorted(WORKERS))
+def test_one_sketch_span_a_build_whatever_the_file_count(lake, tmp_path, monkeypatch, read):
+    _read(monkeypatch, read)
     names = {}
     for n_files in (5, N_FILES):
         directory, cols = lake[n_files]
@@ -177,7 +194,12 @@ def test_one_sketch_span_a_build_whatever_the_file_count(lake, tmp_path):
         assert a["files"] == n_files and a["rows"] == len(cols[IX["indexed"][0]])
         assert a["bloom_s"] >= a["device_s"] + a["set_bits_s"] > 0
         assert a["bloom_s"] >= a["words_s"] + a["device_s"] + a["set_bits_s"]
-        assert sketch.duration_s >= a["read_s"] + a["minmax_s"] + a["bloom_s"] + a["table_s"]
+        assert a["workers"] == WORKERS[read]
+        if a["workers"] == 1:   # one thread: the parts add up to the span
+            assert sketch.duration_s >= a["read_s"] + a["minmax_s"] + a["bloom_s"] + a["table_s"]
+        else:                   # the reads are thread seconds beside the sketches
+            assert a["read_s"] <= a["workers"] * sketch.duration_s
+            assert sketch.duration_s >= a["minmax_s"] + a["bloom_s"] + a["table_s"]
         assert write.attrs["files"] == 1 and write.attrs["bytes"] > 0
         # the bloom's device leg, reckoned from shapes: two 32-bit words a
         # padded row up, k 32-bit indices a padded row down
@@ -200,3 +222,26 @@ def test_the_sketch_file_is_the_same_with_the_tracing_off(lake, tmp_path, monkey
     assert trace.finished("action.CreateAction") == []
     with open(untraced, "rb") as f:
         assert f.read() == on
+
+
+def _file_ids(session):
+    entry = session.index_manager.get_index_log_entry(IX["name"])
+    return {p: info.id for p, info in entry.relation.content.file_infos}
+
+
+@pytest.mark.parametrize("read", sorted(WORKERS))
+def test_the_sketch_file_is_the_same_read_inline_or_pooled(lake, tmp_path, monkeypatch, read):
+    directory, _cols = lake[N_FILES]
+    with monkeypatch.context() as m:
+        _read(m, "inline")
+        inline_session, _items, inline = _build(tmp_path, directory, name="inline")
+    _read(monkeypatch, read)
+    session, _items, path = _build(tmp_path, directory, name=f"read_{read}")
+    (sketch,) = [sp for sp in _root().spans if sp.name == "sketch"]
+    assert sketch.attrs["workers"] == WORKERS[read] and sketch.attrs["files"] == N_FILES
+    with open(inline, "rb") as f:
+        want = f.read()
+    with open(path, "rb") as f:
+        assert f.read() == want
+    assert _file_ids(session) == _file_ids(inline_session)
+    assert sorted(_file_ids(session).values()) == list(range(N_FILES))

@@ -8,6 +8,8 @@ included) and C-contiguity."""
 
 import datetime as dt
 import os
+import threading
+import time
 
 import numpy as np
 import pyarrow as pa
@@ -127,6 +129,43 @@ def test_scan_equals_per_file_decode_and_concat(tmp_path, monkeypatch, case, wor
     assert 0.0 <= at["concat_s"] <= sp.duration_s + 1e-6
     if lineage:
         assert got.column_names[-1] == DATA_FILE_NAME_ID
+
+
+@pytest.mark.parametrize("workers", [1, 3])
+def test_the_read_holds_at_most_workers_tables_the_consumer_has_not_taken(tmp_path, monkeypatch, workers):
+    """``_read_source_files`` yields the files in order, and reads no
+    more than ``workers`` files ahead of the consumer: one worker reads
+    each when it is asked for, a pool keeps that many in hand."""
+    from hyperspace_tpu import native
+
+    files = _lake(tmp_path, [(100 + 10 * n, False, False) for n in range(9)])
+    monkeypatch.setattr(native, "_cores", lambda: workers)
+    monkeypatch.setattr(covering_build, "_SCAN_BYTES_PER_WORKER", 1)
+    started, lock, real = [], threading.Lock(), pio.read_table
+
+    def counted(paths, columns=None, fmt="parquet", **kw):
+        with lock:
+            started.append(paths[0])
+        return real(paths, columns, fmt, **kw)
+
+    monkeypatch.setattr(pio, "read_table", counted)
+    got, reads = covering_build._read_source_files(files, "parquet", ["k"])
+    assert got == workers and started == []     # nothing read before it is asked for
+    ahead = 0 if workers == 1 else workers
+    for taken, (table, read_ns) in enumerate(reads, 1):
+        assert table.num_rows == 100 + 10 * (taken - 1) and read_ns > 0
+        want = min(len(files), taken + ahead)
+        deadline = time.monotonic() + 10
+        while len(started) < want and time.monotonic() < deadline:
+            time.sleep(0.005)
+        time.sleep(0.02)                        # room to overrun, were it allowed
+        assert len(started) == want             # read ahead as far as it may, and no further
+        if taken == 4:
+            break
+    reads.close()                               # a consumer that stops early
+    time.sleep(0.05)
+    assert len(started) == min(len(files), 4 + ahead)
+    assert started == files[: len(started)]
 
 
 def test_chunks_with_offsets_and_nulls_copy_as_from_arrow_decodes():

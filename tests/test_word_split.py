@@ -11,7 +11,8 @@ to. Here: the pass against the twins bit for bit, what it does with
 input it cannot read in place, the dispatch, ``ColumnarBatch.key_reps``
 (one int64 key is a read-only view, several are written once each), and
 a whole covering build with the pass against the same build with the
-twins forced, file bytes for file bytes.
+twins forced, file bytes for file bytes. The bloom's ``[1, n]`` block
+(``ops/bloom.bit_indices_np``) is the hash's, from either arm.
 """
 
 import glob
@@ -32,6 +33,7 @@ from hyperspace_tpu.indexes import covering_build
 from hyperspace_tpu.indexes.covering import CoveringIndexConfig
 from hyperspace_tpu.io.columnar import NULL_KEY_REP, Column, ColumnarBatch
 from hyperspace_tpu.obs import trace
+from hyperspace_tpu.ops import bloom
 from hyperspace_tpu.ops import hash as hash_ops
 from hyperspace_tpu.ops import pad_len
 from hyperspace_tpu.ops import sort as sort_ops
@@ -221,6 +223,32 @@ def test_the_device_hash_takes_the_same_block_from_either(monkeypatch, n, arm):
     assert split.attrs == {"words": 4, "native": int(arm == "native")}
     (h2d,) = [s for s in root.spans if s.name == "h2d"]
     assert h2d.attrs["bytes"] == root.attrs["h2d_bytes"] == 4 * 4 * pad_len(n)
+
+
+@pytest.mark.parametrize("arm", ["native", "numpy"])
+@pytest.mark.parametrize("n", [1, 4097, 80_000])
+def test_the_bloom_takes_the_same_block_from_either(monkeypatch, n, arm):
+    """``ops/bloom.bit_indices_np`` pads its ``[1, n]`` block with the
+    hash's ``_padded_words``: with the pass and with the twin the same
+    block (a benchmark file's 80,000 rows pad to 131,072), so the same
+    bit indices as the split and zero tail written out here."""
+    if arm == "native" and native.load() is None:
+        pytest.skip("the native kernels did not build here")
+    _arms(monkeypatch, 1 if arm == "native" else _HUGE)
+    reps = _reps(1, n)[0]
+    m, k = bloom.optimal_params(n, 0.01)
+    want_words = np.zeros((2, pad_len(n)), dtype=np.uint32)
+    want_words[:, :n] = hash_ops.split_words_np(reps[None, :])
+    words, ran = hash_ops._padded_words(reps[None, :], pad_len(n))
+    assert ran == (arm == "native") and np.array_equal(words, want_words)
+    acc = dict.fromkeys(("words_s", "device_s"), 0.0) | dict.fromkeys(("h2d_bytes", "d2h_bytes"), 0)
+    got = bloom.bit_indices_np(reps, m, k, acc)
+    want = np.asarray(bloom._bit_indices(jax.numpy.asarray(want_words), m, k))[:, :n]
+    assert got.shape == (k, n) and np.array_equal(got, want)
+    assert acc["h2d_bytes"] == 2 * 4 * pad_len(n) and acc["d2h_bytes"] == k * 4 * pad_len(n)
+    assert acc["words_s"] > 0 and acc["device_s"] > 0
+    if n == 80_000:
+        assert pad_len(n) == 131_072
 
 
 # -- ColumnarBatch.key_reps: a view where one serves, one pass a column where not ---------
