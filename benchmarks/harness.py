@@ -3,9 +3,12 @@
 Everything that belongs to one configuration, one traffic mix or one
 per-layer metric is a file found by the name ``BENCHMARK.json`` gives:
 
-    configs/<config>.json          the deployment as it is run; ``index.kind``
-                                   names the index kind, ``indexed`` + ``included``
-                                   (absent: none) the reference's columns
+    configs/<config>.json          the deployment as it is run; ``table`` names
+                                   the table generated and indexed (``lineitem``
+                                   when absent, or ``orders``; ``rows`` are its
+                                   rows), ``index.kind`` the index kind,
+                                   ``indexed`` + ``included`` (absent: none) the
+                                   reference's columns, any of the table's
     index_kinds/<kind>.py          ``ABBR``, what ``explain()`` prints for the kind,
                                    and ``index_config(config)``, the program's
                                    index config from the configuration
@@ -148,6 +151,7 @@ class Ctx:
     rehearsal: bool = False
     session: object = None
     hs: object = None
+    # the configuration's table, LINEITEM or ORDERS (the names date from LINEITEM alone)
     items_dir: str = ""
     index_root: str = ""
     items_cols: dict = None     # the reference's input, never the program's output
@@ -224,10 +228,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     from hyperspace_tpu.native import calibrate
     from hyperspace_tpu.session import HyperspaceSession
 
-    n_orders = max((rehearsal_rows or int(config["rows"])) // datagen.ITEMS_PER_ORDER, 1)
-    rows = n_orders * datagen.ITEMS_PER_ORDER
+    table = config.get("table", "lineitem")
+    per_order = datagen.rows_per_order(table)
+    n_orders = max((rehearsal_rows or int(config["rows"])) // per_order, 1)
+    rows = n_orders * per_order
     if not rehearsal and (rows, n_orders) != (config["rows"], config["orders"]):
-        raise RuntimeError(f"config states {config['rows']} rows of {config['orders']} orders")
+        raise RuntimeError(f"config states {config['rows']} {table} rows of {config['orders']} "
+                           f"orders; {table} has {per_order} row(s) an order")
     log(f"cell {workload}: config {cell['config']} traffic {cell['traffic']} "
         f"seed {seed} rows {rows:,} device {device} jax {jax.__version__} "
         f"compile cache {jax.config.jax_compilation_cache_dir}")
@@ -244,8 +251,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         thresholds = calibrate.thresholds()
         log(f"set-up: native + probe {time.time() - t0:.1f}s; thresholds {thresholds}")
         t0 = time.time()
-        ctx.items_dir, ctx.items_cols = datagen.gen_lineitem(
-            tmp, n_orders, int(config["files_per_table"]), seed,
+        ctx.items_dir, ctx.items_cols = datagen.gen_table(
+            tmp, table, n_orders, int(config["files_per_table"]), seed,
             cols=config["index"]["indexed"] + config["index"].get("included", []))
         log(f"set-up: data {time.time() - t0:.1f}s")
 

@@ -29,7 +29,7 @@ def main(argv=None) -> int:
     ap.add_argument("--controls", type=int, choices=(0, 1), default=0)
     ap.add_argument("--cpu-rehearsal", action="store_true")
     ap.add_argument("--rows", type=int, default=0,
-                    help="lineitem rows of a --cpu-rehearsal")
+                    help="rows of the configuration's table in a --cpu-rehearsal")
     args = ap.parse_args(argv)
     if args.seed < 0:
         ap.error("--seed is a whole number from 0")

@@ -35,13 +35,43 @@ def test_same_seed_same_bytes_and_every_seed_the_same_shapes(tables, tmp_path):
     assert tuple(eleven) == datagen.NUMPY_COLS
     for name in datagen.NUMPY_COLS:
         assert np.array_equal(reference.table_cols(on_disk.select([name]))[name], eleven[name]), name
-    with pytest.raises(ValueError, match="l_comment"):
-        datagen.gen_lineitem(str(tmp_path / "d"), N_ORDERS, 4, 1, cols=["l_comment"])
+    # a string column comes back as an object array equal to the file's column
+    strings = ("l_returnflag", "l_linestatus", "l_shipinstruct", "l_shipmode", "l_comment")
+    _d, named = datagen.gen_lineitem(str(tmp_path / "d"), N_ORDERS, 4, 2**31 + 7, cols=strings)
+    for name in strings:
+        assert named[name].dtype == object and named[name].tolist() == on_disk.column(name).to_pylist(), name
+    with pytest.raises(ValueError, match="l_no_such_column"):
+        datagen.gen_lineitem(str(tmp_path / "e"), N_ORDERS, 4, 1, cols=["l_comment", "l_no_such_column"])
     _d, other = datagen.gen_lineitem(str(tmp_path / "b"), N_ORDERS, 4, 12345)
     assert len(other["l_orderkey"]) == len(items["l_orderkey"]) == 4 * N_ORDERS
     assert not np.array_equal(other["l_orderkey"], items["l_orderkey"])
     per_file = [pq.read_metadata(f"{items_dir}/part{i}.parquet").num_rows for i in range(4)]
     assert per_file == [N_ORDERS] * 4
+
+
+# sha256 of each file the generator wrote before ORDERS could be made:
+# (orders, files, seed) -> one digest a file
+PARENT_BYTES = {
+    (1000, 4, 2**31 + 7): (
+        "912679f1c5a3fad4308390cd7e20532ce3bc0c7c1c60695b9eb6277c3e1ea8b1",
+        "c15258248fe5811fc46ffbb4c491ba9b2edd51be2bda02829ab9567e1d4b26b5",
+        "7cd64a5b88d2e7317fa6f51eae7a481f7bcc915d8eb6644b2053dba8066f76d7",
+        "ce2fc17a6fe24742033828301c12f65c2ec05b6b15ed5d3ffb91b1f683539017"),
+    (7001, 3, 5): (
+        "b3189d9807c2fa113f17f1aae8f8bf09597e50420ccef258bd34b9bfc654b791",
+        "c2a02481504747467e0f3f757beb28b0f29a857d2095274808e202ab4a84778d",
+        "d86805d6bc03332a89784559b51e362bd9e6f8741f11e6838ceb787c1141f449"),
+}
+
+
+@pytest.mark.parametrize("orders,files,seed", sorted(PARENT_BYTES))
+def test_lineitem_bytes_are_what_they_were(tmp_path, orders, files, seed):
+    import hashlib
+
+    items_dir, _ = datagen.gen_lineitem(str(tmp_path), orders, files, seed, cols=datagen.LINEITEM_COLS)
+    got = tuple(hashlib.sha256((tmp_path / "lineitem" / f"part{i}.parquet").read_bytes()).hexdigest()
+                for i in range(files))
+    assert got == PARENT_BYTES[orders, files, seed]
 
 
 def test_lineitem_is_populated_as_the_specification_says(tables):
